@@ -43,10 +43,6 @@ def _np_array(shape, dtype, seed=0):
 
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_pack_matches_serialization_bytes(dtype):
-    from torchsnapshot_tpu.test_utils import backend_materializes_dtype
-
-    if not backend_materializes_dtype(dtype):
-        pytest.skip(f"backend cannot materialize {dtype}")
     hosts = [_np_array((5, 3), dtype, seed=i) for i in range(3)]
     devs = [jnp.asarray(h) for h in hosts]
     packed = np.asarray(dp.pack_async([(d, None) for d in devs]))
@@ -119,10 +115,6 @@ def test_batched_snapshot_uses_device_pack(tmp_path, monkeypatch):
 def test_batched_snapshot_mixed_dtypes_roundtrip(tmp_path):
     tree = {}
     for i, dtype in enumerate(DTYPES):
-        from torchsnapshot_tpu.test_utils import backend_materializes_dtype
-
-        if not backend_materializes_dtype(dtype):
-            continue
         tree[f"a_{dtype}"] = jnp.asarray(_np_array((7, 3), dtype, seed=i))
     p = str(tmp_path / "snap")
     with enable_batching(), enable_device_pack(), \

@@ -289,34 +289,6 @@ def test_world_32_stress_over_tcp() -> None:
     assert elapsed < 60, f"world-32 coordination took {elapsed:.1f}s"
 
 
-def test_jax_pg_fallback_bootstraps_tcp_store() -> None:
-    """A coordination client without atomic increment must get a TCPStore
-    bootstrapped through set/get (the two primitives every KV has) instead
-    of NotImplementedError surfacing mid-collective."""
-    from torchsnapshot_tpu.dist_store import _bootstrap_tcp_store
-
-    kv = InProcessStore()  # stands in for the coordination KV (set/get only)
-    stores = {}
-
-    def worker(rank: int) -> None:
-        stores[rank] = _bootstrap_tcp_store(kv, rank, timeout=30)
-
-    threads = [threading.Thread(target=worker, args=(r,)) for r in range(3)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=60)
-    assert sorted(stores) == [0, 1, 2]
-    try:
-        stores[0].set("k", b"v")
-        assert stores[1].try_get("k") == b"v"
-        assert stores[2].add("c", 5) == 5
-        assert stores[1].add("c", 1) == 6
-    finally:
-        for s in stores.values():
-            s.close()
-
-
 def test_world_32_snapshot_take_restore(tmp_path) -> None:
     """Full Snapshot.take + restore at world 32 over one TCP store: the
     manifest gather (rank-0 aggregate exchange), replicated verification,
@@ -370,16 +342,12 @@ def test_world_32_snapshot_take_restore(tmp_path) -> None:
 
 def test_jax_process_group_is_cached(monkeypatch) -> None:
     """Repeated jax_process_group() calls must return the same ProcessGroup
-    (same store object): op-seq namespaces stay shared, and the TCPStore
-    fallback never bootstraps a second server under the same address key."""
+    (same store object): op-seq namespaces stay shared."""
     import torchsnapshot_tpu.dist_store as ds
 
     monkeypatch.setattr(ds, "_JAX_PG", None)
     sentinel_store = InProcessStore()
     monkeypatch.setattr(ds, "JaxCoordinationStore", lambda: sentinel_store)
-    monkeypatch.setattr(
-        ds.InProcessStore, "supports_add", lambda self: True, raising=False
-    )
     pg1 = ds.jax_process_group()
     pg2 = ds.jax_process_group()
     assert pg1 is pg2

@@ -8,33 +8,7 @@ process-global and irreversible, so the exercise runs in a spawned worker
 (the harness pins workers to the CPU backend).
 """
 
-import pytest
-
 from torchsnapshot_tpu.test_utils import run_multiprocess
-
-
-def _jaxlib_has_kv_try_get() -> bool:
-    """The store's absent-key probe needs ``key_value_try_get_bytes``
-    on the distributed runtime client; older jaxlibs (this container's
-    included) ship the KV API without it, and JaxCoordinationStore
-    refuses to construct there (directing users at TCPStore). Skip
-    rather than carry a known-red environment failure."""
-    try:
-        import jaxlib.xla_extension as xe
-
-        return hasattr(
-            xe.DistributedRuntimeClient, "key_value_try_get_bytes"
-        )
-    except Exception:  # noqa: BLE001 - no probe = assume modern jaxlib
-        return True
-
-
-pytestmark = pytest.mark.skipif(
-    not _jaxlib_has_kv_try_get(),
-    reason="jaxlib's DistributedRuntimeClient lacks "
-    "key_value_try_get_bytes; JaxCoordinationStore cannot serve here "
-    "(TCPStore coordination is the supported path)",
-)
 
 
 def _jax_coordination_worker(pg, port: int):
@@ -60,20 +34,15 @@ def _jax_coordination_worker(pg, port: int):
     store.delete("k1")
     assert store.try_get("k1") is None
 
-    counters_ok = True
-    try:
-        assert store.add("ctr", 2) == 2
-        assert store.add("ctr", 3) == 5
-    except NotImplementedError:
-        counters_ok = False  # older jaxlib: documented degradation
+    assert store.add("ctr", 2) == 2
+    assert store.add("ctr", 3) == 5
 
     # Object collectives (world 1 semantics still run real KV traffic).
-    if counters_ok:
-        assert store.exchange("ex", 0, 1, {"x": 1}) == [{"x": 1}]
-        assert store.broadcast("bc", 0, 1, "hello") == "hello"
-        barrier = LinearBarrier("b", store, rank=0, world_size=1)
-        barrier.arrive()
-        barrier.depart()
+    assert store.exchange("ex", 0, 1, {"x": 1}) == [{"x": 1}]
+    assert store.broadcast("bc", 0, 1, "hello") == "hello"
+    barrier = LinearBarrier("b", store, rank=0, world_size=1)
+    barrier.arrive()
+    barrier.depart()
 
     # The convenience pg threads through the Snapshot API (world size 1
     # short-circuits collectives, so KV coverage comes from the block
@@ -88,7 +57,6 @@ def _jax_coordination_worker(pg, port: int):
     dst = {"s": ts.PyTreeState({"w": np.zeros(16)})}
     ts.Snapshot(path, pg=jpg).restore(dst)
     np.testing.assert_array_equal(dst["s"].tree["w"], arr)
-    return counters_ok
 
 
 def test_jax_coordination_store() -> None:
@@ -103,10 +71,9 @@ def test_jax_coordination_store() -> None:
         coord_port = s1.getsockname()[1]
         store_port = s2.getsockname()[1]
 
-    [counters_ok] = run_multiprocess(
+    run_multiprocess(
         _jax_coordination_worker, nproc=1, args=(coord_port,), port=store_port
     )
-    assert isinstance(counters_ok, bool)
 
 
 def _jax_dist2_worker(pg, coord_port: int, root: str):

@@ -16,27 +16,6 @@ from torchsnapshot_tpu.parallel import (
     stack_stage_params,
 )
 
-# The GPipe schedule itself (pipelined_apply) rides
-# utils.shard_map_compat: top-level jax.shard_map where it exists, the
-# jax.experimental spelling on pre-promotion 0.4.x releases (this
-# container's included). Skip only when NEITHER spelling exists.
-def _has_shard_map() -> bool:
-    if hasattr(jax, "shard_map"):
-        return True
-    try:
-        from jax.experimental.shard_map import shard_map  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
-needs_shard_map = pytest.mark.skipif(
-    not _has_shard_map(),
-    reason="this jax has neither jax.shard_map nor "
-    "jax.experimental.shard_map; pipelined_apply requires one",
-)
-
 
 def _pp_mesh(n: int) -> Mesh:
     if len(jax.devices()) < n:
@@ -60,7 +39,6 @@ def _make_stages(n_stages: int, d: int, seed: int = 0):
     ]
 
 
-@needs_shard_map
 def test_pipeline_matches_sequential():
     n_stages, d = 4, 16
     mesh = _pp_mesh(n_stages)
@@ -78,7 +56,6 @@ def test_pipeline_matches_sequential():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-@needs_shard_map
 def test_pipeline_bubble_only_schedule():
     """n_microbatches == 1 (pure bubble) still yields the right answer."""
     n_stages, d = 2, 8
@@ -93,7 +70,6 @@ def test_pipeline_bubble_only_schedule():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
-@needs_shard_map
 def test_pipeline_grad():
     """Reverse-mode through the schedule (the backward pipeline) matches
     the unpipelined gradient."""

@@ -119,10 +119,6 @@ def test_take_restore_chunked(tmp_path) -> None:
     ],
 )
 def test_roundtrip_dtypes(tmp_path, dtype) -> None:
-    from torchsnapshot_tpu.test_utils import backend_materializes_dtype
-
-    if not backend_materializes_dtype(dtype):
-        pytest.skip(f"{dtype} not materializable on this jax backend")
     rng = np.random.default_rng(0)
     if dtype.startswith("float8"):
         import ml_dtypes

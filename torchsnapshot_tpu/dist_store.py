@@ -943,50 +943,49 @@ class ShardedStore(Store):
                     pass
 
 
+_SHARD_STORE_PREFIX = "__ts/shard_store"
+
+
 def bootstrap_sharded_store(
     base: Store,
     rank: int,
     world_size: int,
     num_shards: Optional[int] = None,
-    prefix: str = "__ts/shard_store",
     timeout: float = _DEFAULT_TIMEOUT_S,
 ) -> Store:
     """Stand up a :class:`ShardedStore` of TCPStore members over an
     existing coordination store (which only needs ``set``/``get``).
 
     Rank 0's knob reading decides the shard count for the whole job —
-    published through ``base`` exactly like the TCPStore-bootstrap
-    address (the same agreement-by-broadcast discipline as the fan-out
-    nonce): env skew across ranks can never split the key space two
-    ways. Shard ``i`` is hosted by rank ``i % world_size``, so on a
-    multi-host pod the server sockets spread across hosts instead of
-    stacking on the leader. ``num_shards <= 1`` returns ``base``
-    unchanged (the packaged default)."""
+    published through ``base`` (the same agreement-by-broadcast
+    discipline as the fan-out nonce): env skew across ranks can never
+    split the key space two ways. Shard ``i`` is hosted by rank
+    ``i % world_size``, so on a multi-host pod the server sockets spread
+    across hosts instead of stacking on the leader. ``num_shards <= 1``
+    returns ``base`` unchanged (the packaged default)."""
     if rank == 0:
         if num_shards is None:
             num_shards = knobs.get_store_shards()
         num_shards = max(1, min(int(num_shards), world_size * 8))
-        base.set(f"{prefix}/n", str(num_shards).encode())
+        base.set(f"{_SHARD_STORE_PREFIX}/n", str(num_shards).encode())
     else:
-        num_shards = int(base.get(f"{prefix}/n", timeout))
+        num_shards = int(base.get(f"{_SHARD_STORE_PREFIX}/n", timeout))
     if num_shards <= 1:
         return base
     members: List[Optional[Store]] = [None] * num_shards
     for i in range(num_shards):
         if i % world_size != rank:
             continue
-        # THIS rank's own interface, not _routable_host(): its first
-        # choice is the coordinator (rank 0's) address, which is the
-        # wrong advert for a shard server bound on any other host.
         host = _local_advertise_host()
         tcp = TCPStore(host="0.0.0.0", port=0, is_server=True)
         tcp.host = host
-        base.set(f"{prefix}/{i}", f"{host}:{tcp.port}".encode())
+        base.set(f"{_SHARD_STORE_PREFIX}/{i}", f"{host}:{tcp.port}".encode())
         members[i] = tcp
     for i in range(num_shards):
         if members[i] is not None:
             continue
-        host, port = base.get(f"{prefix}/{i}", timeout).decode().rsplit(":", 1)
+        addr = base.get(f"{_SHARD_STORE_PREFIX}/{i}", timeout).decode()
+        host, port = addr.rsplit(":", 1)
         members[i] = TCPStore(host=host, port=int(port), is_server=False)
     return ShardedStore([m for m in members if m is not None])
 
@@ -995,10 +994,7 @@ class JaxCoordinationStore(Store):
     """KV store over the JAX distributed coordination service.
 
     Usable once ``jax.distributed.initialize`` has run; rides DCN like the
-    rest of JAX's control plane. Atomic counters require the coordination
-    client's ``key_value_increment`` (present in current jaxlib); on an
-    older jaxlib without it, ``add`` raises and snapshot coordination
-    should use :class:`TCPStore` instead.
+    rest of JAX's control plane.
     """
 
     def __init__(self) -> None:
@@ -1064,23 +1060,8 @@ class JaxCoordinationStore(Store):
                 return None
             raise
 
-    def supports_add(self) -> bool:
-        """Whether this jaxlib's coordination client has atomic increment.
-        ``add`` is load-bearing for every collective's cleanup and for
-        ``Store.barrier``, so a runtime without it must be detected at
-        :func:`jax_process_group` time (which then bootstraps a TCPStore
-        through the KV service — set/get are always available), not
-        mid-collective."""
-        return getattr(self._client, "key_value_increment", None) is not None
-
     def add(self, key: str, amount: int) -> int:
-        inc = getattr(self._client, "key_value_increment", None)
-        if inc is not None:
-            return int(inc(key, amount))
-        raise NotImplementedError(
-            "This jaxlib's coordination client lacks atomic increment; "
-            "use TCPStore for snapshot coordination instead"
-        )
+        return int(self._client.key_value_increment(key, amount))
 
     def delete(self, key: str) -> None:
         try:
@@ -1102,17 +1083,9 @@ def jax_process_group():
     (Reference analog: get_or_create_store reusing the c10d default
     TCPStore, dist_store.py:22-88.)
 
-    On a jaxlib whose coordination client lacks atomic increment, a
-    TCPStore is bootstrapped through the KV service transparently (rank 0
-    hosts, publishes its address via set; everyone else gets it) — the
-    failure mode otherwise would be a ``NotImplementedError`` surfacing
-    mid-collective, far from its cause.
-
     The result is cached per process: repeated calls return the SAME
-    ProcessGroup (hence the same store object). This keeps the ``__pg/*``
-    op-seq namespace shared across call sites, and — on the TCPStore
-    fallback path — prevents a second call from bootstrapping a second
-    server under the same address key and splitting ranks between the two.
+    ProcessGroup (hence the same store object), which keeps the
+    ``__pg/*`` op-seq namespace shared across call sites.
     """
     global _JAX_PG
     with _JAX_PG_LOCK:
@@ -1122,13 +1095,10 @@ def jax_process_group():
 
         rank = jax.process_index()
         world = jax.process_count()
-        kv = JaxCoordinationStore()
-        store: Store = kv
-        if not kv.supports_add():
-            store = _bootstrap_tcp_store(kv, rank)
+        store: Store = JaxCoordinationStore()
         # Store sharding (docs/scaling.md): rank 0's knob decides the
         # shard count for the whole job; the members bootstrap through
-        # the KV service like the TCPStore fallback. Default 1 = no-op.
+        # the KV service. Default 1 = no-op.
         if world > 1:
             store = bootstrap_sharded_store(store, rank, world)
         _JAX_PG = ProcessGroup(
@@ -1143,31 +1113,10 @@ _JAX_PG: Optional[ProcessGroup] = None
 _JAX_PG_LOCK = threading.Lock()
 
 
-def _routable_host() -> str:
-    """An address peers on other hosts can dial for RANK 0's machine.
-    The jax coordinator address is best (rank 0 of jax.distributed
-    hosts the coordinator, and every process demonstrably reached it);
-    else this machine's own interface. Only correct on the rank that
-    hosts the coordinator — any-rank servers advertise via
-    :func:`_local_advertise_host` instead."""
-    try:
-        from jax._src import distributed
-
-        addr = getattr(distributed.global_state, "coordinator_address", None)
-        if addr:
-            return addr.rsplit(":", 1)[0]
-    except Exception:
-        pass
-    return _local_advertise_host()
-
-
 def _local_advertise_host() -> str:
     """An address peers on other hosts can dial for THIS machine —
-    correct on any rank. Unlike :func:`_routable_host` (whose first
-    choice is the jax coordinator address — right only for the rank
-    that HOSTS the coordinator, i.e. rank 0's TCP-store bootstrap), a
-    per-rank server (shard store member, peer-tier cache) must
-    advertise its own interface: outbound-interface IP first (the UDP
+    correct on any rank, so right for a per-rank server (shard store
+    member, peer-tier cache): outbound-interface IP first (the UDP
     connect sends no traffic), hostname last."""
     try:
         probe = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
@@ -1178,23 +1127,6 @@ def _local_advertise_host() -> str:
             probe.close()
     except Exception:
         return socket.gethostname()
-
-
-def _bootstrap_tcp_store(
-    kv: Store, rank: int, timeout: float = _DEFAULT_TIMEOUT_S
-) -> "TCPStore":
-    """Bootstrap a TCPStore using only ``set``/``get`` of ``kv`` (the two
-    primitives every coordination KV has): rank 0 binds a free port and
-    publishes ``host:port``; the rest fetch and connect."""
-    addr_key = "__ts/tcp_store_addr"
-    if rank == 0:
-        host = _routable_host()
-        tcp = TCPStore(host="0.0.0.0", port=0, is_server=True)
-        tcp.host = host  # clients (and rank 0's own socket) dial this addr
-        kv.set(addr_key, f"{host}:{tcp.port}".encode())
-        return tcp
-    host, port = kv.get(addr_key, timeout).decode().rsplit(":", 1)
-    return TCPStore(host=host, port=int(port), is_server=False)
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,7 @@ ShardedTensorIOPreparer.
 from __future__ import annotations
 
 import asyncio
+import logging
 import sys
 from concurrent.futures import Executor
 from typing import Any, Callable, List, Optional, Tuple
@@ -58,6 +59,8 @@ from .serialization import (
 
 from .telemetry import names as metric_names
 from .utils.tracing import trace_annotation
+
+logger: logging.Logger = logging.getLogger(__name__)
 
 ArrayPrepareFunc = Callable[[Any, bool], Any]
 
@@ -201,7 +204,13 @@ class ArrayBufferStager(BufferStager):
                 import jax.numpy as jnp
 
                 snap = jnp.copy(arr)
-            except Exception:  # noqa: BLE001 - host fallback, never torn
+            except Exception as e:  # noqa: BLE001 - host fallback, never torn
+                logger.warning(
+                    "Device clone of a %d-byte leaf failed (%r); copying it "
+                    "to the host inside the visible span instead",
+                    arr.nbytes,
+                    e,
+                )
                 snap = np.ascontiguousarray(np.asarray(arr))
         elif isinstance(arr, np.ndarray):
             snap = np.array(arr, order="C", copy=True)

@@ -242,9 +242,7 @@ def _flash_attention_sharded(
     has_dp = "dp" in mesh.axis_names
     has_tp = "tp" in mesh.axis_names
     spec = P("dp" if has_dp else None, None, "tp" if has_tp else None, None)
-    from ..utils import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(flash_causal_attention, interpret=interpret),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -369,10 +367,27 @@ def init_train_state(
     rng = jax.random.PRNGKey(seed)
     params = init_params(cfg, rng, mesh=mesh)
     opt = _optimizer(cfg)
-    # Adam moments are zeros_like(params): GSPMD propagation shards them
-    # like the params; the scalar count replicates. No manual out_shardings.
-    opt_state = jax.jit(opt.init)(params)
     step = jnp.zeros((), dtype=jnp.int32)
+    if mesh is None:
+        opt_state = jax.jit(opt.init)(params)
+    else:
+        # Adam moments are zeros_like(params): no data flows from the
+        # params, so sharding propagation has nothing to follow and a bare
+        # jit leaves both moments (2/3 of the state) whole on device 0
+        # until the first step reshards them. Shard them like the params
+        # and replicate the scalars, so the state enters the step with the
+        # shardings it leaves with (one compile, and restore destinations
+        # laid out as trained).
+        replicated = NamedSharding(mesh, P())
+        opt_shardings = optax.tree_map_params(
+            opt,
+            lambda _, sharding: sharding,
+            jax.eval_shape(opt.init, params),
+            param_shardings(cfg, mesh),
+            transform_non_params=lambda _: replicated,
+        )
+        opt_state = jax.jit(opt.init, out_shardings=opt_shardings)(params)
+        step, rng = jax.device_put((step, rng), replicated)
     return TrainState(params=params, opt_state=opt_state, step=step, rng=rng)
 
 

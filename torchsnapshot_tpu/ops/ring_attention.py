@@ -183,9 +183,7 @@ def ring_causal_attention(
     spec = P("dp" if has_dp else None, axis_name, "tp" if has_tp else None, None)
 
     def mapped(flash: bool):
-        from ..utils import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             functools.partial(
                 _ring_attention_local,
                 axis_name=axis_name,

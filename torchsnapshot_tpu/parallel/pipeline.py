@@ -148,9 +148,7 @@ def pipelined_apply(
     spec_params = jax.tree_util.tree_map(
         lambda leaf: P(axis_name, *([None] * (leaf.ndim - 1))), stage_params
     )
-    from ..utils import shard_map_compat
-
-    out = shard_map_compat(
+    out = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(spec_params, P()),

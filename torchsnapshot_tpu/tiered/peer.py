@@ -1115,10 +1115,8 @@ class PeerReplicator:
 
 
 def _advertise_host() -> str:
-    """The address peers dial for THIS process's cache server. Must be
-    rank-local: ``_routable_host``'s first choice is the jax
-    coordinator (rank 0's) address, which every non-rank-0 host would
-    wrongly advertise for a server bound on its own machine."""
+    """The address peers dial for THIS process's cache server: this
+    machine's own interface, never the coordinator's."""
     from ..dist_store import _local_advertise_host
 
     try:

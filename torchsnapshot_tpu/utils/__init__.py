@@ -1,32 +1,3 @@
 from .rss_profiler import measure_rss_deltas, RSSDeltas
 
-__all__ = ["measure_rss_deltas", "RSSDeltas", "shard_map_compat"]
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across the promotion boundary: newer jax ships
-    it top-level (with ``check_vma``); older 0.4.x releases only ship
-    ``jax.experimental.shard_map`` (where the same knob is spelled
-    ``check_rep``). The three shard_map consumers (ring attention, the
-    flash-attention mesh wrapper, the GPipe schedule) route through
-    here so either jax runs them instead of failing on the missing
-    attribute."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
-    )
+__all__ = ["measure_rss_deltas", "RSSDeltas"]
