@@ -1,0 +1,10 @@
+"""Seconds per restore during which at least one `verify:blob` (checksum
+verification of read bytes, inline or on the executor) was open."""
+
+from typing import Any, Dict, Optional
+
+from stage_table import busy_s
+
+
+def read(run: Dict[str, Any]) -> Optional[float]:
+    return busy_s(run, "SPAN_VERIFY_BLOB")

@@ -39,6 +39,7 @@ import numpy as np
 from . import _native, knobs, telemetry
 from .telemetry import names as metric_names
 from .telemetry.trace import get_recorder as _trace_recorder
+from .utils.tracing import run_in_executor
 from .io_types import (
     BufferConsumer,
     BufferList,
@@ -132,7 +133,7 @@ class BatchedBufferStager(BufferStager):
         else:
             self._staging_cost = self.total + pack_bytes + peak_member
 
-    def capture(self, cache: dict) -> None:
+    def capture(self, cache: dict, leaf: str = "") -> None:
         """Device-snapshot capture recurses into the slab's members:
         each member stager pins its own source (shared ``cache``, so a
         leaf split across slabs still snapshots once). The group split
@@ -141,7 +142,7 @@ class BatchedBufferStager(BufferStager):
         (and the pack path degrades to sequential staging on any
         surprise, as it always has)."""
         for req, _, _ in self.members:
-            req.buffer_stager.capture(cache)
+            req.buffer_stager.capture(cache, leaf=req.path)
 
     # Per-dispatch member cap: an N-ary concat program's trace/compile
     # time grows with N, and one compile per distinct slab layout must
@@ -317,10 +318,9 @@ class BatchedBufferStager(BufferStager):
         CRC) writes them without the gather_memcpy pack pass ever
         running — the one-full-memory-pass-per-staged-byte elimination
         this path exists for."""
-        loop = asyncio.get_running_loop()
         parts: List[Tuple[int, memoryview]] = []
         pack_futures = [
-            loop.run_in_executor(executor, self._pack_group_vectorized, items)
+            run_in_executor(executor, self._pack_group_vectorized, items)
             for items in self._packed
         ]
         first_exc: Optional[BaseException] = None
@@ -381,10 +381,9 @@ class BatchedBufferStager(BufferStager):
         # gate; see docs/storage.md "Native write path").
         slab = _native.aligned_buffer(self.total)
         view = memoryview(slab)
-        loop = asyncio.get_running_loop()
         packed, rest = self._packed, self._rest
         pack_futures = [
-            loop.run_in_executor(executor, self._pack_group_sync, items, view)
+            run_in_executor(executor, self._pack_group_sync, items, view)
             for items in packed
         ]
         # Every pack future MUST settle before this method returns or

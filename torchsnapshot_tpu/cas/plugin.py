@@ -32,7 +32,6 @@ keys off (an in-flight step's reused chunks are always fresh).
 
 from __future__ import annotations
 
-import asyncio
 import json
 import logging
 import os
@@ -42,6 +41,7 @@ from .. import telemetry
 from ..integrity import compute_checksum_entry
 from ..io_types import ReadIO, StoragePlugin, WriteIO, payload_nbytes
 from ..telemetry import names as metric_names
+from ..utils.tracing import run_in_executor
 from .store import (
     CAS_MAP_DIR,
     CHUNKS_DIRNAME,
@@ -103,7 +103,7 @@ class CASStoragePlugin(StoragePlugin):
     async def _entry_of(self, buf) -> Tuple:
         if payload_nbytes(buf) <= _INLINE_DIGEST_BYTES:
             return compute_checksum_entry(buf)
-        return await asyncio.get_running_loop().run_in_executor(
+        return await run_in_executor(
             None, compute_checksum_entry, buf
         )
 

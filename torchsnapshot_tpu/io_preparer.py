@@ -28,6 +28,7 @@ ShardedTensorIOPreparer.
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import sys
 from concurrent.futures import Executor
@@ -58,7 +59,7 @@ from .serialization import (
 )
 
 from .telemetry import names as metric_names
-from .utils.tracing import trace_annotation
+from .utils.tracing import run_in_executor, trace_annotation
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -69,6 +70,21 @@ def _jax():
     import jax
 
     return jax
+
+
+@functools.lru_cache(maxsize=1)
+def _capture_clone_jit():
+    """The on-device clone of a captured leaf as one named program
+    (``jit_ts_capture_clone`` on a profile's Modules line): the copy
+    ``jnp.copy`` dispatches, one compile per leaf shape as before."""
+    import jax
+    import jax.numpy as jnp
+
+    def ts_capture_clone(x):
+        with jax.named_scope("ts_capture_clone"):
+            return jnp.copy(x)
+
+    return jax.jit(ts_capture_clone)
 
 
 def is_jax_array(obj: Any) -> bool:
@@ -173,7 +189,7 @@ class ArrayBufferStager(BufferStager):
             self.get_staging_cost_bytes() < knobs.get_slab_size_threshold_bytes()
         )
 
-    def capture(self, cache: dict) -> None:
+    def capture(self, cache: dict, leaf: str = "") -> None:
         """Device-snapshot capture (the deferred-staging async take's
         pre-return consistency point):
 
@@ -190,7 +206,12 @@ class ArrayBufferStager(BufferStager):
         A jax clone that fails (e.g. a multi-process array this process
         cannot re-materialize on device) falls back to an eager HOST
         snapshot of the bytes — slower (it pays the D2H in the visible
-        span, for that leaf only) but never inconsistent."""
+        span, for that leaf only) but never inconsistent.
+
+        One span per distinct source (``leaf`` is the write request's
+        path): ``capture:clone`` is the dispatch alone — nothing here
+        waits for the device — so one that is long is the runtime
+        holding the dispatch back."""
         arr = self.arr
         if arr is None:
             return
@@ -199,11 +220,16 @@ class ArrayBufferStager(BufferStager):
             self.arr = cache[key]
             self._captured = True
             return
+        snap = None
         if is_jax_array(arr):
             try:
-                import jax.numpy as jnp
-
-                snap = jnp.copy(arr)
+                with trace_annotation(
+                    metric_names.SPAN_CAPTURE_CLONE,
+                    kind="device",
+                    bytes=int(arr.nbytes),
+                    leaf=leaf,
+                ):
+                    snap = _capture_clone_jit()(arr)
             except Exception as e:  # noqa: BLE001 - host fallback, never torn
                 logger.warning(
                     "Device clone of a %d-byte leaf failed (%r); copying it "
@@ -211,13 +237,25 @@ class ArrayBufferStager(BufferStager):
                     arr.nbytes,
                     e,
                 )
-                snap = np.ascontiguousarray(np.asarray(arr))
-        elif isinstance(arr, np.ndarray):
-            snap = np.array(arr, order="C", copy=True)
-        else:
-            # Exotic array-like: materialize through numpy now — the
-            # generic consistency fallback.
-            snap = np.array(np.asarray(arr), order="C", copy=True)
+        if snap is None:
+            kind = (
+                "clone_fallback"
+                if is_jax_array(arr)
+                else "numpy" if isinstance(arr, np.ndarray) else "array_like"
+            )
+            with trace_annotation(
+                metric_names.SPAN_CAPTURE_HOST_COPY,
+                kind=kind,
+                bytes=int(getattr(arr, "nbytes", 0)),
+                leaf=leaf,
+            ):
+                if is_jax_array(arr):
+                    # Immutable source: its host image needs no second copy.
+                    snap = np.ascontiguousarray(np.asarray(arr))
+                else:
+                    # Exotic array-likes materialize through numpy too:
+                    # the generic consistency fallback.
+                    snap = np.array(np.asarray(arr), order="C", copy=True)
         cache[key] = snap
         self.arr = snap
         self._captured = True
@@ -236,8 +274,7 @@ class ArrayBufferStager(BufferStager):
             and self.get_staging_cost_bytes() <= _INLINE_STAGE_MAX_BYTES
         ):
             return self._stage_sync()
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(executor, self._stage_sync)
+        return await run_in_executor(executor, self._stage_sync)
 
     def _stage_sync(self) -> BufferType:
         with trace_annotation(metric_names.SPAN_LEAF_STAGE):
@@ -252,7 +289,10 @@ class ArrayBufferStager(BufferStager):
         if is_jax_array(arr):
             # jax.Array is immutable: the host copy is consistent even for
             # async snapshots, with no defensive copy.
-            host = np.asarray(arr)
+            with trace_annotation(
+                metric_names.SPAN_STAGE_D2H, bytes=int(arr.nbytes)
+            ):
+                host = np.asarray(arr)
             host = np.ascontiguousarray(host)
         else:
             host = np.asarray(arr)
@@ -310,8 +350,7 @@ class ArrayBufferConsumer(BufferConsumer):
         if self.get_consuming_cost_bytes() <= _INLINE_STAGE_MAX_BYTES:
             self._consume_sync(buf)
             return
-        loop = asyncio.get_running_loop()
-        await loop.run_in_executor(executor, self._consume_sync, buf)
+        await run_in_executor(executor, self._consume_sync, buf)
 
     def _consume_sync(self, buf: BufferType) -> None:
         with trace_annotation(metric_names.SPAN_LEAF_CONSUME):
@@ -614,23 +653,27 @@ class ObjectBufferStager(BufferStager):
         self.obj = obj
         self._buf: Optional[bytes] = None
 
-    def capture(self, cache: dict) -> None:
+    def capture(self, cache: dict, leaf: str = "") -> None:
         """Objects are snapshotted by pickling them NOW: deferred
         staging would otherwise serialize a mutable object (a metrics
         dict, a dataloader state) after training resumed mutating it.
         Objects are metadata-sized in practice; the pickle cost sits in
-        the visible span by design — consistency over latency here."""
+        the visible span by design — consistency over latency here. An
+        object that holds a device array waits for the device here."""
         if self._buf is None:
-            self._buf = pickle_save_as_bytes(self.obj)
+            with trace_annotation(
+                metric_names.SPAN_CAPTURE_OBJECT,
+                kind=type(self.obj).__name__,
+                leaf=leaf,
+            ) as span:
+                self._buf = pickle_save_as_bytes(self.obj)
+                span.annotate(bytes=len(self._buf))
             self.obj = None
 
     async def stage_buffer(self, executor: Optional[Executor] = None) -> BufferType:
         if self._buf is not None:
             return self._buf
-        loop = asyncio.get_running_loop()
-        return await loop.run_in_executor(
-            executor, pickle_save_as_bytes, self.obj
-        )
+        return await run_in_executor(executor, pickle_save_as_bytes, self.obj)
 
     def get_staging_cost_bytes(self) -> int:
         if self._buf is not None:
@@ -649,8 +692,9 @@ class ObjectBufferConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
-        obj = await loop.run_in_executor(executor, pickle_load_from_bytes, bytes(buf))
+        obj = await run_in_executor(
+            executor, pickle_load_from_bytes, bytes(buf)
+        )
         self.callback(obj)
 
     def get_consuming_cost_bytes(self) -> int:
@@ -762,7 +806,7 @@ def capture_write_reqs(write_reqs: List[WriteReq]) -> int:
     once. Returns the number of distinct sources captured."""
     cache: dict = {}
     for req in write_reqs:
-        req.buffer_stager.capture(cache)
+        req.buffer_stager.capture(cache, leaf=req.path)
     return len(cache)
 
 

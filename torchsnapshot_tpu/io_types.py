@@ -138,13 +138,14 @@ class BufferStager(abc.ABC):
     @abc.abstractmethod
     def get_staging_cost_bytes(self) -> int: ...
 
-    def capture(self, cache: dict) -> None:
+    def capture(self, cache: dict, leaf: str = "") -> None:
         """Pin a consistent snapshot of this stager's source *before*
         ``async_take`` returns, so the application may mutate (or
         donate) the live state while staging runs on the background
         drain. ``cache`` is shared across one take's stagers, keyed by
         ``id(source)``, so several stagers over one leaf (chunked
-        writes, shard pieces) snapshot it once. Default: no-op —
+        writes, shard pieces) snapshot it once; ``leaf`` (the write
+        request's path) names the source in the capture's span. Default: no-op —
         stagers whose source cannot change under them (or that stage
         before the take returns) need nothing."""
         return None

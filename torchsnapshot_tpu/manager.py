@@ -44,6 +44,8 @@ from . import knobs, telemetry
 from .chaos import crashpoint as _crashpoint
 from .event_loop import run_in_fresh_event_loop
 from .telemetry import names as metric_names
+from .telemetry.trace import get_recorder as _trace_recorder
+from .telemetry.trace import op_scope as _op_scope
 from .io_types import ReadIO, StoragePlugin, WriteIO
 from .manifest import (
     ChunkedArrayEntry,
@@ -57,6 +59,7 @@ from .pg_wrapper import PGWrapper
 from .snapshot import SNAPSHOT_METADATA_FNAME, PendingSnapshot, Snapshot
 from .stateful import AppState
 from .storage_plugin import join_path, split_tiered_url, url_to_storage_plugin
+from .utils.tracing import trace_annotation
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -253,20 +256,9 @@ class _PendingManagedSnapshot:
         with self._commit_lock:
             if self._committed:
                 return snapshot
-            self._manager._commit_step(
-                self._step,
-                refs=lambda: referenced_steps(snapshot.metadata.manifest),
-                metric=self._metric,
-                chunk_refs=lambda: _manifest_chunk_refs(
-                    snapshot.metadata.manifest
-                ),
+            self._manager._after_commit(
+                self._step, snapshot, self._metric, self._pending.trace_op
             )
-            telemetry.metrics().counter_inc(metric_names.MANAGER_SAVES_TOTAL)
-            self._manager._record_step_history(self._step)
-            self._manager._post_step_ledger(self._step, snapshot)
-            self._manager._evaluate_slos(self._step)
-            self._manager._publish_cdn_step(self._step, snapshot)
-            self._manager._autotune_step(self._step)
             self._committed = True
         return snapshot
 
@@ -294,7 +286,9 @@ class _ManagedPendingRestore:
         out = self._pending.wait()
         if not self._recorded:
             self._recorded = True
-            self._manager._record_restore_history(self._step)
+            self._manager._record_restore_history(
+                self._step, self._pending.trace_op
+            )
         return out
 
     def done(self) -> bool:
@@ -480,21 +474,41 @@ class CheckpointManager:
         snapshot = Snapshot.take(
             self.step_path(step), app_state, pg=self._pg_arg, **take_kwargs
         )
-        self._commit_step(
-            step,
-            refs=lambda: referenced_steps(snapshot.metadata.manifest),
-            metric=metric,
-            chunk_refs=lambda: _manifest_chunk_refs(
-                snapshot.metadata.manifest
-            ),
-        )
-        telemetry.metrics().counter_inc(metric_names.MANAGER_SAVES_TOTAL)
-        self._record_step_history(step)
-        self._post_step_ledger(step, snapshot)
-        self._evaluate_slos(step)
-        self._publish_cdn_step(step, snapshot)
-        self._autotune_step(step)
+        self._after_commit(step, snapshot, metric, snapshot.trace_op)
         return snapshot
+
+    def _after_commit(
+        self,
+        step: int,
+        snapshot: Snapshot,
+        metric: Optional[float],
+        trace_op: int,
+    ) -> None:
+        """What the manager does for a step once its snapshot is
+        committed, on the thread that called ``save()`` / ``wait()``:
+        index + retention, history / ledger / SLOs, the CDN announce,
+        the tuner's move. Recorded as stages of the take (``trace_op``)
+        that made the step: this is inside the caller's stall."""
+        with _op_scope(trace_op):
+            with trace_annotation(metric_names.SPAN_MANAGER_INDEX, step=step):
+                self._commit_step(
+                    step,
+                    refs=lambda: referenced_steps(snapshot.metadata.manifest),
+                    metric=metric,
+                    chunk_refs=lambda: _manifest_chunk_refs(
+                        snapshot.metadata.manifest
+                    ),
+                )
+            telemetry.metrics().counter_inc(metric_names.MANAGER_SAVES_TOTAL)
+            with trace_annotation(
+                metric_names.SPAN_TELEMETRY_REPORT, kind="step", step=step
+            ):
+                self._record_step_history(step)
+                self._post_step_ledger(step, snapshot)
+                self._evaluate_slos(step)
+            self._publish_cdn_step(step, snapshot)
+            with trace_annotation(metric_names.SPAN_MANAGER_TUNE, step=step):
+                self._autotune_step(step)
 
     @staticmethod
     def _validate_metric(metric: Optional[float]) -> None:
@@ -767,28 +781,34 @@ class CheckpointManager:
         return step
 
     def restore(self, step: int, app_state: AppState) -> None:
-        Snapshot(self.step_path(step), pg=self._pg_arg).restore(app_state)
+        snapshot = Snapshot(self.step_path(step), pg=self._pg_arg)
+        snapshot.restore(app_state)
         telemetry.metrics().counter_inc(metric_names.MANAGER_RESTORES_TOTAL)
-        self._record_restore_history(step)
+        self._record_restore_history(step, snapshot.trace_op)
 
-    def _record_restore_history(self, step: int) -> None:
+    def _record_restore_history(self, step: int, trace_op: int = 0) -> None:
         """Append the just-served restore's telemetry summary to the
         same rolling history takes feed — recovery time is a trend
         metric too (``doctor --trend`` baselines per kind, so restore
-        rows never pollute take baselines). Rank 0 only; best-effort."""
+        rows never pollute take baselines). Rank 0 only; best-effort.
+        Inside the caller's restore time, so a stage of that restore
+        (``trace_op``)."""
         if self._pg.get_rank() != 0:
             return
         try:
             from .telemetry import history, last_report
 
-            report = last_report(
-                "restore", "async_restore", path=self.step_path(step)
-            )
-            if report is None:
-                return
-            history.append_summary(
-                self.root, history.summarize_report(report, step=step)
-            )
+            with _op_scope(trace_op), trace_annotation(
+                metric_names.SPAN_TELEMETRY_REPORT, kind="step", step=step
+            ):
+                report = last_report(
+                    "restore", "async_restore", path=self.step_path(step)
+                )
+                if report is None:
+                    return
+                history.append_summary(
+                    self.root, history.summarize_report(report, step=step)
+                )
         except Exception as e:  # noqa: BLE001 - history is best-effort
             logger.warning(
                 "could not record step %d restore history: %r", step, e
@@ -1023,23 +1043,29 @@ class CheckpointManager:
             registry.counter_inc(
                 metric_names.MANAGER_GC_STEPS_TOTAL, len(to_delete)
             )
-        for old in to_delete:
+        # Recorder-only, like ``pipeline:*``: the span crosses awaits
+        # (utils/tracing.py's rule for the dual annotation).
+        with _trace_recorder().span(
+            metric_names.SPAN_MANAGER_RETENTION, step=step, steps=len(to_delete)
+        ):
+            for old in to_delete:
+                try:
+                    await self._delete_step_async(old)
+                except Exception as e:  # noqa: BLE001 - GC must not fail a save
+                    logger.warning("Failed to GC step %d: %r", old, e)
+            # Chunk-store GC: unpin the deleted steps and reclaim chunks
+            # no pinned step references (grace-window + orphan deferral
+            # inside). Runs AFTER the step deletes so an interrupted pass
+            # errs toward retaining chunks, never toward dangling refs.
+            # Runs on EVERY commit, not only ones that dropped steps —
+            # grace-deferred orphans and crashed takes' strays must age
+            # out even in runs whose retention never deletes anything
+            # (keep-everything, or still inside the first keep_last_n
+            # saves).
             try:
-                await self._delete_step_async(old)
+                await self._cas_collect_async(storage, step, to_delete)
             except Exception as e:  # noqa: BLE001 - GC must not fail a save
-                logger.warning("Failed to GC step %d: %r", old, e)
-        # Chunk-store GC: unpin the deleted steps and reclaim chunks no
-        # pinned step references (grace-window + orphan deferral inside).
-        # Runs AFTER the step deletes so an interrupted pass errs toward
-        # retaining chunks, never toward dangling refs. Runs on EVERY
-        # commit, not only ones that dropped steps — grace-deferred
-        # orphans and crashed takes' strays must age out even in runs
-        # whose retention never deletes anything (keep-everything, or
-        # still inside the first keep_last_n saves).
-        try:
-            await self._cas_collect_async(storage, step, to_delete)
-        except Exception as e:  # noqa: BLE001 - GC must not fail a save
-            logger.warning("CAS chunk GC failed: %r", e)
+                logger.warning("CAS chunk GC failed: %r", e)
 
     async def _derive_refs_async(
         self, storage: StoragePlugin, step: int

@@ -201,12 +201,21 @@ def _digest_jax_impl(x):
     )
 
 
+# The name of both digest programs on a profile's Modules line
+# (``jit_ts_device_digest``) and of their ops' scope.
+_PROGRAM_NAME = "ts_device_digest"
+
+
 @functools.lru_cache(maxsize=1)
 def _digest_jit():
     import jax
 
+    def ts_device_digest(x):
+        with jax.named_scope(_PROGRAM_NAME):
+            return _digest_jax_impl(x)
+
     # jit caches per (shape, dtype) signature; one wrapper suffices.
-    return jax.jit(_digest_jax_impl)
+    return jax.jit(ts_device_digest)
 
 
 def digest_device_async(arr: Any, row_range: Optional[Tuple[int, int]] = None):
@@ -245,17 +254,18 @@ def _digest_many_jit(n_arrays: int, range_specs: Tuple[RangeSpec, ...]):
     import jax
     import jax.numpy as jnp
 
-    def f(arrays):
+    def ts_device_digest(arrays):
         outs = []
-        for x, ranges in zip(arrays, range_specs):
-            if ranges is None:
-                outs.append(_digest_jax_impl(x))
-            else:
-                for a, b in ranges:
-                    outs.append(_digest_jax_impl(x[a:b]))
-        return jnp.stack(outs)
+        with jax.named_scope(_PROGRAM_NAME):
+            for x, ranges in zip(arrays, range_specs):
+                if ranges is None:
+                    outs.append(_digest_jax_impl(x))
+                else:
+                    for a, b in ranges:
+                        outs.append(_digest_jax_impl(x[a:b]))
+            return jnp.stack(outs)
 
-    return jax.jit(f)
+    return jax.jit(ts_device_digest)
 
 
 def digest_many_async(specs: list):

@@ -41,6 +41,7 @@ if TYPE_CHECKING:  # pragma: no cover - typing only
 
 from . import knobs, telemetry
 from .telemetry.trace import get_recorder as _trace_recorder
+from .utils.tracing import run_in_executor, trace_annotation
 from .integrity import (
     ChecksumError,
     ChecksumTable,
@@ -96,6 +97,17 @@ _LOG_LINE_LIMIT = 8
 # the loop well under a millisecond, while an executor round-trip costs
 # ~0.1 ms per request regardless of size.
 _INLINE_CHECKSUM_BYTES = 64 * 1024
+
+
+def _verify_blob(mode: str, blob: str, nbytes: int, verify, *args):
+    """One checksum verification of read bytes under its ``verify:blob``
+    span, on whichever thread runs it (inline or the executor). ``mode``:
+    ``whole`` re-hashes the blob, ``range`` the pages a ranged read
+    covers, ``pages`` folds the CRCs the fused read already computed."""
+    with trace_annotation(
+        telemetry.names.SPAN_VERIFY_BLOB, bytes=nbytes, mode=mode, blob=blob
+    ):
+        return verify(*args)
 
 
 def get_process_memory_budget_bytes(pg=None) -> int:
@@ -545,9 +557,7 @@ async def execute_write_reqs(
         CRC never stalls the event loop."""
         if len(buf) <= _INLINE_CHECKSUM_BYTES:
             return compute_checksum_entry(buf)
-        return await asyncio.get_running_loop().run_in_executor(
-            executor, compute_checksum_entry, buf
-        )
+        return await run_in_executor(executor, compute_checksum_entry, buf)
 
     async def write_one(req: WriteReq, buf) -> None:
         nonlocal fused_declined
@@ -566,7 +576,7 @@ async def execute_write_reqs(
             ):
                 await budget.adjust(buf_len)
                 try:
-                    buf = await asyncio.get_running_loop().run_in_executor(
+                    buf = await run_in_executor(
                         executor, buf.consolidate
                     )
                 finally:
@@ -885,55 +895,51 @@ async def execute_read_reqs(
             # value is handed to the application either way (direct reads
             # land in framework-owned buffers only).
             if entry is not None:
-                loop_ = asyncio.get_running_loop()
 
                 async def _verify_current(
                     cur_buf, use_fused_pages=None
                 ) -> None:
                     verified_from_pages = False
+                    nbytes = memoryview(cur_buf).nbytes
                     if use_fused_pages is not None:
                         # Pure GF(2) fold over the pages read — O(pages),
                         # no second pass over the bytes, no executor hop.
                         # False = this entry needs the bytes (foreign alg
                         # / mismatched interim granularity): verify below.
-                        verified_from_pages = verify_page_crcs(
+                        verified_from_pages = _verify_blob(
+                            "pages",
+                            req.path,
+                            nbytes,
+                            verify_page_crcs,
                             use_fused_pages,
-                            memoryview(cur_buf).nbytes,
+                            nbytes,
                             entry,
                             req.path,
                         )
                     # Small buffers verify inline: the executor
                     # round-trip costs ~0.1 ms against sub-microsecond
                     # hashing (same rationale as checksum_off_slot).
-                    small = (
-                        memoryview(cur_buf).nbytes <= _INLINE_CHECKSUM_BYTES
-                    )
+                    small = nbytes <= _INLINE_CHECKSUM_BYTES
                     if verified_from_pages:
                         pass
                     elif req.byte_range is None:
+                        whole = ("whole", req.path, nbytes, verify_checksum,
+                                 cur_buf, entry, req.path)
                         if small:
-                            verify_checksum(cur_buf, entry, req.path)
+                            _verify_blob(*whole)
                         else:
-                            await loop_.run_in_executor(
-                                executor,
-                                verify_checksum,
-                                cur_buf,
-                                entry,
-                                req.path,
+                            await run_in_executor(
+                                executor, _verify_blob, *whole
                             )
                     else:
+                        ranged = ("range", req.path, nbytes,
+                                  verify_range_checksum, cur_buf, entry,
+                                  req.byte_range, req.path)
                         if small:
-                            page_verified = verify_range_checksum(
-                                cur_buf, entry, req.byte_range, req.path
-                            )
+                            page_verified = _verify_blob(*ranged)
                         else:
-                            page_verified = await loop_.run_in_executor(
-                                executor,
-                                verify_range_checksum,
-                                cur_buf,
-                                entry,
-                                req.byte_range,
-                                req.path,
+                            page_verified = await run_in_executor(
+                                executor, _verify_blob, *ranged
                             )
                         if not page_verified:
                             verify_skipped[0] += 1

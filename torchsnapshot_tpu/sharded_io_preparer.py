@@ -34,7 +34,6 @@ Read side (resharding):
 
 from __future__ import annotations
 
-import asyncio
 from concurrent.futures import Executor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -57,7 +56,7 @@ from .serialization import (
     dtype_to_string,
 )
 from .telemetry import names as metric_names
-from .utils.tracing import trace_annotation
+from .utils.tracing import run_in_executor, trace_annotation
 
 
 # Sentinel: assembly was registered into a placement batch and will land
@@ -92,8 +91,7 @@ class _OverlapConsumer(BufferConsumer):
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
     ) -> None:
-        loop = asyncio.get_running_loop()
-        await loop.run_in_executor(executor, self._consume_sync, buf)
+        await run_in_executor(executor, self._consume_sync, buf)
 
     def _consume_sync(self, buf: BufferType) -> None:
         with trace_annotation(metric_names.SPAN_LEAF_CONSUME):

@@ -43,8 +43,10 @@ from . import knobs, telemetry
 from .telemetry import progress as _progress
 from .telemetry.trace import (
     TraceMark,
+    current_op as _current_op,
     export_op_trace,
     get_recorder as _trace_recorder,
+    op_scope as _op_scope,
 )
 from .dist_store import StoreBarrier, make_barrier
 from .flatten import flatten, inflate
@@ -78,6 +80,8 @@ from .scheduler import (
 )
 from .stateful import AppState, Stateful
 from .storage_plugin import url_to_storage_plugin
+from .utils import tracing as _tracing
+from .utils.tracing import trace_annotation
 from .version import __version__
 
 logger: logging.Logger = logging.getLogger(__name__)
@@ -232,7 +236,18 @@ def _mirror_state_for(path: str) -> Dict[str, Any]:
     return dict(mirror_state_for_path(path) or {})
 
 
-def _emit_snapshot_report(
+def _emit_snapshot_report(kind: str, trace_op: int = 0, **report: Any) -> None:
+    """:func:`_build_and_emit_report` under a ``telemetry:report`` span
+    of operation ``trace_op``: the emission runs after the envelope
+    closed and, for a take or a restore, inside the caller's timed
+    call, so what it costs is a stage of the op like any other."""
+    with _op_scope(trace_op), trace_annotation(
+        telemetry.names.SPAN_TELEMETRY_REPORT, kind=kind
+    ):
+        _build_and_emit_report(kind=kind, trace_op=trace_op, **report)
+
+
+def _build_and_emit_report(
     kind: str,
     path: str,
     pg_wrapper: "PGWrapper",
@@ -242,6 +257,7 @@ def _emit_snapshot_report(
     error: Optional[BaseException] = None,
     trace_mark: Optional[TraceMark] = None,
     tunables: Optional[Dict[str, Any]] = None,
+    trace_op: int = 0,
 ) -> None:
     """Assemble this rank's SnapshotReport, aggregate across ranks, and
     hand it to the sinks. Best-effort — telemetry must never fail a
@@ -280,13 +296,27 @@ def _emit_snapshot_report(
         # rank's dict carries its segments into the cross-rank fold.
         # The envelope span closed before this call (callers end it
         # before emitting), so the window holds the op's full extent.
+        # The op's stage table (who was busy) rides beside the
+        # partition; this emission's own span is still open, so the
+        # table a report carries is without it.
         if trace_mark is not None:
             try:
                 from .telemetry import critpath as _critpath
 
-                report.critical_path = _critpath.critical_path_from_events(
-                    _trace_recorder().events_since(trace_mark), kind
+                events = _trace_recorder().events_since(trace_mark)
+                cp = _critpath.critical_path_from_events(
+                    events, kind, op=trace_op
                 )
+                # Unstamped spans (work outside any context) go in too:
+                # stage_tables counts them by overlap, and the report's
+                # table must be the one any other reader of it gets.
+                table = _critpath.stage_tables(
+                    [e for e in events if e.get("op", 0) in (0, trace_op)]
+                ).get(trace_op)
+                if cp is not None and table is not None:
+                    cp["stages"] = table["stages"]
+                    cp["unattributed_s"] = table["unattributed_s"]
+                report.critical_path = cp
             except Exception as e:  # noqa: BLE001 - attribution is best-effort
                 logger.warning(
                     "telemetry: critical-path attribution failed: %r", e
@@ -388,6 +418,11 @@ class Snapshot:
         self.path = path
         self._pg_arg = pg
         self._metadata: Optional[SnapshotMetadata] = None
+        # The flight recorder's id of the take that made this snapshot or
+        # of the last restore from it (0: neither, in this process): work
+        # done for that operation after it returned (the manager's index,
+        # retention, history) is recorded under it (trace.op_scope).
+        self.trace_op = 0
         # Merged checksum tables, loaded at most once per Snapshot instance
         # (False = not loaded yet; None = no tables / verification disabled).
         self._checksum_table_cache: Any = False
@@ -441,11 +476,11 @@ class Snapshot:
         event_loop = asyncio.new_event_loop()
         counter_baseline = telemetry.metrics().counters_snapshot()
         tunables_at_start = knobs.tunable_snapshot()
-        recorder = _trace_recorder()
-        trace_mark = recorder.mark()
-        take_span = recorder.begin(
+        trace_mark = _trace_recorder().mark()
+        take_span = _tracing.begin(
             telemetry.names.SPAN_TAKE, path=path, rank=pg_wrapper.get_rank()
         )
+        trace_op = _current_op()
         # Live-progress heartbeat for the whole op: external pollers see
         # a stuck rank from outside the process (telemetry/progress.py).
         tracker = _progress.track("take", path, pg_wrapper.get_rank())
@@ -470,15 +505,6 @@ class Snapshot:
                 )
                 pending_io_work.sync_complete(event_loop)
                 _crashpoint(telemetry.names.CRASH_TAKE_WRITES_DONE)
-                pending_io_work.finalize_checksums()
-                _maybe_write_checksum_table(
-                    pending_io_work, pg_wrapper.get_rank(), storage, event_loop
-                )
-                _crashpoint(telemetry.names.CRASH_CHECKSUM_TABLE_WRITTEN)
-                _maybe_write_cas_map(
-                    storage, pg_wrapper.get_rank(), event_loop
-                )
-                _crashpoint(telemetry.names.CRASH_CAS_MAP_WRITTEN)
 
             # All writes are durable on every rank before the commit marker
             # exists anywhere (commit-after-barrier invariant). The commit
@@ -488,20 +514,30 @@ class Snapshot:
             # instead of blocking out the store timeout (the async path's
             # catch-all in PendingSnapshot._complete_snapshot already
             # covers its equivalent window).
-            with _reporting_to(barrier, "commit"):
-                if barrier is not None:
-                    barrier.arrive()
-                if pg_wrapper.get_rank() == 0:
-                    cls._write_snapshot_metadata(metadata, storage, event_loop)
-                if barrier is not None:
-                    barrier.depart()
+            with trace_annotation(
+                telemetry.names.SPAN_COMMIT_FINALIZE, rank=pg_wrapper.get_rank()
+            ):
+                with _reporting_to(barrier, "take"):
+                    _write_checksum_and_cas_tables(
+                        pending_io_work, pg_wrapper.get_rank(), storage,
+                        event_loop,
+                    )
+                with _reporting_to(barrier, "commit"):
+                    if barrier is not None:
+                        barrier.arrive()
+                    if pg_wrapper.get_rank() == 0:
+                        cls._write_snapshot_metadata(
+                            metadata, storage, event_loop
+                        )
+                    if barrier is not None:
+                        barrier.depart()
             # Post-commit: hand this rank's blobs to the peer tier (the
             # committed step is what a replacement rank would restore).
             _maybe_push_to_peer(path, pending_io_work)
             event_loop.run_until_complete(storage.close())
             # The envelope span closes before the report/trace emission
             # so the exported timeline carries the take's full extent.
-            recorder.end(take_span)
+            _tracing.end(take_span)
             # Post-close on purpose: a tiered plugin enqueues its mirror
             # job at close, so the report's mirror state reflects the
             # durability backlog this take just created.
@@ -514,6 +550,7 @@ class Snapshot:
                 nonce=commit_nonce,
                 trace_mark=trace_mark,
                 tunables=tunables_at_start,
+                trace_op=trace_op,
             )
         except BaseException as e:
             op_error = e
@@ -522,10 +559,11 @@ class Snapshot:
             # Success removes the heartbeat file; failure leaves a
             # terminal document (doctor evidence the op *ended*).
             tracker.finish(op_error)
-            recorder.end(take_span)  # no-op if already closed
+            _tracing.end(take_span)  # no-op if already closed
             event_loop.close()
         snapshot = cls(path=path, pg=pg)
         snapshot._metadata = metadata
+        snapshot.trace_op = trace_op
         return snapshot
 
     @classmethod
@@ -584,19 +622,20 @@ class Snapshot:
         event_loop = asyncio.new_event_loop()
         counter_baseline = telemetry.metrics().counters_snapshot()
         tunables_at_start = knobs.tunable_snapshot()
-        recorder = _trace_recorder()
-        trace_mark = recorder.mark()
+        trace_mark = _trace_recorder().mark()
         storage = _maybe_cas_storage(
             url_to_storage_plugin(path), path, cas_on
         )
         tracker = _progress.track("async_take", path, pg_wrapper.get_rank())
         defer_staging = knobs.is_async_device_snapshot_enabled()
         try:
-            with recorder.span(
+            with _tracing.op_annotation(
                 telemetry.names.SPAN_ASYNC_TAKE_STAGE,
                 path=path,
                 rank=pg_wrapper.get_rank(),
             ), _reporting_to(barrier, "async take staging"):
+                # The commit envelope, on its own thread, joins this op.
+                trace_op = _current_op()
                 pending_io_work, metadata = cls._take_impl(
                     path=path,
                     app_state=app_state,
@@ -634,34 +673,26 @@ class Snapshot:
             progress_tracker=tracker,
             op_begin=op_begin,
             tunables=tunables_at_start,
+            trace_op=trace_op,
         )
 
     @classmethod
-    def _take_impl(
+    def _plan_take(
         cls,
         path: str,
         app_state: AppState,
         pg_wrapper: PGWrapper,
         replicated: List[str],
-        storage: StoragePlugin,
-        event_loop: asyncio.AbstractEventLoop,
         is_async_snapshot: bool,
-        incremental_base: Optional[Any] = None,
-        record_digests: bool = False,
-        _custom_array_prepare_func=None,
-        progress_tracker: Optional[_progress.ProgressTracker] = None,
-        defer_staging: bool = False,
-    ) -> Tuple["PendingIOWork | DeferredIOWork", Optional[SnapshotMetadata]]:
-        """Shared take core (reference snapshot.py:316-440). The returned
-        metadata is None on non-leader ranks (manifests gather to rank 0
-        only; see :func:`_gather_manifest`).
-
-        With ``defer_staging`` (device-snapshot async takes), no staging
-        runs here: the write plan's sources are captured (on-device
-        clones / host copies) and the returned :class:`DeferredIOWork`
-        runs the whole pool-bounded pipeline on the background commit
-        thread. Collectives still all happen on this (the calling)
-        thread either way."""
+        incremental_base: Optional[Any],
+        record_digests: bool,
+        _custom_array_prepare_func,
+    ) -> Tuple[List[WriteReq], Optional[SnapshotMetadata], int, Optional[Any]]:
+        """A take's plan, before any byte moves: capture the state dicts,
+        flatten, prepare / partition / batch the write requests, agree on
+        the budget and gather the manifest. Returns the write requests,
+        the metadata (None off rank 0), the memory budget and the
+        incremental context (None for a full take)."""
         _validate_app_state(app_state)
         rank = pg_wrapper.get_rank()
         world_size = pg_wrapper.get_world_size()
@@ -783,6 +814,48 @@ class Snapshot:
             if global_manifest is not None
             else None
         )
+        return write_reqs, metadata, memory_budget_bytes, incr_ctx
+
+    @classmethod
+    def _take_impl(
+        cls,
+        path: str,
+        app_state: AppState,
+        pg_wrapper: PGWrapper,
+        replicated: List[str],
+        storage: StoragePlugin,
+        event_loop: asyncio.AbstractEventLoop,
+        is_async_snapshot: bool,
+        incremental_base: Optional[Any] = None,
+        record_digests: bool = False,
+        _custom_array_prepare_func=None,
+        progress_tracker: Optional[_progress.ProgressTracker] = None,
+        defer_staging: bool = False,
+    ) -> Tuple["PendingIOWork | DeferredIOWork", Optional[SnapshotMetadata]]:
+        """Shared take core (reference snapshot.py:316-440). The returned
+        metadata is None on non-leader ranks (manifests gather to rank 0
+        only; see :func:`_gather_manifest`).
+
+        With ``defer_staging`` (device-snapshot async takes), no staging
+        runs here: the write plan's sources are captured (on-device
+        clones / host copies) and the returned :class:`DeferredIOWork`
+        runs the whole pool-bounded pipeline on the background commit
+        thread. Collectives still all happen on this (the calling)
+        thread either way."""
+        rank = pg_wrapper.get_rank()
+        with trace_annotation(telemetry.names.SPAN_TAKE_PLAN, rank=rank):
+            write_reqs, metadata, memory_budget_bytes, incr_ctx = (
+                cls._plan_take(
+                    path=path,
+                    app_state=app_state,
+                    pg_wrapper=pg_wrapper,
+                    replicated=replicated,
+                    is_async_snapshot=is_async_snapshot,
+                    incremental_base=incremental_base,
+                    record_digests=record_digests,
+                    _custom_array_prepare_func=_custom_array_prepare_func,
+                )
+            )
 
         if defer_staging:
             # Device-snapshot point: pin every write source (on-device
@@ -791,8 +864,7 @@ class Snapshot:
             # hand the un-staged plan to the background drain. From the
             # caller's return onward the live arrays are free to be
             # mutated, donated, or deleted.
-            recorder = _trace_recorder()
-            with recorder.span(
+            with trace_annotation(
                 telemetry.names.SPAN_DEVICE_CAPTURE,
                 rank=rank,
                 reqs=len(write_reqs),
@@ -948,11 +1020,11 @@ class Snapshot:
             )
         counter_baseline = telemetry.metrics().counters_snapshot()
         tunables_at_start = knobs.tunable_snapshot()
-        recorder = _trace_recorder()
-        trace_mark = recorder.mark()
-        restore_span = recorder.begin(
+        trace_mark = _trace_recorder().mark()
+        restore_span = _tracing.begin(
             telemetry.names.SPAN_RESTORE, path=self.path, rank=rank
         )
+        self.trace_op = _current_op()
         tracker = _progress.track("restore", self.path, rank)
         op_error: Optional[BaseException] = None
         pipeline_sink: List[dict] = []
@@ -1010,8 +1082,11 @@ class Snapshot:
             setup_barrier = key_barrier(0) if keys else None
             fanout_ctx = None
             with _reporting_to(setup_barrier, "restore setup"):
-                available = get_manifest_for_rank(self.metadata, rank)
-                checksum_table = self._get_checksum_table(storage, event_loop)
+                with trace_annotation(telemetry.names.SPAN_RESTORE_PLAN):
+                    available = get_manifest_for_rank(self.metadata, rank)
+                    checksum_table = self._get_checksum_table(
+                        storage, event_loop
+                    )
                 # Single-reader fan-out (docs/restore.md): enablement was
                 # broadcast-agreed above; the owner table is derived
                 # deterministically from the committed manifest (same
@@ -1090,7 +1165,7 @@ class Snapshot:
                     progress_tracker=tracker,
                 )
             event_loop.run_until_complete(storage.close())
-            recorder.end(restore_span)
+            _tracing.end(restore_span)
             pipeline = telemetry.merge_pipeline_telemetry(pipeline_sink)
             _merge_fanout_telemetry(pipeline, fanout_ctx)
             _merge_peer_telemetry(pipeline, peer_ctx)
@@ -1110,13 +1185,14 @@ class Snapshot:
                 nonce=restore_nonce,
                 trace_mark=trace_mark,
                 tunables=tunables_at_start,
+                trace_op=self.trace_op,
             )
         except BaseException as e:
             op_error = e
             raise
         finally:
             tracker.finish(op_error)
-            recorder.end(restore_span)  # no-op if already closed
+            _tracing.end(restore_span)  # no-op if already closed
             event_loop.close()
 
     def async_restore(self, app_state: AppState) -> "PendingRestore":
@@ -1144,6 +1220,23 @@ class Snapshot:
         pg_wrapper = PGWrapper(self._pg_arg)
         rank = pg_wrapper.get_rank()
         trace_mark = _trace_recorder().mark()
+        # The op's first envelope: capture, planning and the exchange run
+        # on the calling thread; the read thread's envelope joins it.
+        with _tracing.op_annotation(
+            telemetry.names.SPAN_ASYNC_RESTORE_PLAN, path=self.path, rank=rank
+        ):
+            return self._start_async_restore(
+                app_state, pg_wrapper, rank, trace_mark, _current_op()
+            )
+
+    def _start_async_restore(
+        self,
+        app_state: AppState,
+        pg_wrapper: PGWrapper,
+        rank: int,
+        trace_mark: TraceMark,
+        trace_op: int,
+    ) -> "PendingRestore":
         memory_budget_bytes = get_process_memory_budget_bytes(pg_wrapper)
 
         rng_key_and_state = _pop_rng_state(app_state)
@@ -1269,6 +1362,7 @@ class Snapshot:
             tunables=knobs.tunable_snapshot(),
             fanout_ctx=fanout_ctx,
             peer_ctx=peer_ctx,
+            trace_op=trace_op,
         )
 
     def _load_stateful(
@@ -1354,8 +1448,11 @@ class Snapshot:
         if pipeline_sink is not None:
             pipeline_sink.append(pipeline_telemetry)
         placer.flush()
-        plan.finish_reads()
-        plan.apply()
+        with trace_annotation(
+            telemetry.names.SPAN_RESTORE_APPLY, stateful=plan.key
+        ):
+            plan.finish_reads()
+            plan.apply()
 
     def _plan_stateful_load(
         self,
@@ -1367,6 +1464,20 @@ class Snapshot:
         """Pure planning for one stateful's restore: captures its current
         state dict, picks/allocates read destinations, builds read
         requests + deferred conversions. No storage I/O happens here."""
+        with trace_annotation(
+            telemetry.names.SPAN_RESTORE_PLAN, stateful=key
+        ):
+            return self._plan_stateful_load_impl(
+                key, stateful, available, memory_budget_bytes
+            )
+
+    def _plan_stateful_load_impl(
+        self,
+        key: str,
+        stateful: Stateful,
+        available: Manifest,
+        memory_budget_bytes: int,
+    ) -> Optional["_StatefulLoadPlan"]:
         from .flatten import _encode
 
         encoded_key = _encode(key)
@@ -1612,12 +1723,21 @@ class _PlacementBatch:
         self._deferred.append(fn)
 
     def run(self) -> None:
-        if self._values:
-            import jax
+        if not self._values and not self._deferred:
+            return
+        # On the thread that calls it: the scheduler's event loop for a
+        # streamed flush (reads wait behind it), else the restore's own.
+        with trace_annotation(
+            telemetry.names.SPAN_RESTORE_PLACE,
+            arrays=len(self._values),
+            bytes=sum(int(getattr(v, "nbytes", 0)) for v in self._values),
+        ):
+            if self._values:
+                import jax
 
-            self._results = jax.device_put(self._values, self._targets)
-        for fn in self._deferred:
-            fn()
+                self._results = jax.device_put(self._values, self._targets)
+            for fn in self._deferred:
+                fn()
         self._values, self._targets, self._deferred = [], [], []
 
 
@@ -1799,10 +1919,15 @@ class PendingSnapshot:
         progress_tracker: Optional[_progress.ProgressTracker] = None,
         op_begin: Optional[float] = None,
         tunables: Optional[Dict[str, Any]] = None,
+        trace_op: int = 0,
     ) -> None:
         import threading
 
         self.path = path
+        # The flight recorder's id of this take (the stage envelope's):
+        # the commit envelope joins it, and so does what the manager
+        # does for the step in wait().
+        self.trace_op = trace_op
         self.commit_nonce = commit_nonce
         self.pg = pg_wrapper
         self._metadata = metadata
@@ -1844,9 +1969,9 @@ class PendingSnapshot:
 
     def _complete_snapshot(self) -> None:
         barrier = None
-        recorder = _trace_recorder()
-        commit_span = recorder.begin(
+        commit_span = _tracing.begin(
             telemetry.names.SPAN_ASYNC_TAKE_COMMIT,
+            op=self.trace_op,
             path=self.path,
             rank=self.pg.get_rank(),
         )
@@ -1856,32 +1981,29 @@ class PendingSnapshot:
             )
             self._pending_io_work.sync_complete(self._event_loop)
             _crashpoint(telemetry.names.CRASH_TAKE_WRITES_DONE)
-            self._pending_io_work.finalize_checksums()
-            _maybe_write_checksum_table(
-                self._pending_io_work,
-                self.pg.get_rank(),
-                self._storage,
-                self._event_loop,
-            )
-            _crashpoint(telemetry.names.CRASH_CHECKSUM_TABLE_WRITTEN)
-            _maybe_write_cas_map(
-                self._storage, self.pg.get_rank(), self._event_loop
-            )
-            _crashpoint(telemetry.names.CRASH_CAS_MAP_WRITTEN)
-            if barrier is not None:
-                barrier.arrive()
-            if self.pg.get_rank() == 0:
-                Snapshot._write_snapshot_metadata(
-                    self._metadata, self._storage, self._event_loop
+            with trace_annotation(
+                telemetry.names.SPAN_COMMIT_FINALIZE, rank=self.pg.get_rank()
+            ):
+                _write_checksum_and_cas_tables(
+                    self._pending_io_work,
+                    self.pg.get_rank(),
+                    self._storage,
+                    self._event_loop,
                 )
-            if barrier is not None:
-                barrier.depart()
+                if barrier is not None:
+                    barrier.arrive()
+                if self.pg.get_rank() == 0:
+                    Snapshot._write_snapshot_metadata(
+                        self._metadata, self._storage, self._event_loop
+                    )
+                if barrier is not None:
+                    barrier.depart()
             # Post-commit peer push, same hook as the sync take's: the
             # enqueue is queue-put cheap and the job runs on the peer
             # replicator's own worker, not this commit thread.
             _maybe_push_to_peer(self.path, self._pending_io_work)
             self._event_loop.run_until_complete(self._storage.close())
-            recorder.end(commit_span)
+            _tracing.end(commit_span)
             # Store-based gather + local file append only — safe on this
             # background thread (no collectives), same rule the commit
             # barrier follows. Post-close so a tiered take's report sees
@@ -1901,6 +2023,7 @@ class PendingSnapshot:
                 nonce=self.commit_nonce,
                 trace_mark=self._trace_mark,
                 tunables=self._tunables,
+                trace_op=self.trace_op,
             )
         except BaseException as e:  # noqa: BLE001 - must propagate via wait()
             # Record the failure before telling peers: report_error talks to
@@ -1922,7 +2045,7 @@ class PendingSnapshot:
             # final state, exactly once, not a half-settled one.
             if self._progress_tracker is not None:
                 self._progress_tracker.finish(self._exc_info)
-            recorder.end(commit_span)  # no-op if already closed
+            _tracing.end(commit_span)  # no-op if already closed
             self._event_loop.close()
             self._staged.set()  # no-op if staging completed normally
             self._done.set()
@@ -1997,6 +2120,7 @@ class PendingRestore:
         tunables: Optional[Dict[str, Any]] = None,
         fanout_ctx=None,
         peer_ctx=None,
+        trace_op: int = 0,
     ) -> None:
         import threading
 
@@ -2025,6 +2149,9 @@ class PendingRestore:
             "async_restore", path, rank
         )
         self._pipeline_telemetry: Optional[dict] = None
+        # The op async_restore's planning envelope opened; the reads
+        # envelope joins it, and wait() applies and reports under it.
+        self.trace_op = trace_op
         self._exc_info: Optional[BaseException] = None
         self._applied = False
         self._done = threading.Event()
@@ -2035,11 +2162,14 @@ class PendingRestore:
 
     def _run_reads(self) -> None:
         event_loop = asyncio.new_event_loop()
-        reads_span = _trace_recorder().begin(
+        reads_span = _tracing.begin(
             telemetry.names.SPAN_ASYNC_RESTORE_READS,
+            op=self.trace_op,
             path=self.path,
             rank=self._rank,
         )
+        # A handle made without a planning envelope starts the op here.
+        self.trace_op = _current_op()
         try:
             storage = url_to_storage_plugin(self.path)
             if self._peer_ctx is not None:
@@ -2103,7 +2233,7 @@ class PendingRestore:
             if self._fanout_ctx is not None:
                 self._fanout_ctx.clear()
             self._progress_tracker.finish(self._exc_info)
-            _trace_recorder().end(reads_span)
+            _tracing.end(reads_span)
             event_loop.close()
             self._done.set()
 
@@ -2151,7 +2281,7 @@ class PendingRestore:
             with _reporting_to(barrier, "restore-apply"):
                 plan = self._plans.get(key)
                 if plan is not None and key != self._rng_key:
-                    plan.apply()
+                    self._apply(plan)
             # load_state_dict may run collectives; keep global order
             # (reference snapshot.py:466-476 barrier discipline).
             if barrier is not None:
@@ -2161,7 +2291,7 @@ class PendingRestore:
                 self._pg.barrier()
         rng_plan = self._plans.get(self._rng_key) if self._rng_key else None
         if rng_plan is not None:
-            rng_plan.apply()
+            self._apply(rng_plan)
         # Applied only if every plan succeeded: a raised apply leaves the
         # handle un-applied, so a retried wait() re-applies from the start
         # (deterministic) instead of silently succeeding half-restored.
@@ -2178,10 +2308,17 @@ class PendingRestore:
             nonce=None,
             trace_mark=self._trace_mark,
             tunables=self._tunables,
+            trace_op=self.trace_op,
         )
         # Release the checkpoint-sized host buffers the plans hold; the
         # handle itself may outlive the restore (done()-polling callers).
         self._plans = {}
+
+    def _apply(self, plan: _StatefulLoadPlan) -> None:
+        with _op_scope(self.trace_op), trace_annotation(
+            telemetry.names.SPAN_RESTORE_APPLY, stateful=plan.key
+        ):
+            plan.apply()
 
     def done(self) -> bool:
         """True once background reads finished (wait() will not block)."""
@@ -2394,6 +2531,22 @@ def _maybe_write_checksum_table(
     sync_write_checksum_table(
         pending_io_work.checksums, rank, storage, event_loop
     )
+
+
+def _write_checksum_and_cas_tables(
+    pending_io_work: "PendingIOWork | DeferredIOWork",
+    rank: int,
+    storage: StoragePlugin,
+    event_loop: asyncio.AbstractEventLoop,
+) -> None:
+    """What a rank makes durable between its last blob and the commit
+    barrier, sync take and async commit thread alike: the (finalized)
+    checksum table, then the CAS chunk map, a kill point after each."""
+    pending_io_work.finalize_checksums()
+    _maybe_write_checksum_table(pending_io_work, rank, storage, event_loop)
+    _crashpoint(telemetry.names.CRASH_CHECKSUM_TABLE_WRITTEN)
+    _maybe_write_cas_map(storage, rank, event_loop)
+    _crashpoint(telemetry.names.CRASH_CAS_MAP_WRITTEN)
 
 
 def _restore_destination(

@@ -15,7 +15,6 @@ only after all data writes return).
 
 from __future__ import annotations
 
-import asyncio
 import logging
 import os
 import time
@@ -46,7 +45,7 @@ from ..io_types import (
 )
 from ..telemetry import names as metric_names, observe_io
 from ..telemetry.trace import io_span
-from ..utils.tracing import trace_annotation
+from ..utils.tracing import run_in_executor, trace_annotation
 
 # O_DIRECT is for LARGE writes: below this the page-cache copy is noise
 # and the alignment bookkeeping isn't worth a syscall pattern change.
@@ -121,8 +120,7 @@ class FSStoragePlugin(StoragePlugin):
             if aiofiles is not None:
                 await aiofiles.os.makedirs(parent, exist_ok=True)
             else:
-                loop = asyncio.get_running_loop()
-                await loop.run_in_executor(
+                await run_in_executor(
                     None, lambda: os.makedirs(parent, exist_ok=True)
                 )
             self._dir_cache.add(parent)
@@ -139,7 +137,6 @@ class FSStoragePlugin(StoragePlugin):
         await self._ensure_parent_dir(full_path)
         buf = write_io.buf
         if self._native:
-            loop = asyncio.get_running_loop()
             # buf stays referenced by write_io for the call's duration.
             # The native kernels return None/False (wrote nothing) if the
             # lib became unavailable after construction — fall through.
@@ -155,7 +152,7 @@ class FSStoragePlugin(StoragePlugin):
                             is not None
                         )
 
-                if await loop.run_in_executor(None, _writev_native):
+                if await run_in_executor(None, _writev_native):
                     write_io.variant = "vectorized"
                     telemetry.metrics().counter_inc(
                         metric_names.FS_VECTORIZED_WRITE_BYTES_TOTAL,
@@ -166,7 +163,7 @@ class FSStoragePlugin(StoragePlugin):
             else:
                 if self._direct_eligible(buf):
                     try:
-                        if await loop.run_in_executor(
+                        if await run_in_executor(
                             None, self._write_direct_kernel, full_path, write_io
                         ):
                             return
@@ -181,7 +178,7 @@ class FSStoragePlugin(StoragePlugin):
                     ):
                         return _native.write_file(full_path, buf)
 
-                if await loop.run_in_executor(None, _write_native):
+                if await run_in_executor(None, _write_native):
                     write_io.variant = "buffered"
                     return
         if isinstance(buf, BufferList):
@@ -199,7 +196,7 @@ class FSStoragePlugin(StoragePlugin):
                     for part in buf.parts:
                         f.write(part)
 
-            await asyncio.get_running_loop().run_in_executor(
+            await run_in_executor(
                 None, _writev_blocking
             )
             return
@@ -213,7 +210,7 @@ class FSStoragePlugin(StoragePlugin):
             with open(full_path, "wb") as f:
                 f.write(buf)
 
-        await asyncio.get_running_loop().run_in_executor(
+        await run_in_executor(
             None, _write_blocking
         )
 
@@ -252,7 +249,6 @@ class FSStoragePlugin(StoragePlugin):
 
         full_path = self._full_path(write_io.path)
         await self._ensure_parent_dir(full_path)
-        loop = asyncio.get_running_loop()
         buf = write_io.buf
         nbytes = payload_nbytes(buf)
 
@@ -302,17 +298,17 @@ class FSStoragePlugin(StoragePlugin):
         with io_span("fs", "write", write_io.path, nbytes):
             entry = None
             if isinstance(buf, BufferList):
-                entry = await loop.run_in_executor(None, _writev_crc)
+                entry = await run_in_executor(None, _writev_crc)
             else:
                 if self._direct_eligible(buf):
                     try:
-                        entry = await loop.run_in_executor(None, _direct_crc)
+                        entry = await run_in_executor(None, _direct_crc)
                     except OSError as e:
                         if e.errno not in _DIRECT_DECLINE_ERRNOS:
                             raise
                         self._decline_direct(e, write_io.path)
                 if entry is None:
-                    entry = await loop.run_in_executor(None, _write_crc)
+                    entry = await run_in_executor(None, _write_crc)
         if entry is not None:
             # A declined fused write wrote nothing; the scheduler's
             # two-step fallback lands in write(), which accounts itself.
@@ -333,8 +329,7 @@ class FSStoragePlugin(StoragePlugin):
     async def _read_dispatch(self, read_io: ReadIO) -> None:
         full_path = self._full_path(read_io.path)
         if self._native:
-            loop = asyncio.get_running_loop()
-            data = await loop.run_in_executor(
+            data = await run_in_executor(
                 None, self._native_read, full_path, read_io
             )
             if data is not None:
@@ -362,7 +357,7 @@ class FSStoragePlugin(StoragePlugin):
                     f.seek(start)
                     return f.read(end - start)
 
-            data = await asyncio.get_running_loop().run_in_executor(
+            data = await run_in_executor(
                 None, _read_blocking
             )
         if read_io.byte_range is not None:
@@ -390,7 +385,6 @@ class FSStoragePlugin(StoragePlugin):
         from ..integrity import PAGE_SIZE
 
         full_path = self._full_path(read_io.path)
-        loop = asyncio.get_running_loop()
 
         def _read_crc():
             with trace_annotation(
@@ -410,7 +404,7 @@ class FSStoragePlugin(StoragePlugin):
 
         t0 = time.monotonic()
         with io_span("fs", "read", read_io.path):
-            res = await loop.run_in_executor(None, _read_crc)
+            res = await run_in_executor(None, _read_crc)
         if res is None:
             return None
         out, pages = res
@@ -456,7 +450,7 @@ class FSStoragePlugin(StoragePlugin):
         if aiofiles is not None:
             await aiofiles.os.remove(self._full_path(path))
             return
-        await asyncio.get_running_loop().run_in_executor(
+        await run_in_executor(
             None, os.remove, self._full_path(path)
         )
 

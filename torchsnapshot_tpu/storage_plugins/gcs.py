@@ -28,7 +28,7 @@ from ..io_types import ReadIO, StoragePlugin, WriteIO
 from ..telemetry import names as metric_names
 from ..telemetry import observe_io
 from ..telemetry.trace import get_recorder as _trace_recorder, io_span
-from ..utils.tracing import trace_annotation
+from ..utils.tracing import run_in_executor, trace_annotation
 from .retry import CollectiveProgressRetryStrategy
 
 logger = logging.getLogger(__name__)
@@ -273,11 +273,10 @@ class GCSStoragePlugin(StoragePlugin):
     # ------------------------------------------------------------------
 
     async def write(self, write_io: WriteIO) -> None:
-        loop = asyncio.get_running_loop()
         data = bytes(write_io.buf)
 
         async def op() -> None:
-            await loop.run_in_executor(
+            await run_in_executor(
                 self._executor, self._upload_sync, write_io.path, data
             )
 
@@ -286,10 +285,9 @@ class GCSStoragePlugin(StoragePlugin):
         observe_io("gcs", "write", len(data), time.monotonic() - t0)
 
     async def read(self, read_io: ReadIO) -> None:
-        loop = asyncio.get_running_loop()
 
         async def op() -> bytes:
-            return await loop.run_in_executor(
+            return await run_in_executor(
                 self._executor,
                 self._download_sync,
                 read_io.path,
@@ -301,10 +299,9 @@ class GCSStoragePlugin(StoragePlugin):
         observe_io("gcs", "read", read_io.buf.nbytes, time.monotonic() - t0)
 
     async def delete(self, path: str) -> None:
-        loop = asyncio.get_running_loop()
 
         async def op() -> None:
-            await loop.run_in_executor(self._executor, self._delete_sync, path)
+            await run_in_executor(self._executor, self._delete_sync, path)
 
         await self._run_retrying(op)
 

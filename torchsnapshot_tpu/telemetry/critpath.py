@@ -17,6 +17,17 @@ double-charges every overlapped second. This module closes that gap:
   partition is exhaustive by construction (envelope-only time lands in
   ``other``), so the segment sums cover >= 95% of op wall — the
   per-stage attribution ByteCheckpoint-style pipeline tuning needs.
+- **Stage tables.** The partition says what the op was *inside*; it
+  does not say who was *busy*. With tens of reads or stagings in flight
+  "the most recently begun span" is whichever request began last.
+  :func:`stage_tables` is the other view, per op and per span name: the
+  wall seconds at least one such span was open (``busy_s``), their
+  summed durations (``thread_s``), the ratio of the two (the stage's
+  parallelism) and the envelope wall no span of the op covers
+  (``unattributed_s``). The stage whose busy time is the op's wall sets
+  its pace. Spans are selected by the op id the recorder stamps on
+  them, so an async commit that drains into the next take is told
+  apart from it.
 - **Cross-process descent.** The same sweep over a *merged* Chrome
   trace (trace.merge_traces) descends through the wire observatory's
   stitched client->handler pairs: an interval gated by a ``wire:rpc``
@@ -63,6 +74,14 @@ SEG_WIRE = "wire"
 SEG_MIRROR = "mirror"
 SEG_PEER = "peer"
 SEG_CDN = "cdn"
+# Host work around the pipelines: flatten / partition / batch /
+# prepare_write of a take and metadata / checksum-table / destination
+# planning of a restore; checksum table + manifest + marker (and the
+# manager's index, retention and tuning); device_put and
+# load_state_dict of a restore.
+SEG_PLAN = "plan"
+SEG_COMMIT = "commit"
+SEG_PLACEMENT = "placement"
 # Envelope-only time: the op span was open but no instrumented child
 # was — scheduling gaps, uninstrumented Python. A named segment (it
 # counts toward coverage); a LARGE ``other`` share is itself a finding
@@ -73,10 +92,16 @@ SEG_OTHER = "other"
 # spans gating nothing) attribute to ``other`` rather than erroring:
 # the engine must survive spans younger than itself.
 _SEGMENT_BY_SPAN: Dict[str, str] = {
+    names.SPAN_TAKE_PLAN: SEG_PLAN,
+    names.SPAN_RESTORE_PLAN: SEG_PLAN,
     names.SPAN_DEVICE_CAPTURE: SEG_DEVICE_CAPTURE,
+    names.SPAN_CAPTURE_CLONE: SEG_DEVICE_CAPTURE,
+    names.SPAN_CAPTURE_HOST_COPY: SEG_DEVICE_CAPTURE,
+    names.SPAN_CAPTURE_OBJECT: SEG_DEVICE_CAPTURE,
     names.SPAN_PIPELINE_BUDGET_ACQUIRE: SEG_BUDGET_WAIT,
     names.SPAN_PIPELINE_STAGE: SEG_STAGING,
     names.SPAN_LEAF_STAGE: SEG_STAGING,
+    names.SPAN_STAGE_D2H: SEG_STAGING,
     names.SPAN_BATCHER_STAGE_SLAB: SEG_STAGING,
     names.SPAN_BATCHER_STAGE_SLAB_VECTORIZED: SEG_STAGING,
     names.SPAN_PIPELINE_WRITE_DRAIN: SEG_WRITE_DRAIN,
@@ -89,6 +114,14 @@ _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_BATCHER_CONSUME_SPANNING: SEG_READ_DRAIN,
     names.SPAN_STORAGE_READ: SEG_READ_DRAIN,
     names.SPAN_FS_NATIVE_READ: SEG_READ_DRAIN,
+    # BENCHMARK.json's layer for read_drain is "read + verify".
+    names.SPAN_VERIFY_BLOB: SEG_READ_DRAIN,
+    names.SPAN_RESTORE_PLACE: SEG_PLACEMENT,
+    names.SPAN_RESTORE_APPLY: SEG_PLACEMENT,
+    names.SPAN_COMMIT_FINALIZE: SEG_COMMIT,
+    names.SPAN_MANAGER_INDEX: SEG_COMMIT,
+    names.SPAN_MANAGER_RETENTION: SEG_COMMIT,
+    names.SPAN_MANAGER_TUNE: SEG_COMMIT,
     names.SPAN_BARRIER_ARRIVE: SEG_COORDINATION,
     names.SPAN_BARRIER_DEPART: SEG_COORDINATION,
     names.SPAN_FANOUT_EXCHANGE: SEG_COORDINATION,
@@ -115,16 +148,26 @@ _ENVELOPES_BY_KIND: Dict[str, Tuple[str, ...]] = {
         names.SPAN_ASYNC_TAKE_STAGE,
         names.SPAN_ASYNC_TAKE_COMMIT,
     ),
-    "async_restore": (names.SPAN_ASYNC_RESTORE_READS,),
+    "async_restore": (
+        names.SPAN_ASYNC_RESTORE_PLAN,
+        names.SPAN_ASYNC_RESTORE_READS,
+    ),
     "mirror": (names.SPAN_MIRROR_JOB,),
 }
 _ALL_ENVELOPE_NAMES = frozenset(
     n for ns in _ENVELOPES_BY_KIND.values() for n in ns
 )
+_KIND_BY_ENVELOPE: Dict[str, str] = {
+    n: kind for kind, ns in _ENVELOPES_BY_KIND.items() for n in ns
+}
 
 # Evidence spans cited per critical_path result (the blocking chain's
-# heaviest members), and the coverage the acceptance bar requires.
-EVIDENCE_TOP_N = 5
+# heaviest members), and the coverage the acceptance bar requires. One
+# evidence row per (segment, span name): eight, not the five of before
+# the stage spans, because plan / verify / place / apply / finalize now
+# gate intervals under names of their own and would push a short op's
+# fifth-heaviest span (a peer pull, a wire RPC) off the list.
+EVIDENCE_TOP_N = 8
 MIN_COVERAGE = 0.95
 
 
@@ -207,13 +250,9 @@ def _sweep(
         overlap = _overlap_us(lo, hi, windows)
         if overlap <= 0:
             continue
-        gating = None
-        for s in active.values():
-            if gating is None or (s["ts"], s["order"]) > (
-                gating["ts"],
-                gating["order"],
-            ):
-                gating = s
+        # ``active`` fills in begin order, so its last entry is the most
+        # recently begun span still open.
+        gating = next(reversed(active.values()), None)
         if gating is None:
             seg, name, args = SEG_OTHER, "", {}
         else:
@@ -270,24 +309,33 @@ def _assemble(
 
 
 def critical_path_from_events(
-    events: Sequence[Dict[str, Any]], kind: str
+    events: Sequence[Dict[str, Any]], kind: str, op: int = 0
 ) -> Optional[Dict[str, Any]]:
     """The ``critical_path`` field for one op, from the flight
     recorder's window (``recorder.events_since(mark)`` — completed "X"
     events, ts/dur in unix-epoch us, begin order in ``bseq``). None
     when the window holds no envelope span for ``kind`` (trace ring
-    overrun, or an op that never opened its envelope)."""
+    overrun, or an op that never opened its envelope).
+
+    With ``op`` (the recorder's op id) only that operation's envelopes
+    bound the wall. Spans stamped with another operation's id never
+    gate; spans without one (old traces, work outside any envelope) are
+    taken by time overlap, as before the recorder stamped ids."""
     env_names = _ENVELOPES_BY_KIND.get(kind)
     if not env_names:
         return None
     envelopes: List[Tuple[int, int]] = []
+    ops = set()
     candidates: List[Dict[str, Any]] = []
     for e in events:
         if e.get("ph") != "X":
             continue
         name = e["name"]
         if name in env_names:
+            if op and e.get("op", 0) not in (0, op):
+                continue
             envelopes.append((e["ts"], e["ts"] + e["dur"]))
+            ops.add(e.get("op", 0))
             continue
         if name in _ALL_ENVELOPE_NAMES:
             # Another op's envelope overlapping this window (async
@@ -300,9 +348,13 @@ def critical_path_from_events(
                 "ts": e["ts"],
                 "dur": e["dur"],
                 "order": e.get("bseq", 0),
+                "op": e.get("op", 0),
                 "args": e.get("args") or {},
             }
         )
+    ops.discard(0)
+    if ops:
+        candidates = [c for c in candidates if not c["op"] or c["op"] in ops]
     windows = _merge_intervals(envelopes)
     wall_us = sum(hi - lo for lo, hi in windows)
     segments, evidence = _sweep(candidates, windows)
@@ -405,6 +457,138 @@ def critical_path_from_doc(
     if gap > 1e-9:
         segments[SEG_OTHER] = segments.get(SEG_OTHER, 0.0) + gap
     return _assemble(segments, evidence, wall_us)
+
+
+# ---------------------------------------------------------------------------
+# Stage tables: who was busy, per op
+# ---------------------------------------------------------------------------
+
+
+def _max_open(intervals: List[Tuple[int, int]]) -> int:
+    """The most intervals open at one instant (an end at ``t`` closes
+    before a begin at ``t`` opens)."""
+    edges = sorted(
+        [(lo, 1) for lo, _ in intervals] + [(hi, -1) for _, hi in intervals]
+    )
+    depth = peak = 0
+    for _, step in edges:
+        depth += step
+        peak = max(peak, depth)
+    return peak
+
+
+def stage_tables(
+    events: Sequence[Dict[str, Any]]
+) -> Dict[int, Dict[str, Any]]:
+    """Per operation, who was busy: ``{op: {"kind", "wall_s",
+    "stages": {span name: {"count", "busy_s", "thread_s", "bytes",
+    "max_open"}}, "unattributed_s"}}`` from recorder events (or
+    ``trace.spans_from_chrome`` spans).
+
+    ``busy_s`` is the union of the name's intervals (wall seconds at
+    least one was open), ``thread_s`` their sum, so ``thread_s /
+    busy_s`` is the stage's mean parallelism and ``max_open`` its peak;
+    ``bytes`` sums the spans' ``bytes`` arg. ``unattributed_s`` is the
+    envelope wall during which no span of the op was open on any
+    thread: the op's self time. Spans are not clipped to the envelope —
+    report emission and the manager's post-commit work run after it
+    closes and still belong to the op. A span stamped with another op
+    is never counted; one stamped with none is counted by overlap.
+
+    An operation is the envelopes sharing an ``op`` id, keyed by it.
+    Where events carry none (files written before the recorder stamped
+    ids), each envelope is an operation keyed by its begin order (an
+    async take's commit joins the stage envelope of the same path
+    before it) and takes the spans that overlap its wall."""
+    spans = []
+    for e in events:
+        if e.get("ph", "X") != "X":
+            continue
+        dur = e["dur"] if "dur" in e else e["dur_us"]
+        spans.append((e, e["ts"], e["ts"] + dur))
+    ops: Dict[int, Dict[str, Any]] = {}
+    unstamped: Dict[Any, int] = {}
+    for e, lo, hi in sorted(spans, key=lambda s: s[1]):
+        name = e["name"]
+        if name not in _ALL_ENVELOPE_NAMES:
+            continue
+        op = e.get("op", 0)
+        if not op:
+            path = (e.get("args") or {}).get("path")
+            if name == names.SPAN_ASYNC_TAKE_COMMIT and path in unstamped:
+                op = unstamped.pop(path)
+            else:
+                op = e.get("bseq") or -(len(ops) + 1)
+                if name == names.SPAN_ASYNC_TAKE_STAGE:
+                    unstamped[path] = op
+        entry = ops.setdefault(
+            op, {"kind": _KIND_BY_ENVELOPE[name], "windows": []}
+        )
+        entry["windows"].append((lo, hi))
+    out: Dict[int, Dict[str, Any]] = {}
+    for op, entry in ops.items():
+        windows = _merge_intervals(entry["windows"])
+        by_name: Dict[str, List[Tuple[int, int]]] = {}
+        nbytes: Dict[str, int] = {}
+        for e, lo, hi in spans:
+            name = e["name"]
+            if name in _ALL_ENVELOPE_NAMES:
+                continue
+            span_op = e.get("op", 0)
+            if span_op != op and (
+                span_op or _overlap_us(lo, hi, windows) <= 0
+            ):
+                continue
+            by_name.setdefault(name, []).append((lo, hi))
+            b = (e.get("args") or {}).get("bytes")
+            if isinstance(b, int):
+                nbytes[name] = nbytes.get(name, 0) + b
+        stages: Dict[str, Dict[str, Any]] = {}
+        covered: List[Tuple[int, int]] = []
+        for name, intervals in by_name.items():
+            merged = _merge_intervals(intervals)
+            covered.extend(merged)
+            stages[name] = {
+                "count": len(intervals),
+                "busy_s": round(sum(hi - lo for lo, hi in merged) / 1e6, 6),
+                "thread_s": round(
+                    sum(hi - lo for lo, hi in intervals) / 1e6, 6
+                ),
+                "bytes": nbytes.get(name, 0),
+                "max_open": _max_open(intervals),
+            }
+        wall_us = sum(hi - lo for lo, hi in windows)
+        covered_us = sum(
+            _overlap_us(lo, hi, windows) for lo, hi in _merge_intervals(covered)
+        )
+        out[op] = {
+            "kind": entry["kind"],
+            "wall_s": round(wall_us / 1e6, 6),
+            "stages": dict(
+                sorted(stages.items(), key=lambda kv: -kv[1]["busy_s"])
+            ),
+            "unattributed_s": round((wall_us - covered_us) / 1e6, 6),
+        }
+    return out
+
+
+def format_stage_table(table: Dict[str, Any]) -> str:
+    """One op's stage table as the operator reads it (``telemetry
+    trace``), busiest stage first."""
+    lines = [
+        f"{table['kind']}: wall {table['wall_s']:.3f} s, "
+        f"unattributed {table['unattributed_s']:.3f} s",
+        f"  {'stage':<32} {'count':>6} {'busy_s':>9} {'thread_s':>9} "
+        f"{'par':>5} {'max':>4} {'MiB':>9}",
+    ]
+    for name, row in table["stages"].items():
+        par = row["thread_s"] / row["busy_s"] if row["busy_s"] else 0.0
+        lines.append(
+            f"  {name:<32} {row['count']:>6} {row['busy_s']:>9.3f} "
+            f"{row['thread_s']:>9.3f} {par:>5.2f} {row['max_open']:>4} "
+            f"{row['bytes'] / 2**20:>9.1f}"
+        )
+    return "\n".join(lines)
 
 
 # ---------------------------------------------------------------------------

@@ -157,6 +157,9 @@ def summarize_report(
         # seconds. Feeds one ``critpath_<segment>_s`` trend series per
         # segment plus the doctor's critical-path-shifted rule — a step
         # whose bottleneck MOVED flags even when the wall barely did.
+        # ``stages`` is the op's stage table (who was busy: count,
+        # busy_s, thread_s, bytes, max_open per span name; absent from
+        # rows older than it) and ``unattributed_s`` its self time.
         "critpath": (
             {
                 "dominant": report.critical_path.get("dominant"),
@@ -167,6 +170,8 @@ def summarize_report(
                         report.critical_path.get("segments") or {}
                     ).items()
                 },
+                "stages": report.critical_path.get("stages"),
+                "unattributed_s": report.critical_path.get("unattributed_s"),
             }
             if report.critical_path
             else None

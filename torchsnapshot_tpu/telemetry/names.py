@@ -224,6 +224,7 @@ SPAN_TAKE = "snapshot:take"
 SPAN_RESTORE = "snapshot:restore"
 SPAN_ASYNC_TAKE_STAGE = "snapshot:async_take:stage"
 SPAN_ASYNC_TAKE_COMMIT = "snapshot:async_take:commit"
+SPAN_ASYNC_RESTORE_PLAN = "snapshot:async_restore:plan"
 SPAN_ASYNC_RESTORE_READS = "snapshot:async_restore:reads"
 
 # scheduler.py pipeline stages
@@ -240,6 +241,47 @@ SPAN_LEAF_CONSUME = "consume:leaf"
 # clone dispatch + mutable-host-leaf copies) — the only staging-flavored
 # work left inside async_take's training-visible span.
 SPAN_DEVICE_CAPTURE = "stage:device_capture"
+# One per distinct source inside that pass (args: kind, bytes, leaf):
+# the dispatch of a jax leaf's on-device clone, the host copy of a
+# mutable numpy leaf (or of a jax leaf whose clone failed), the eager
+# pickle of an object.
+SPAN_CAPTURE_CLONE = "capture:clone"
+SPAN_CAPTURE_HOST_COPY = "capture:host_copy"
+SPAN_CAPTURE_OBJECT = "capture:object"
+# Inside stage:leaf, the ``np.asarray`` of a jax array alone: the PJRT
+# transfer plus the host-side untiling, without the slicing and
+# ascontiguousarray around it.
+SPAN_STAGE_D2H = "stage:d2h"
+
+# snapshot.py host work around the pipelines. take:plan is flatten +
+# partition + batch + prepare_write (and the manifest gather) of a take;
+# commit:finalize is finalize_checksums + checksum table + manifest +
+# marker, on the caller's thread (sync) or the commit thread (async);
+# restore:plan is the metadata and checksum-table reads (no ``stateful``
+# arg) and each stateful's destination allocation + read planning;
+# restore:place is one batched device_put with its deferred conversions;
+# restore:apply is a stateful's remaining placements + load_state_dict.
+SPAN_TAKE_PLAN = "take:plan"
+SPAN_COMMIT_FINALIZE = "commit:finalize"
+SPAN_RESTORE_PLAN = "restore:plan"
+SPAN_RESTORE_PLACE = "restore:place"
+SPAN_RESTORE_APPLY = "restore:apply"
+# scheduler.py: one checksum verification of read bytes, inline or on
+# the executor (args: bytes, mode=whole|range|pages, blob). The write
+# side has no counterpart: the fused native write computes the CRC
+# inside storage:fs_native_write.
+SPAN_VERIFY_BLOB = "verify:blob"
+
+# manager.py, after the commit, on the thread that called save()/wait():
+# the index update (retention nested inside it: step deletes + chunk GC)
+# and the autotuner's move.
+SPAN_MANAGER_INDEX = "manager:index"
+SPAN_MANAGER_RETENTION = "manager:retention"
+SPAN_MANAGER_TUNE = "manager:tune"
+# Report emission after the envelope closed (critical path, stage table,
+# gather, sinks, ledger, trace export; arg kind) and the manager's
+# history / ledger / SLO pass (arg kind="step").
+SPAN_TELEMETRY_REPORT = "telemetry:report"
 
 # storage plugins (fs/s3/gcs); the fs native fast path additionally
 # stamps its executor-thread kernel I/O

@@ -19,6 +19,15 @@ and phase sums cannot answer. Design:
 - **Dual emission.** ``utils.tracing.trace_annotation`` call sites feed
   BOTH this recorder and (when a profiler session is active) the jax
   XPlane timeline — one annotation, two sinks.
+  :func:`xplane_offset_us` measures the offset between the two clocks
+  from the spans both sinks hold.
+- **One op id, one parent.** Every event carries ``op`` (the ``bseq``
+  of the operation's first envelope span; 0 outside any op) and
+  ``parent`` (the ``bseq`` of the span that was innermost in the
+  caller's context when it began; the envelope for top-level work). Both
+  ride one ``contextvars.ContextVar``, so asyncio tasks inherit them
+  at creation and ``utils.tracing.run_in_executor`` carries them across
+  executor hops.
 - **Chrome trace-event export.** Per checkpoint operation (take /
   restore / async variants / mirror job), the op's event window is
   written as Perfetto-loadable Chrome trace JSON next to the snapshot
@@ -37,10 +46,12 @@ The stall watchdog (watchdog.py) scans this recorder's open spans.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import glob
 import json
 import logging
 import os
+import statistics
 import threading
 import time
 from collections import deque
@@ -61,12 +72,46 @@ from . import names
 logger: logging.Logger = logging.getLogger(__name__)
 
 TRACE_BASENAME_PREFIX = "trace-"
+# Chrome-export arg keys carrying a span's recorder identity.
+CHROME_ARG_SEQ = "trace_seq"
+CHROME_ARG_OP = "trace_op"
+CHROME_ARG_PARENT = "trace_parent"
 SNAPSHOT_TRACE_PREFIX = ".trace-"
 MERGED_TRACE_BASENAME = ".trace.merged.json"
 
 
 def _now_us() -> int:
     return time.time_ns() // 1000
+
+
+# (op id, bseq of the innermost open span) of the caller's context.
+# Tasks copy it at creation; threads start at the default unless the
+# submitter carries its context over (utils.tracing.run_in_executor).
+_NO_OP: Tuple[int, int] = (0, 0)
+_CONTEXT: "contextvars.ContextVar[Tuple[int, int]]" = contextvars.ContextVar(
+    "torchsnapshot_tpu_trace_context", default=_NO_OP
+)
+
+
+def current_op() -> int:
+    """The op id of the caller's context (0 outside any operation)."""
+    return _CONTEXT.get()[0]
+
+
+@contextlib.contextmanager
+def op_scope(op: int) -> Generator[None, None, None]:
+    """Attribute the spans begun inside the block to operation ``op`` —
+    for work done on the op's behalf after its envelope closed (report
+    emission, the manager's index / retention / tuning). ``op`` 0 is a
+    no-op."""
+    if not op:
+        yield
+        return
+    token = _CONTEXT.set((op, op))
+    try:
+        yield
+    finally:
+        _CONTEXT.reset(token)
 
 
 def _track_key() -> Tuple[int, int]:
@@ -82,10 +127,21 @@ def _track_key() -> Tuple[int, int]:
 
 
 class _OpenSpan:
-    __slots__ = ("name", "begin_us", "bseq", "tid", "args", "stalled")
+    __slots__ = (
+        "name", "begin_us", "bseq", "tid", "args", "stalled", "op", "parent",
+        "outer",
+    )
 
     def __init__(
-        self, name: str, begin_us: int, bseq: int, tid: int, args: Dict
+        self,
+        name: str,
+        begin_us: int,
+        bseq: int,
+        tid: int,
+        args: Dict,
+        op: int,
+        parent: int,
+        outer: Tuple[int, int],
     ) -> None:
         self.name = name
         self.begin_us = begin_us
@@ -93,6 +149,10 @@ class _OpenSpan:
         self.tid = tid
         self.args = args
         self.stalled = False
+        self.op = op
+        self.parent = parent
+        # The context this span displaced; end() hands it back.
+        self.outer = outer
 
 
 class TraceMark(NamedTuple):
@@ -110,10 +170,12 @@ class SpanRecorder:
 
     Completed events are dicts
     ``{"seq", "bseq", "ph" ("X"|"i"), "name", "ts", "dur", "tid",
-    "args"}`` with ``ts``/``dur`` in unix-epoch microseconds; ``seq``
-    orders completions (the ring's eviction order and the export-window
-    cursor), ``bseq`` orders begins (what the Chrome exporter's B/E
-    interleave sorts on).
+    "op", "parent", "args"}`` with ``ts``/``dur`` in unix-epoch
+    microseconds; ``seq`` orders completions (the ring's eviction order
+    and the export-window cursor), ``bseq`` orders begins (what the
+    Chrome exporter's B/E interleave sorts on) and is the span's
+    identity: ``op`` is the ``bseq`` of the operation's first envelope,
+    ``parent`` the ``bseq`` of the span that caused this one.
     """
 
     def __init__(self, capacity: Optional[int] = None) -> None:
@@ -148,17 +210,38 @@ class SpanRecorder:
 
     def begin(self, name: str, **args: Any) -> int:
         """Open a span on the caller's track; returns a token for
-        :meth:`end`."""
+        :meth:`end`. The span is stamped with the context's op id and
+        becomes the context's innermost span until it ends."""
+        return self._begin(name, args, None)
+
+    def begin_op(self, name: str, op: int = 0, **args: Any) -> int:
+        """Open an operation's envelope span. ``op`` 0 starts a new
+        operation whose id is this span's ``bseq``; a later envelope of
+        the same operation (an async take's commit, on its own thread)
+        passes the first one's id (:func:`current_op`, read while that
+        one is open)."""
+        return self._begin(name, args, op)
+
+    def _begin(self, name: str, args: Dict[str, Any], op: Optional[int]) -> int:
         key = _track_key()
+        outer = _CONTEXT.get()
+        ctx_op, parent = outer
         ts = _now_us()
         with self._lock:
             self._seq += 1
             self._next_token += 1
             self._last_activity = time.monotonic()
             token = self._next_token
+            bseq = self._seq
+            if op is None:
+                op = ctx_op
+            else:
+                op = op or bseq
+                parent = op if op != bseq else 0
             self._open[token] = _OpenSpan(
-                name, ts, self._seq, self._tid_locked(key), args
+                name, ts, bseq, self._tid_locked(key), args, op, parent, outer
             )
+        _CONTEXT.set((op, bseq))
         # Outside the lock: may start the watchdog thread.
         from . import watchdog
 
@@ -171,6 +254,10 @@ class SpanRecorder:
             span = self._open.pop(token, None)
             if span is None:
                 return
+            # A span ended from another context (or out of order) leaves
+            # that context alone.
+            if _CONTEXT.get() == (span.op, span.bseq):
+                _CONTEXT.set(span.outer)
             if extra_args:
                 span.args.update(extra_args)
             self._seq += 1
@@ -186,6 +273,8 @@ class SpanRecorder:
                     # B in the ts-major export ordering.
                     "dur": max(1, ts - span.begin_us),
                     "tid": span.tid,
+                    "op": span.op,
+                    "parent": span.parent,
                     "args": span.args,
                 }
             )
@@ -206,6 +295,7 @@ class SpanRecorder:
         markers must not look like the stalled process doing work."""
         ts = _now_us()
         key = _track_key()
+        op, parent = _CONTEXT.get()
         with self._lock:
             self._seq += 1
             if count_as_progress:
@@ -218,6 +308,8 @@ class SpanRecorder:
                     "name": name,
                     "ts": ts,
                     "tid": self._tid_locked(key),
+                    "op": op,
+                    "parent": parent,
                     "args": args,
                 }
             )
@@ -340,6 +432,75 @@ def io_span(
 
 
 # ---------------------------------------------------------------------------
+# The profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def xplane_offset_us(
+    profile_data: Any, events: Optional[List[Dict[str, Any]]] = None
+) -> Optional[Dict[str, float]]:
+    """The offset between the recorder's clock and a jax profile's.
+
+    A dual-emitted span (``utils.tracing.trace_annotation``) is in both:
+    the recorder stamps ``ts`` in unix-epoch microseconds, the XPlane
+    ``start_ns`` from the profile session's own origin. Spans are
+    matched by name and order — a name whose count differs between the
+    two (a span open when the session started or stopped) is left out —
+    so pass the recorder events of the profiled window
+    (``events_since(mark)`` with the mark taken before ``start_trace``;
+    default: everything in the ring).
+
+    Returns ``{"n", "median_us", "spread_us", "drift_us_per_s"}``:
+    ``median_us`` is recorder ``ts`` minus XPlane start, so a
+    recorder-only span sits at ``ts - median_us`` on the profile's
+    timeline; ``spread_us`` is the interquartile distance of the
+    pairs' offsets and ``drift_us_per_s`` their least-squares slope over
+    the window (the two clocks do not tick at the same rate). None where
+    no span could be paired. ``profile_data`` is a
+    ``jax.profiler.ProfileData``."""
+    if events is None:
+        events = get_recorder().events_since(0)
+    recorded: Dict[str, List[int]] = {}
+    for e in events:
+        if e.get("ph") == "X":
+            recorded.setdefault(e["name"], []).append(e["ts"])
+    profiled: Dict[str, List[float]] = {}
+    for plane in profile_data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in recorded:
+                    profiled.setdefault(ev.name, []).append(ev.start_ns / 1e3)
+    pairs: List[Tuple[float, float]] = []
+    for name, starts in profiled.items():
+        if len(starts) == len(recorded[name]):
+            pairs.extend(zip(sorted(recorded[name]), sorted(starts)))
+    if not pairs:
+        return None
+    offsets = [ts - start for ts, start in pairs]
+    spread = 0.0
+    if len(offsets) >= 2:
+        q = statistics.quantiles(offsets, n=4)
+        spread = q[2] - q[0]
+    drift = 0.0
+    t0 = min(ts for ts, _ in pairs)
+    xs = [(ts - t0) / 1e6 for ts, _ in pairs]
+    mean_x, mean_y = statistics.fmean(xs), statistics.fmean(offsets)
+    var = sum((x - mean_x) ** 2 for x in xs)
+    if var > 0:
+        drift = (
+            sum((x - mean_x) * (y - mean_y) for x, y in zip(xs, offsets)) / var
+        )
+    return {
+        "n": len(pairs),
+        "median_us": statistics.median(offsets),
+        "spread_us": spread,
+        "drift_us_per_s": drift,
+    }
+
+
+# ---------------------------------------------------------------------------
 # Chrome trace-event export
 # ---------------------------------------------------------------------------
 
@@ -396,7 +557,15 @@ def chrome_trace(
                     "tid": e["tid"],
                     "ts": e["ts"],
                     "bseq": e["bseq"],
-                    "args": e["args"],
+                    # The span's identity rides its args (the Chrome
+                    # schema has no field for it): spans_from_chrome
+                    # lifts the three keys back out.
+                    "args": {
+                        **e["args"],
+                        CHROME_ARG_SEQ: e["bseq"],
+                        CHROME_ARG_OP: e.get("op", 0),
+                        CHROME_ARG_PARENT: e.get("parent", 0),
+                    },
                 }
             )
             flat.append(
@@ -666,7 +835,9 @@ def spans_from_chrome(doc: Dict[str, Any]) -> List[Dict[str, Any]]:
     ``self_us`` is the span's inclusive duration minus the durations of
     its direct children on the same track — the time the span spent in
     its OWN frame, which is what separates a genuinely slow stage from
-    an envelope that merely contains one."""
+    an envelope that merely contains one. Spans exported by this
+    recorder also carry ``bseq`` / ``op`` / ``parent`` (0 where the
+    file predates them)."""
     # Stack entries are [begin_event, accumulated_child_us].
     stacks: Dict[Tuple[int, int], List[List[Any]]] = {}
     spans: List[Dict[str, Any]] = []
@@ -683,6 +854,7 @@ def spans_from_chrome(doc: Dict[str, Any]) -> List[Dict[str, Any]]:
             dur_us = ev["ts"] - begin["ts"]
             if stack:
                 stack[-1][1] += dur_us
+            args = begin.get("args", {})
             spans.append(
                 {
                     "name": begin.get("name", "?"),
@@ -691,7 +863,10 @@ def spans_from_chrome(doc: Dict[str, Any]) -> List[Dict[str, Any]]:
                     "ts": begin["ts"],
                     "dur_us": dur_us,
                     "self_us": max(0, dur_us - child_us),
-                    "args": begin.get("args", {}),
+                    "bseq": args.get(CHROME_ARG_SEQ, 0),
+                    "op": args.get(CHROME_ARG_OP, 0),
+                    "parent": args.get(CHROME_ARG_PARENT, 0),
+                    "args": args,
                 }
             )
     return spans
@@ -730,7 +905,8 @@ def longest_spans(
 
 def summarize_merged(doc: Dict[str, Any], top: int = 5) -> str:
     """Operator summary of a merged trace: per-rank wall extent, the
-    longest individual spans, the per-span-name straggler rank (largest
+    longest individual spans, each operation's stage table
+    (critpath.stage_tables), the per-span-name straggler rank (largest
     total duration), and any watchdog stall events."""
     spans = spans_from_chrome(doc)
     lines: List[str] = []
@@ -766,6 +942,18 @@ def summarize_merged(doc: Dict[str, Any], top: int = 5) -> str:
             f"{s.get('self_us', s['dur_us']) / 1e3:>10.1f} ms self "
             f"(of {s['dur_us'] / 1e3:.1f} ms)"
         )
+    # Who was busy, per operation (op ids are per process, so per rank):
+    # the stage whose busy seconds are the op's wall sets its pace.
+    from .critpath import format_stage_table, stage_tables
+
+    for rank in ranks:
+        tables = stage_tables([s for s in spans if s["pid"] == rank])
+        for op in sorted(tables):
+            lines.append("")
+            lines.append(
+                f"rank {rank} op {op} "
+                + format_stage_table(tables[op])
+            )
     if len(ranks) > 1:
         totals: Dict[str, Dict[int, float]] = {}
         for s in spans:

@@ -274,8 +274,10 @@ class Mirror:
             began = time.monotonic()
             recorder = _trace_recorder()
             job.trace_mark = recorder.mark()
-            job_span = recorder.begin(
+            # The job is an operation of its own: its spans carry its id.
+            job_span = recorder.begin_op(
                 metric_names.SPAN_MIRROR_JOB,
+                0,
                 fast=job.fast_url,
                 durable=job.durable_url,
                 blobs=len(job.blobs),

@@ -52,7 +52,6 @@ no pulls). Knobs: ring offset, cache budget bytes, transfer timeout
 
 from __future__ import annotations
 
-import asyncio
 import json
 import logging
 import pickle
@@ -84,6 +83,7 @@ from ..storage_plugins.retry import (
 from ..telemetry import names as metric_names
 from ..telemetry import wire
 from ..telemetry.trace import get_recorder as _trace_recorder
+from ..utils.tracing import run_in_executor
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -819,8 +819,10 @@ class PeerReplicator:
             if job is None:
                 return
             recorder = _trace_recorder()
-            job_span = recorder.begin(
+            # The job is an operation of its own: its spans carry its id.
+            job_span = recorder.begin_op(
                 metric_names.SPAN_PEER_JOB,
+                0,
                 step=job.step_key,
                 blobs=len(job.blobs),
             )
@@ -858,7 +860,6 @@ class PeerReplicator:
         retry = CollectiveProgressRetryStrategy(
             progress_window_seconds=timeout, scope="peer"
         )
-        loop = asyncio.get_running_loop()
         try:
             # Inventory-by-digest dedup, one RPC: content-addressed
             # chunk paths the neighbor already pools are *referenced*
@@ -873,7 +874,7 @@ class PeerReplicator:
             if chunk_paths:
 
                 async def _ref_once():
-                    return await loop.run_in_executor(
+                    return await run_in_executor(
                         None,
                         client.reference_chunks,
                         job.step_key,
@@ -915,7 +916,7 @@ class PeerReplicator:
                     return client.push(job.step_key, job.step, p, e, d)
 
                 async def _push_once():
-                    return await loop.run_in_executor(None, _push_sync)
+                    return await run_in_executor(None, _push_sync)
 
                 with _trace_recorder().span(
                     metric_names.SPAN_PEER_PUSH, blob=path
@@ -935,7 +936,7 @@ class PeerReplicator:
                     job.blobs_refused += 1
             if job.committed:
                 async def _commit_once():
-                    return await loop.run_in_executor(
+                    return await run_in_executor(
                         None, client.commit, job.step_key, job.step
                     )
 
@@ -1551,8 +1552,7 @@ class _PeerLadderPlugin(StoragePlugin):
                 pass
         if eligible:
             rng = read_io.byte_range
-            loop = asyncio.get_running_loop()
-            chunk = await loop.run_in_executor(
+            chunk = await run_in_executor(
                 None, self.ctx.pull, path, rng
             )
             if chunk is not None:

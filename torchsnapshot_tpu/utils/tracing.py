@@ -26,11 +26,19 @@ hold a span across an ``await`` should use the recorder directly
 (``telemetry.trace.get_recorder().span(...)``, which tracks per
 asyncio task) rather than this helper — an interleaved task on the
 same thread would otherwise mis-nest the XPlane timeline.
+
+The recorder stamps every span with the op id and parent span of the
+caller's ``contextvars`` context. Tasks inherit that context; executor
+threads do not, so hops on the hot path go through
+:func:`run_in_executor`.
 """
 
 from __future__ import annotations
 
-from typing import Any, ContextManager
+import asyncio
+import contextvars
+from concurrent.futures import Executor
+from typing import Any, Callable, Optional
 
 from ..telemetry.trace import get_recorder
 
@@ -43,32 +51,80 @@ except Exception:  # pragma: no cover - jax always present in this repo
 class _DualAnnotation:
     """Flight-recorder span + jax TraceAnnotation, one context manager
     (hand-rolled: this wraps every buffer's staging/write/read, and a
-    generator-based contextmanager costs ~3x per entry)."""
+    generator-based contextmanager costs ~3x per entry). ``op`` not
+    None makes the span an operation's envelope
+    (``SpanRecorder.begin_op``)."""
 
-    __slots__ = ("_name", "_args", "_token", "_jax")
+    __slots__ = ("_name", "_args", "_op", "_token", "_jax", "_late")
 
-    def __init__(self, name: str, args: dict) -> None:
+    def __init__(self, name: str, args: dict, op: Optional[int] = None) -> None:
         self._name = name
         self._args = args
+        self._op = op
         self._token = 0
         self._jax = None
+        self._late: Optional[dict] = None
 
-    def __enter__(self) -> None:
-        self._token = get_recorder().begin(self._name, **self._args)
+    def annotate(self, **args: Any) -> None:
+        """Args known only once the work is done (a pickle's size); they
+        join the recorder span when it ends."""
+        self._late = {**(self._late or {}), **args}
+
+    def __enter__(self) -> "_DualAnnotation":
+        if self._op is None:
+            self._token = get_recorder().begin(self._name, **self._args)
+        else:
+            self._token = get_recorder().begin_op(
+                self._name, self._op, **self._args
+            )
         if _TraceAnnotation is not None:
             self._jax = _TraceAnnotation(self._name)
             self._jax.__enter__()
+        return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        # Idempotent: :func:`end` may run again from a ``finally``.
+        jax_side, self._jax = self._jax, None
         try:
-            if self._jax is not None:
-                self._jax.__exit__(exc_type, exc, tb)
+            if jax_side is not None:
+                jax_side.__exit__(exc_type, exc, tb)
         finally:
-            get_recorder().end(self._token)
+            get_recorder().end(self._token, **(self._late or {}))
 
 
-def trace_annotation(name: str, **args: Any) -> ContextManager[None]:
+def trace_annotation(name: str, **args: Any) -> "_DualAnnotation":
     """A context manager placing ``name`` on the flight recorder AND
     the active jax profiler timeline (thread-local on the jax side —
     safe on executor threads; see module note for coroutines)."""
     return _DualAnnotation(name, args)
+
+
+def op_annotation(name: str, op: int = 0, **args: Any) -> "_DualAnnotation":
+    """:func:`trace_annotation` for an operation's envelope span: ``op``
+    0 opens a new operation, another value joins that one. For
+    envelopes that open and close on one thread."""
+    return _DualAnnotation(name, args, op)
+
+
+def begin(name: str, op: int = 0, **args: Any) -> _DualAnnotation:
+    """:func:`op_annotation`, opened: for an envelope that has to close
+    before the end of its ``try`` block (the report is emitted after
+    it). Pair with :func:`end`, once there and once in the ``finally``."""
+    annotation = _DualAnnotation(name, args, op)
+    annotation.__enter__()
+    return annotation
+
+
+def end(annotation: _DualAnnotation) -> None:
+    """Close an envelope opened by :func:`begin`; a no-op the second time."""
+    annotation.__exit__(None, None, None)
+
+
+def run_in_executor(
+    executor: Optional[Executor], fn: Callable[..., Any], *args: Any
+) -> "asyncio.Future[Any]":
+    """``loop.run_in_executor`` that carries the caller's context (the
+    op id and parent span the recorder stamps) onto the worker thread."""
+    return asyncio.get_running_loop().run_in_executor(
+        executor, contextvars.copy_context().run, fn, *args
+    )
