@@ -252,3 +252,53 @@ def test_staging_cost_matches_payload() -> None:
         sum(wr.buffer_stager.get_staging_cost_bytes() for wr in chunked_reqs)
         == src.nbytes
     )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("bound", [True, False], ids=["bound", "self-made"])
+def test_late_destination(dtype: str, bound: bool) -> None:
+    """An owned dense entry planned without a destination: its consumer
+    takes the buffer the read pipeline binds when it admits the read (a
+    slab of dest_pool), hands it back through ``placed`` exactly once,
+    and makes its own where nobody bound one (a batched read's member, a
+    pipeline without a pool)."""
+    src = rand_array((24, 16), dtype)
+    entry, write_reqs = prepare_write(src, "late", rank=0)
+    (req,) = prepare_read(entry, obj_out=None, dest_owned=True)
+    consumer = req.buffer_consumer
+    assert consumer.dst is None
+    assert consumer.unbound_destination_bytes() == src.nbytes
+
+    slab = np.zeros(src.nbytes, np.uint8)
+    handed_back: List[Any] = []
+    if bound:
+        consumer.bind_destination(slab, handed_back.append)
+    assert consumer.unbound_destination_bytes() == 0 or not bound
+
+    direct = consumer.direct_destination()
+    assert direct is not None and direct.nbytes == src.nbytes
+    assert np.shares_memory(np.frombuffer(direct, np.uint8), slab) == bound
+    fulfill_read_reqs_with_write_reqs([req], write_reqs)
+    assert consumer.dst.shape == src.shape and consumer.dst.dtype == src.dtype
+    np.testing.assert_array_equal(
+        consumer.dst.view(np.uint8), src.view(np.uint8)
+    )
+    assert np.shares_memory(consumer.dst, slab) == bound
+
+    consumer.placed("on-device")
+    consumer.placed("on-device")
+    assert handed_back == (["on-device"] if bound else [])
+
+
+def test_late_destination_is_for_owned_whole_reads() -> None:
+    src = rand_array((8, 8), "float32")
+    entry, _ = prepare_write(src, "late", rank=0)
+    with pytest.raises(ValueError, match="destination"):
+        prepare_read(entry, obj_out=None, dest_owned=False)
+    # A buffer limit cannot split what has no destination yet.
+    assert len(prepare_read(entry, None, buffer_size_limit_bytes=16, dest_owned=True)) == 1
+    with knobs.override_max_chunk_size_bytes(64):
+        chunked, _ = prepare_write(src, "late", rank=0)
+    assert isinstance(chunked, ChunkedArrayEntry)
+    with pytest.raises(ValueError, match="destination"):
+        prepare_read(chunked, obj_out=None, dest_owned=True)

@@ -6,6 +6,7 @@ here the scheduler is tested directly with instrumented stagers/plugins.
 """
 
 import asyncio
+import threading
 from typing import Dict
 
 import pytest
@@ -381,3 +382,246 @@ def test_deferred_io_work_runs_pipeline_and_fires_on_staged() -> None:
     telemetry = work.pipeline_telemetry()
     assert telemetry["blobs"] == 12
     assert "staging" in telemetry["phases"]
+
+
+# ---------------------------------------------------------------------------
+# Destination pool (dest_pool.py): recycled host slabs for restore reads
+# ---------------------------------------------------------------------------
+
+
+class FakePlaced:
+    """What ``device_put`` returned, as the pool sees it: ready when told,
+    and, once deleted, unable to say."""
+
+    def __init__(self) -> None:
+        self._landed = threading.Event()
+        self.deleted = False
+
+    def land(self) -> None:
+        self._landed.set()
+
+    def is_ready(self) -> bool:
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        return self._landed.is_set()
+
+    def block_until_ready(self) -> "FakePlaced":
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        assert self._landed.wait(10), "placement never landed"
+        return self
+
+
+def _pool_respects_cap(pool) -> None:
+    taken = [pool.try_take(100, 250) for _ in range(3)]
+    assert [s is not None for s in taken] == [True, True, False]
+    assert pool.retained_bytes() == 200
+    assert taken[0].array.nbytes == 100 and not taken[0].recycled
+    assert taken[0].array.ctypes.data % 4096 == 0
+
+
+def _pool_reuses_only_ready(pool) -> None:
+    slab = pool.try_take(100, 100)
+    value = FakePlaced()
+    pool.placed(slab, value)
+    pool.sweep()
+    assert pool.try_take(100, 100) is None  # placed, not yet ready
+    assert pool.unsettled() == 1
+    value.land()
+    pool.sweep()
+    again = pool.try_take(100, 100)
+    assert again is slab and again.recycled
+    assert pool.retained_bytes() == 100
+
+
+def _pool_drops_deleted(pool) -> None:
+    slab = pool.try_take(100, 100)
+    value = FakePlaced()
+    pool.placed(slab, value)
+    value.deleted = True  # the application donated it: transfer unknowable
+    pool.settle()
+    assert pool.retained_bytes() == 0 and pool.unsettled() == 0
+    fresh = pool.try_take(100, 100)
+    assert fresh is not slab and not fresh.recycled
+
+
+def _pool_keeps_the_plans_sizes(pool) -> None:
+    big, small = pool.try_take(300, 600), pool.try_take(100, 600)
+    landed = FakePlaced()
+    landed.land()
+    pool.placed(small, landed)
+    pool.sweep()  # small is free, big is out
+    # One of 300 is out and comes back: the free 100 the plan also wants
+    # does not give way to a second 300.
+    assert pool.try_take(300, 600, keep_sizes={300, 100}) is None
+    assert pool.retained_bytes() == 400
+    # A size nobody asked to keep does give way.
+    assert pool.try_take(300, 600, keep_sizes={300}) is not big
+    assert pool.retained_bytes() == 600
+
+
+def _pool_cap_is_a_high_water_mark(pool) -> None:
+    slab = pool.try_take(1000, 4000)
+    landed = FakePlaced()
+    landed.land()
+    pool.placed(slab, landed)
+    pool.settle()
+    # A restore's small stateful runs under a cap of bytes.
+    assert pool.try_take(8, 32, keep_sizes={8}) is not None
+    assert pool.retained_bytes() == 1008
+    assert pool.try_take(1000, 4000) is slab
+    pool.clear()
+    assert pool.retained_bytes() == 1008  # both are out; none was free
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        _pool_respects_cap,
+        _pool_reuses_only_ready,
+        _pool_drops_deleted,
+        _pool_keeps_the_plans_sizes,
+        _pool_cap_is_a_high_water_mark,
+    ],
+    ids=lambda f: f.__name__.strip("_"),
+)
+def test_destination_pool(case) -> None:
+    from torchsnapshot_tpu.dest_pool import DestinationPool
+
+    case(DestinationPool())
+
+
+def test_pipeline_cap_bytes() -> None:
+    from torchsnapshot_tpu.dest_pool import pipeline_cap_bytes
+
+    MiB = 1 << 20
+    neox = [394 * MiB, 394 * MiB] + [128 * MiB] * 4 + [96 * MiB, 32 * MiB] * 2
+    # Four of the largest, or half the plan: under half a train state.
+    assert pipeline_cap_bytes(neox, 1 << 40) == 4 * 394 * MiB
+    assert pipeline_cap_bytes(neox * 4, 1 << 40) == 2 * sum(neox)
+    assert pipeline_cap_bytes([8] * 100, 1 << 40) == 400
+    # Clamped to the budget, never below one destination.
+    assert pipeline_cap_bytes(neox, 500 * MiB) == 500 * MiB
+    assert pipeline_cap_bytes(neox, 100 * MiB) == 394 * MiB
+
+
+class LateConsumer(BufferConsumer):
+    """A consumer that comes without a destination, as a dense leaf bound
+    for an accelerator does."""
+
+    def __init__(self, nbytes: int) -> None:
+        self.nbytes = nbytes
+        self.dst = None
+        self.on_placed = None
+
+    async def consume_buffer(self, buf, executor=None) -> None:
+        self.dst[:] = bytearray(buf)
+
+    def get_consuming_cost_bytes(self) -> int:
+        return self.nbytes
+
+    def unbound_destination_bytes(self) -> int:
+        return self.nbytes if self.dst is None else 0
+
+    def bind_destination(self, buf, on_placed) -> None:
+        self.dst, self.on_placed = buf, on_placed
+
+
+class FailingReads(SlowStorage):
+    async def read(self, read_io: ReadIO) -> None:
+        if read_io.path == "boom":
+            raise OSError("injected read failure")
+        await asyncio.sleep(0.002)
+        await super().read(read_io)
+
+
+def _run_leased_reads(pool, storage, paths, nbytes, cap_budget, loop):
+    """Reads through a pipeline whose 'placer' places each finished read's
+    bytes (a copy, as an accelerator's device_put makes) on ``flush``, the
+    transfer landing a moment later. Returns (copies by path, leases)."""
+    from torchsnapshot_tpu.dest_pool import DestinationLeases
+
+    reqs = [ReadReq(path=p, buffer_consumer=LateConsumer(nbytes)) for p in paths]
+    done, copies, out_peak = [], {}, [0]
+
+    def flush() -> None:
+        while done:
+            req = done.pop()
+            consumer = req.buffer_consumer
+            copies[req.path] = bytes(consumer.dst)
+            value = FakePlaced()
+            consumer.on_placed(value)
+            threading.Timer(0.005, value.land).start()
+
+    leases = DestinationLeases(pool, [nbytes] * len(reqs), cap_budget, flush)
+
+    def on_req_complete(req) -> None:
+        out_peak[0] = max(out_peak[0], len(leases._out))
+        done.append(req)
+        if leases.starved:
+            flush()
+
+    try:
+        sync_execute_read_reqs(
+            reqs, storage, 10**6, 0, loop,
+            on_req_complete=on_req_complete, destinations=leases,
+        )
+        flush()
+    except BaseException:
+        leases.abandon()
+        raise
+    assert out_peak[0] * nbytes <= max(nbytes, min(4 * nbytes, cap_budget))
+    return copies, leases
+
+
+@pytest.mark.parametrize("slabs", [1, 2, 4])
+def test_leased_reads_wait_for_slabs(slabs: int) -> None:
+    """More reads than the cap has slabs: each waits for a placement to
+    land, none deadlocks, every byte arrives, and all but the first
+    ``slabs`` destinations are recycled."""
+    from torchsnapshot_tpu.dest_pool import DestinationPool
+
+    loop = asyncio.new_event_loop()
+    storage, pool, n = FailingReads(), DestinationPool(), 12
+    for i in range(n):
+        storage.blobs[f"b/{i}"] = bytes([i]) * 64
+    try:
+        copies, leases = _run_leased_reads(
+            pool, storage, [f"b/{i}" for i in range(n)], 64, slabs * 64, loop
+        )
+        assert copies == {f"b/{i}": bytes([i]) * 64 for i in range(n)}
+        cap = max(1, min(4, slabs)) * 64
+        assert leases.bytes_fresh == cap
+        assert leases.bytes_recycled == n * 64 - cap
+        pool.settle()
+        assert pool.retained_bytes() == cap and not leases._out
+    finally:
+        loop.close()
+
+
+def test_failed_leased_reads_leave_the_pool_usable() -> None:
+    """A read that raises fails the pipeline; the slabs it had out are
+    dropped, not returned (a thread may still write into them), and the
+    next pipeline runs on what is left."""
+    from torchsnapshot_tpu.dest_pool import DestinationPool
+
+    loop = asyncio.new_event_loop()
+    storage, pool = FailingReads(), DestinationPool()
+    for i in range(6):
+        storage.blobs[f"b/{i}"] = bytes([i]) * 64
+    try:
+        with pytest.raises(OSError, match="injected read failure"):
+            _run_leased_reads(
+                pool, storage, ["b/0", "b/1", "boom", "b/2"], 64, 128, loop
+            )
+        pool.settle()
+        assert sum(pool._out_sizes.values()) == 0
+        assert pool.retained_bytes() <= 128
+        copies, leases = _run_leased_reads(
+            pool, storage, [f"b/{i}" for i in range(6)], 64, 128, loop
+        )
+        assert copies == {f"b/{i}": bytes([i]) * 64 for i in range(6)}
+        pool.settle()
+        assert pool.retained_bytes() == 128
+    finally:
+        loop.close()

@@ -56,6 +56,7 @@ from .serialization import (
     obj_type_name,
     pickle_load_from_bytes,
     pickle_save_as_bytes,
+    string_to_dtype,
 )
 
 from .telemetry import names as metric_names
@@ -324,11 +325,15 @@ class ArrayBufferConsumer(BufferConsumer):
     The destination is an ``np.ndarray`` view (possibly a narrowed slice of
     a larger restore target); the copy runs on the executor since it is
     pure-numpy and GIL-releasing for large blocks.
+
+    ``dst`` None: the destination exists from the moment the read is
+    admitted. The read pipeline binds one (``bind_destination``: a slab of
+    ``dest_pool``); a consumer nobody bound makes its own.
     """
 
     def __init__(
         self,
-        dst: np.ndarray,
+        dst: Optional[np.ndarray],
         dtype: str,
         shape: Tuple[int, ...],
         dest_owned: bool = False,
@@ -341,6 +346,7 @@ class ArrayBufferConsumer(BufferConsumer):
         # fresh buffer but would tear a user-owned in-place array that the
         # caller might keep using after catching the restore error.
         self.dest_owned = dest_owned
+        self._on_placed: Optional[Callable[[Any], None]] = None
 
     async def consume_buffer(
         self, buf: BufferType, executor: Optional[Executor] = None
@@ -355,21 +361,43 @@ class ArrayBufferConsumer(BufferConsumer):
     def _consume_sync(self, buf: BufferType) -> None:
         with trace_annotation(metric_names.SPAN_LEAF_CONSUME):
             src = array_from_memoryview(buf, self.dtype, self.shape)
-            np.copyto(self.dst, src, casting="no")
+            np.copyto(self.destination(), src, casting="no")
 
     def get_consuming_cost_bytes(self) -> int:
         return array_size_bytes(self.shape, self.dtype)
+
+    def unbound_destination_bytes(self) -> int:
+        return self.get_consuming_cost_bytes() if self.dst is None else 0
+
+    def bind_destination(
+        self, buf: np.ndarray, on_placed: Callable[[Any], None]
+    ) -> None:
+        self.dst = buf.view(string_to_dtype(self.dtype)).reshape(self.shape)
+        self._on_placed = on_placed
+
+    def destination(self) -> np.ndarray:
+        if self.dst is None:
+            self.dst = np.empty(self.shape, dtype=string_to_dtype(self.dtype))
+        return self.dst
+
+    def placed(self, value: Any) -> None:
+        """``value`` is on its device from ``dst``: whoever bound ``dst``
+        has it back once ``value`` is ready."""
+        on_placed, self._on_placed = self._on_placed, None
+        if on_placed is not None:
+            on_placed(value)
 
     def direct_destination(self) -> Optional[memoryview]:
         from .serialization import try_writable_byte_view
 
         if not self.dest_owned:
             return None
-        if dtype_to_string(self.dst.dtype) != self.dtype or tuple(
-            self.dst.shape
+        dst = self.destination()
+        if dtype_to_string(dst.dtype) != self.dtype or tuple(
+            dst.shape
         ) != self.shape:
             return None
-        return try_writable_byte_view(self.dst)
+        return try_writable_byte_view(dst)
 
 
 class ArrayIOPreparer:
@@ -435,11 +463,13 @@ class ArrayIOPreparer:
     @staticmethod
     def prepare_read(
         entry: ArrayEntry,
-        arr_out: np.ndarray,
+        arr_out: Optional[np.ndarray],
         buffer_size_limit_bytes: Optional[int] = None,
         dest_owned: bool = False,
     ) -> List[ReadReq]:
-        """Build read request(s) for a dense entry into ``arr_out``.
+        """Build read request(s) for a dense entry into ``arr_out``, or,
+        with ``arr_out`` None, one whole read whose consumer gets its
+        destination when the read is admitted.
 
         With a buffer size limit, large entries become multiple *ranged*
         reads, each consuming directly into a flat slice of the destination
@@ -447,7 +477,7 @@ class ArrayIOPreparer:
         Falls back to one whole read when the destination can't be viewed
         flat (non-contiguous narrow).
         """
-        if list(arr_out.shape) != list(entry.shape):
+        if arr_out is not None and list(arr_out.shape) != list(entry.shape):
             raise ValueError(
                 f"Destination shape {list(arr_out.shape)} != entry shape "
                 f"{entry.shape} for {entry.location}"
@@ -457,7 +487,8 @@ class ArrayIOPreparer:
 
         flat: Optional[np.ndarray] = None
         if (
-            buffer_size_limit_bytes is not None
+            arr_out is not None
+            and buffer_size_limit_bytes is not None
             and total_bytes > buffer_size_limit_bytes
             and arr_out.flags.c_contiguous
         ):
@@ -820,8 +851,10 @@ def prepare_read(
     """Reference parity: io_preparer.py:930-966.
 
     Dense/chunked entries require an ``np.ndarray`` destination (callers
-    allocate via :meth:`ArrayIOPreparer.empty_array_from_entry`); object
-    entries require a ``callback``; primitives produce no reads.
+    allocate via :meth:`ArrayIOPreparer.empty_array_from_entry`), but an
+    owned dense entry may pass none: its consumer then gets one when the
+    read is admitted. Object entries require a ``callback``; primitives
+    produce no reads.
     ``dest_owned`` declares the destination framework-allocated, enabling
     direct (zero-copy) storage reads into it; destinations owned by the
     application must keep copy-on-success semantics.
@@ -829,7 +862,9 @@ def prepare_read(
     if isinstance(entry, PrimitiveEntry):
         return []
     if isinstance(entry, ArrayEntry):
-        if not isinstance(obj_out, np.ndarray):
+        if not isinstance(obj_out, np.ndarray) and not (
+            obj_out is None and dest_owned
+        ):
             raise ValueError(
                 f"Reading {entry.location} requires an np.ndarray destination "
                 f"(got {type(obj_out)})"

@@ -13,7 +13,7 @@ from __future__ import annotations
 import abc
 from concurrent.futures import Executor
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple, Union
+from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
 
 BufferType = Union[bytes, bytearray, memoryview]
 
@@ -166,6 +166,22 @@ class BufferConsumer(abc.ABC):
         (deserialization, scatter into multiple views, dtype conversion).
         When a plugin fills it, ``consume_buffer`` is skipped entirely."""
         return None
+
+    def unbound_destination_bytes(self) -> int:
+        """Bytes of host memory this consumer still needs as its
+        destination, which the read pipeline may bind
+        (:meth:`bind_destination`) when it admits the read; 0 for a
+        consumer that came with its destination."""
+        return 0
+
+    def bind_destination(
+        self, buf: Any, on_placed: Callable[[Any], None]
+    ) -> None:
+        """Take ``buf`` (a uint8 array of ``unbound_destination_bytes()``)
+        as the destination. ``on_placed(value)`` is to be called once with
+        what was placed on a device from it: ``buf`` is the caller's again
+        when ``value`` is ready."""
+        raise NotImplementedError
 
 
 @dataclass

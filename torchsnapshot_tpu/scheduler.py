@@ -37,6 +37,7 @@ from typing import TYPE_CHECKING, Callable, List, Optional
 import psutil
 
 if TYPE_CHECKING:  # pragma: no cover - typing only
+    from .dest_pool import DestinationLeases
     from .telemetry.progress import ProgressTracker
 
 from . import knobs, telemetry
@@ -804,6 +805,7 @@ async def execute_read_reqs(
     on_req_complete: Optional[Callable[[ReadReq], None]] = None,
     progress: Optional["ProgressTracker"] = None,
     classify_read: Optional[Callable[[ReadReq], Optional[str]]] = None,
+    destinations: Optional["DestinationLeases"] = None,
 ) -> dict:
     """Read pipeline: storage read -> deserialize/copy, budgeted by each
     request's consuming cost (reference scheduler.py:357-444). Returns
@@ -820,7 +822,11 @@ async def execute_read_reqs(
     given) to count the bytes as pulled from the storage plugin, or
     ``None`` for bytes served from a local cache (fan-out restore's
     exchanged shards — the exchange already accounted those). The
-    telemetry dict reports the sum as ``bytes_fetched``."""
+    telemetry dict reports the sum as ``bytes_fetched``.
+
+    ``destinations`` gives an admitted read whose consumer came without a
+    destination a slab of the process's pool (``dest_pool``), waiting for
+    one where the pool is at its cap."""
     budget = MemoryBudget(memory_budget_bytes)
     stats = _PipelineStats()
     stats.pending = len(read_reqs)
@@ -847,6 +853,8 @@ async def execute_read_reqs(
         is StoragePlugin.read_with_checksum
     )
 
+    recorder = _trace_recorder()
+
     async def read_one(req: ReadReq) -> None:
         nonlocal fused_read_declined
         cost = req.buffer_consumer.get_consuming_cost_bytes()
@@ -859,12 +867,23 @@ async def execute_read_reqs(
                 else None
             )
             fused_pages = None
+            # Recorder-only: the span crosses the wait for a slab.
+            dest_span = recorder.begin(
+                telemetry.names.SPAN_RESTORE_DEST_ACQUIRE,
+                blob=req.path,
+                bytes=cost,
+            )
+            recycled = False
+            try:
+                if destinations is not None:
+                    recycled = await destinations.bind(req.buffer_consumer)
+                dest = req.buffer_consumer.direct_destination()
+            finally:
+                recorder.end(dest_span, recycled=int(recycled))
             async with io_slots:
                 stats.io += 1
                 read_io = ReadIO(
-                    path=req.path,
-                    byte_range=req.byte_range,
-                    dest=req.buffer_consumer.direct_destination(),
+                    path=req.path, byte_range=req.byte_range, dest=dest
                 )
                 try:
                     # Fused read+verify source: one cache-hot pass
@@ -1013,7 +1032,7 @@ async def execute_read_reqs(
             )
             if kind == "fetched":
                 stats.bytes_fetched += buf.nbytes
-            del buf, read_io
+            del buf, read_io, dest
             if on_req_complete is not None:
                 on_req_complete(req)
             reporter.maybe_report()
@@ -1031,6 +1050,8 @@ async def execute_read_reqs(
         raise
     finally:
         executor.shutdown(wait=False)
+        if destinations is not None:
+            await destinations.aclose()
     if verify_skipped[0]:
         logger.info(
             "%d of %d reads were ranged with no fully-covered pages and "
@@ -1068,6 +1089,7 @@ def sync_execute_read_reqs(
     on_req_complete: Optional[Callable[[ReadReq], None]] = None,
     progress: Optional["ProgressTracker"] = None,
     classify_read: Optional[Callable[[ReadReq], Optional[str]]] = None,
+    destinations: Optional["DestinationLeases"] = None,
 ) -> dict:
     return event_loop.run_until_complete(
         execute_read_reqs(
@@ -1079,5 +1101,6 @@ def sync_execute_read_reqs(
             on_req_complete=on_req_complete,
             progress=progress,
             classify_read=classify_read,
+            destinations=destinations,
         )
     )

@@ -39,7 +39,8 @@ from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
-from . import knobs, telemetry
+from . import dest_pool, knobs, telemetry
+from .dest_pool import DestinationLeases
 from .telemetry import progress as _progress
 from .telemetry.trace import (
     TraceMark,
@@ -1164,6 +1165,7 @@ class Snapshot:
                     pipeline_sink=pipeline_sink,
                     progress_tracker=tracker,
                 )
+            _settle_destinations()
             event_loop.run_until_complete(storage.close())
             _tracing.end(restore_span)
             pipeline = telemetry.merge_pipeline_telemetry(pipeline_sink)
@@ -1429,30 +1431,41 @@ class Snapshot:
         # remaining reads are still in flight.
         placer = _StreamingPlacer()
         placer.register_plan(plan)
-        pipeline_telemetry = sync_execute_read_reqs(
-            read_reqs=read_reqs,
-            storage=(
-                fanout_ctx.wrap(storage) if fanout_ctx is not None else storage
-            ),
-            memory_budget_bytes=memory_budget_bytes,
-            rank=rank,
-            event_loop=event_loop,
-            checksum_table=checksum_table,
-            on_req_complete=placer.on_req_complete,
-            progress=progress_tracker,
-            classify_read=(
-                fanout_ctx.classify_read if fanout_ctx is not None else None
-            ),
-        )
-        pipeline_telemetry["bytes_needed"] = bytes_needed
-        if pipeline_sink is not None:
-            pipeline_sink.append(pipeline_telemetry)
-        placer.flush()
-        with trace_annotation(
-            telemetry.names.SPAN_RESTORE_APPLY, stateful=plan.key
-        ):
-            plan.finish_reads()
-            plan.apply()
+        placer.lease_destinations(read_reqs, memory_budget_bytes)
+        try:
+            pipeline_telemetry = sync_execute_read_reqs(
+                read_reqs=read_reqs,
+                storage=(
+                    fanout_ctx.wrap(storage)
+                    if fanout_ctx is not None
+                    else storage
+                ),
+                memory_budget_bytes=memory_budget_bytes,
+                rank=rank,
+                event_loop=event_loop,
+                checksum_table=checksum_table,
+                on_req_complete=placer.on_req_complete,
+                progress=progress_tracker,
+                classify_read=(
+                    fanout_ctx.classify_read
+                    if fanout_ctx is not None
+                    else None
+                ),
+                destinations=placer.leases,
+            )
+            pipeline_telemetry["bytes_needed"] = bytes_needed
+            placer.report_destinations(pipeline_telemetry)
+            if pipeline_sink is not None:
+                pipeline_sink.append(pipeline_telemetry)
+            placer.flush()
+            with trace_annotation(
+                telemetry.names.SPAN_RESTORE_APPLY, stateful=plan.key
+            ):
+                plan.finish_reads()
+                plan.apply()
+        except BaseException:
+            placer.abandon_destinations()
+            raise
 
     def _plan_stateful_load(
         self,
@@ -1534,7 +1547,9 @@ class Snapshot:
                     groups.append(_LeafGroup(reqs, finalize))
                 continue
             assert isinstance(entry, (ArrayEntry, ChunkedArrayEntry))
-            dst, convert, owned = _restore_destination(entry, current_leaf)
+            dst, convert, owned = _restore_destination(
+                entry, current_leaf, late=True
+            )
             reqs = prepare_read(entry, obj_out=dst, dest_owned=owned)
             read_reqs.extend(reqs)
             if convert is None:
@@ -1544,17 +1559,24 @@ class Snapshot:
                 def _pp(
                     batch: Optional["_PlacementBatch"],
                     path: str = path,
-                    dst: np.ndarray = dst,
+                    dst: Optional[np.ndarray] = dst,
+                    consumer: Any = reqs[0].buffer_consumer,
                     convert: Callable[..., Any] = convert,
                 ) -> None:
-                    out = convert(dst, batch)
+                    # A late destination is the one read's consumer's.
+                    late = dst is None
+
+                    def placed(value: Any) -> None:
+                        restored[path] = value
+                        if late:
+                            consumer.placed(value)
+
+                    out = convert(consumer.dst if late else dst, batch)
                     if isinstance(out, _PlacementSlot):
                         assert batch is not None
-                        batch.defer(
-                            lambda: restored.__setitem__(path, out.value)
-                        )
+                        batch.defer(lambda: placed(out.value))
                     else:
-                        restored[path] = out
+                        placed(out)
 
                 groups.append(_LeafGroup(reqs, _pp))
 
@@ -1785,6 +1807,33 @@ class _StreamingPlacer:
         self._by_req: Dict[int, _LeafGroup] = {}
         self._pending: List[_LeafGroup] = []
         self._pending_bytes = 0
+        # The pipeline's slabs of the destination pool, where it has
+        # reads that take one (lease_destinations).
+        self.leases: Optional[DestinationLeases] = None
+
+    def lease_destinations(
+        self, read_reqs: List[Any], memory_budget_bytes: int
+    ) -> None:
+        """Reads that came without a destination take slabs of the
+        process's pool, under a cap from what this pipeline shows. A
+        slab comes back through a placement, so without streaming (all
+        placements after all reads) nothing is leased: such reads make
+        their own destination, as one nobody binds always does."""
+        sizes = [r.buffer_consumer.unbound_destination_bytes() for r in read_reqs]
+        sizes = [n for n in sizes if n]
+        if sizes and self.flush_bytes > 0:
+            self.leases = DestinationLeases(
+                dest_pool.process_pool(), sizes, memory_budget_bytes, self.flush
+            )
+
+    def report_destinations(self, pipeline_telemetry: dict) -> None:
+        if self.leases is not None:
+            pipeline_telemetry["dest_bytes_recycled"] = self.leases.bytes_recycled
+            pipeline_telemetry["dest_bytes_fresh"] = self.leases.bytes_fresh
+
+    def abandon_destinations(self) -> None:
+        if self.leases is not None:
+            self.leases.abandon()
 
     def register_plan(self, plan: "_StatefulLoadPlan") -> None:
         if self.flush_bytes <= 0:
@@ -1816,7 +1865,10 @@ class _StreamingPlacer:
     def _ready(self, group: _LeafGroup) -> None:
         self._pending.append(group)
         self._pending_bytes += group.nbytes
-        if self._pending_bytes >= self.flush_bytes:
+        # A read waiting for a slab waits for a placement: none may sit
+        # here until more bytes arrive that cannot.
+        starved = self.leases is not None and self.leases.starved
+        if self._pending_bytes >= self.flush_bytes or starved:
             self.flush()
 
     def flush(self) -> None:
@@ -2170,6 +2222,7 @@ class PendingRestore:
         )
         # A handle made without a planning envelope starts the op here.
         self.trace_op = _current_op()
+        placer: Optional[_StreamingPlacer] = None
         try:
             storage = url_to_storage_plugin(self.path)
             if self._peer_ctx is not None:
@@ -2191,6 +2244,7 @@ class PendingRestore:
             placer = _StreamingPlacer()
             for plan in self._plans.values():
                 placer.register_plan(plan)
+            placer.lease_destinations(read_reqs, self._memory_budget_bytes)
             fanout_ctx = self._fanout_ctx
             self._pipeline_telemetry = sync_execute_read_reqs(
                 read_reqs=read_reqs,
@@ -2210,8 +2264,10 @@ class PendingRestore:
                     if fanout_ctx is not None
                     else None
                 ),
+                destinations=placer.leases,
             )
             self._pipeline_telemetry["bytes_needed"] = bytes_needed
+            placer.report_destinations(self._pipeline_telemetry)
             _merge_fanout_telemetry(self._pipeline_telemetry, fanout_ctx)
             _merge_peer_telemetry(self._pipeline_telemetry, self._peer_ctx)
             placer.flush()
@@ -2223,10 +2279,13 @@ class PendingRestore:
             for plan in self._plans.values():
                 plan.finish_reads(placement)
             placement.run()
+            _settle_destinations()
             event_loop.run_until_complete(storage.close())
         except BaseException as e:  # noqa: BLE001 - must propagate via wait()
             self._exc_info = e
             logger.error("Async restore failed: %r", e)
+            if placer is not None:
+                placer.abandon_destinations()
         finally:
             # Release the exchanged shard bytes whether or not the reads
             # succeeded; the handle may outlive the restore.
@@ -2550,15 +2609,28 @@ def _write_checksum_and_cas_tables(
 
 
 def _restore_destination(
-    entry: "ArrayEntry | ChunkedArrayEntry", current_leaf: Any
-) -> Tuple[np.ndarray, Optional[Callable[[np.ndarray], Any]], bool]:
+    entry: "ArrayEntry | ChunkedArrayEntry",
+    current_leaf: Any,
+    late: bool = False,
+) -> Tuple[
+    Optional[np.ndarray], Optional[Callable[[np.ndarray], Any]], bool
+]:
     """Pick/allocate the host read destination for a dense entry and, when
     the application's current leaf is a device array, a converter that puts
     the restored bytes back on its device/sharding. The third element says
     whether the destination is framework-allocated (owned): only owned
     buffers may be direct-read targets — the application's own in-place
     array keeps copy-on-success semantics so a failed restore can't tear
-    it."""
+    it.
+
+    ``late``: the caller can do without the destination until the read
+    is admitted. It is then None for a leaf whose host bytes nobody sees
+    after placement: one blob that ``device_put`` copies to devices none
+    of which is a CPU. Such a read gets a recycled slab of ``dest_pool``.
+    On the CPU backend ``device_put`` may alias an aligned numpy buffer,
+    so a recycled slab would rewrite an array the application holds;
+    a host ``np.ndarray`` leaf and an uncommitted leaf (``jnp.asarray``)
+    hand their buffer on as is. Those keep a fresh ``np.empty``."""
     if isinstance(current_leaf, np.ndarray) and ArrayIOPreparer.can_load_inplace(
         _as_array_entry(entry), current_leaf
     ):
@@ -2575,7 +2647,6 @@ def _restore_destination(
             list(entry.shape),
             list(current_leaf.shape),
         )
-    dst = ArrayIOPreparer.empty_array_from_entry(entry)
     if is_jax_array(current_leaf):
         import jax
 
@@ -2585,6 +2656,14 @@ def _restore_destination(
         # device makes the restored state unusable in a jit alongside
         # differently-placed arrays.
         committed = getattr(current_leaf, "_committed", True)
+        dst = None
+        if not (
+            late
+            and committed
+            and isinstance(entry, ArrayEntry)
+            and _placement_copies(sharding)
+        ):
+            dst = ArrayIOPreparer.empty_array_from_entry(entry)
 
         def convert(
             host: np.ndarray, batch: Optional["_PlacementBatch"] = None
@@ -2600,7 +2679,27 @@ def _restore_destination(
             return batch.put(host, sharding)
 
         return dst, convert, True
-    return dst, None, True
+    return ArrayIOPreparer.empty_array_from_entry(entry), None, True
+
+
+def _settle_destinations() -> None:
+    """Before a restore hands its arrays to the application: wait until
+    those placed from pooled slabs are on their devices, so that every
+    slab is back while its array can still be asked (``dest_pool``). The
+    wait is the tail of the last placements' transfers."""
+    pool = dest_pool.process_pool()
+    waiting = pool.unsettled()
+    if waiting:
+        with trace_annotation(
+            telemetry.names.SPAN_RESTORE_PLACE, arrays=waiting, bytes=0
+        ):
+            pool.settle()
+
+
+def _placement_copies(sharding: Any) -> bool:
+    """Whether ``device_put`` under ``sharding`` copies the host buffer:
+    no target device is a CPU (whose arrays may alias host memory)."""
+    return all(d.platform != "cpu" for d in sharding.device_set)
 
 
 def _as_array_entry(entry: "ArrayEntry | ChunkedArrayEntry") -> ArrayEntry:

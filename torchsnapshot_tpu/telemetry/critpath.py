@@ -99,6 +99,7 @@ _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_CAPTURE_HOST_COPY: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_OBJECT: SEG_DEVICE_CAPTURE,
     names.SPAN_PIPELINE_BUDGET_ACQUIRE: SEG_BUDGET_WAIT,
+    names.SPAN_RESTORE_DEST_ACQUIRE: SEG_BUDGET_WAIT,
     names.SPAN_PIPELINE_STAGE: SEG_STAGING,
     names.SPAN_LEAF_STAGE: SEG_STAGING,
     names.SPAN_STAGE_D2H: SEG_STAGING,

@@ -258,9 +258,12 @@ SPAN_STAGE_D2H = "stage:d2h"
 # commit:finalize is finalize_checksums + checksum table + manifest +
 # marker, on the caller's thread (sync) or the commit thread (async);
 # restore:plan is the metadata and checksum-table reads (no ``stateful``
-# arg) and each stateful's destination allocation + read planning;
-# restore:place is one batched device_put with its deferred conversions;
-# restore:apply is a stateful's remaining placements + load_state_dict.
+# arg) and each stateful's destination allocation + read planning (a
+# leaf bound for an accelerator gets its destination later, under
+# restore:dest_acquire); restore:place is one batched device_put with
+# its deferred conversions, and, with bytes=0, a restore's wait for its
+# last pooled placements to land before it returns; restore:apply is a
+# stateful's remaining placements + load_state_dict.
 SPAN_TAKE_PLAN = "take:plan"
 SPAN_COMMIT_FINALIZE = "commit:finalize"
 SPAN_RESTORE_PLAN = "restore:plan"
@@ -271,6 +274,11 @@ SPAN_RESTORE_APPLY = "restore:apply"
 # side has no counterpart: the fused native write computes the CRC
 # inside storage:fs_native_write.
 SPAN_VERIFY_BLOB = "verify:blob"
+# scheduler.py: from the moment an admitted read asks for its host
+# destination until it has one, the wait for a slab of dest_pool
+# included (args: blob, bytes, recycled = 1 where the slab had been read
+# into before, 0 where it was made now or the read brought its own).
+SPAN_RESTORE_DEST_ACQUIRE = "restore:dest_acquire"
 
 # manager.py, after the commit, on the thread that called save()/wait():
 # the index update (retention nested inside it: step deletes + chunk GC)

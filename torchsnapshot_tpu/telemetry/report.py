@@ -136,6 +136,11 @@ class SnapshotReport:
     bytes_fetched: Optional[int] = None
     bytes_received: Optional[int] = None
     bytes_needed: Optional[int] = None
+    # Restores whose reads took slabs of the destination pool only
+    # (None elsewhere): the bytes read into a slab that had been read
+    # into before, and into one made for this restore (docs/restore.md).
+    dest_bytes_recycled: Optional[int] = None
+    dest_bytes_fresh: Optional[int] = None
     # Peer-tier restores only (None/empty elsewhere): bytes served per
     # tier of the peer RAM -> local fast -> durable ladder
     # (``{"peer": b, "fast": b, "durable": b}``), and the degradation
@@ -239,7 +244,13 @@ def merge_pipeline_telemetry(
         )
         # Read-amplification accounting (read pipelines only): present
         # in the fold exactly when some pipeline carried it.
-        for key in ("bytes_fetched", "bytes_received", "bytes_needed"):
+        for key in (
+            "bytes_fetched",
+            "bytes_received",
+            "bytes_needed",
+            "dest_bytes_recycled",
+            "dest_bytes_fresh",
+        ):
             if key in p:
                 out[key] = out.get(key, 0) + int(p[key])
         # Write-path variant split (write pipelines only): per-variant
@@ -394,6 +405,8 @@ def build_report(
             if pipeline.get("bytes_needed") is not None
             else None
         ),
+        dest_bytes_recycled=pipeline.get("dest_bytes_recycled"),
+        dest_bytes_fresh=pipeline.get("dest_bytes_fresh"),
         tier_split=(
             {k: int(v) for k, v in pipeline["tier_split"].items()}
             if pipeline.get("tier_split")
