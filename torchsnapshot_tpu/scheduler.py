@@ -874,12 +874,17 @@ async def execute_read_reqs(
                 bytes=cost,
             )
             recycled = False
+            dest = None
             try:
                 if destinations is not None:
                     recycled = await destinations.bind(req.buffer_consumer)
                 dest = req.buffer_consumer.direct_destination()
             finally:
-                recorder.end(dest_span, recycled=int(recycled))
+                recorder.end(
+                    dest_span,
+                    recycled=int(recycled),
+                    direct=int(dest is not None),
+                )
             async with io_slots:
                 stats.io += 1
                 read_io = ReadIO(

@@ -96,8 +96,13 @@ class _OverlapConsumer(BufferConsumer):
     def _consume_sync(self, buf: BufferType) -> None:
         with trace_annotation(metric_names.SPAN_LEAF_CONSUME):
             src = array_from_memoryview(buf, self.dtype, self.buf_shape)
-            for dst_view, src_slices in self.copies:
-                np.copyto(dst_view, src[src_slices], casting="no")
+            with trace_annotation(
+                metric_names.SPAN_RESHARD_COPY,
+                bytes=self.destination_nbytes(),
+                buf_bytes=int(src.nbytes),
+            ):
+                for dst_view, src_slices in self.copies:
+                    np.copyto(dst_view, src[src_slices], casting="no")
 
     def get_consuming_cost_bytes(self) -> int:
         return array_size_bytes(self.buf_shape, self.dtype)
@@ -260,24 +265,30 @@ class ShardedArrayIOPreparer:
             # with a shared ``batch`` the shards ride the restore-wide
             # dispatch instead, and assembly defers until it runs.
             devices = list(device_to_box)
+            per_device = [filled[device_to_box[d]] for d in devices]
+            span = trace_annotation(
+                metric_names.SPAN_RESHARD_ASSEMBLE,
+                devices=len(devices),
+                bytes=sum(int(box.nbytes) for box in per_device),
+            )
             if batch is not None and on_done is not None:
-                slots = [
-                    batch.put(filled[device_to_box[d]], d) for d in devices
-                ]
-                batch.defer(
-                    lambda: on_done(
-                        jax.make_array_from_single_device_arrays(
-                            shape, sharding, [s.value for s in slots]
+                slots = [batch.put(box, d) for box, d in zip(per_device, devices)]
+
+                def make() -> None:
+                    with span:
+                        on_done(
+                            jax.make_array_from_single_device_arrays(
+                                shape, sharding, [s.value for s in slots]
+                            )
                         )
-                    )
-                )
+
+                batch.defer(make)
                 return _DEFERRED
-            arrays = jax.device_put(
-                [filled[device_to_box[d]] for d in devices], devices
-            )
-            return jax.make_array_from_single_device_arrays(
-                shape, sharding, arrays
-            )
+            with span:
+                arrays = jax.device_put(per_device, devices)
+                return jax.make_array_from_single_device_arrays(
+                    shape, sharding, arrays
+                )
 
         return boxes, assemble, True
 
@@ -383,27 +394,41 @@ class ShardedArrayIOPreparer:
         reads). ``target_sharding`` restores under an arbitrary jax
         ``Sharding`` — any layout, any world size — regardless of what
         ``current_leaf`` is (the template-free elastic entry point)."""
-        boxes, assemble, derived_owned = ShardedArrayIOPreparer._destination_boxes(
-            entry, current_leaf, target_sharding=target_sharding
-        )
-        if dest_owned is None:
-            dest_owned = derived_owned
-        read_reqs: List[ReadReq] = []
-
-        for saved in entry.shards:
-            saved_box = Box(tuple(saved.offsets), tuple(saved.sizes))
-            overlaps: List[Tuple[np.ndarray, Overlap]] = []
-            for dst_box, dst_buf in boxes.items():
-                ov = box_overlap(saved_box, dst_box)
-                if ov is not None:
-                    overlaps.append((dst_buf[ov.dst_slices], ov))
-            if not overlaps:
-                continue
-            read_reqs.extend(
-                ShardedArrayIOPreparer._reqs_for_saved_shard(
-                    saved, saved_box, overlaps, buffer_size_limit_bytes,
-                    dest_owned=dest_owned,
+        with trace_annotation(
+            metric_names.SPAN_RESHARD_PLAN, saved_shards=len(entry.shards)
+        ) as span:
+            boxes, assemble, derived_owned = (
+                ShardedArrayIOPreparer._destination_boxes(
+                    entry, current_leaf, target_sharding=target_sharding
                 )
+            )
+            if dest_owned is None:
+                dest_owned = derived_owned
+            read_reqs: List[ReadReq] = []
+
+            for saved in entry.shards:
+                saved_box = Box(tuple(saved.offsets), tuple(saved.sizes))
+                overlaps: List[Tuple[np.ndarray, Overlap]] = []
+                for dst_box, dst_buf in boxes.items():
+                    ov = box_overlap(saved_box, dst_box)
+                    if ov is not None:
+                        overlaps.append((dst_buf[ov.dst_slices], ov))
+                if not overlaps:
+                    continue
+                read_reqs.extend(
+                    ShardedArrayIOPreparer._reqs_for_saved_shard(
+                        saved, saved_box, overlaps, buffer_size_limit_bytes,
+                        dest_owned=dest_owned,
+                    )
+                )
+            span.annotate(
+                dest_boxes=len(boxes),
+                reads=len(read_reqs),
+                bytes_needed=sum(int(b.nbytes) for b in boxes.values()),
+                bytes_to_read=sum(
+                    r.buffer_consumer.get_consuming_cost_bytes()
+                    for r in read_reqs
+                ),
             )
 
         def finalize(batch=None) -> None:

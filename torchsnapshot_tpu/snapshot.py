@@ -1744,15 +1744,34 @@ class _PlacementBatch:
     def defer(self, fn: Callable[[], None]) -> None:
         self._deferred.append(fn)
 
+    def _bytes_by_device(self) -> Dict[str, int]:
+        """Host bytes this batch sends to each device, by device id: a
+        target is one device (a sharded leaf's box) or a sharding, every
+        device of which gets its shard of the value."""
+        out: Dict[str, int] = {}
+        for value, target in zip(self._values, self._targets):
+            nbytes = int(getattr(value, "nbytes", 0))
+            devices = [target]
+            if hasattr(target, "shard_shape"):
+                devices = list(target.addressable_devices)
+                shard = target.shard_shape(value.shape)
+                nbytes = int(np.prod(shard, dtype=np.int64)) * value.dtype.itemsize
+            for device in devices:
+                key = str(device.id)
+                out[key] = out.get(key, 0) + nbytes
+        return out
+
     def run(self) -> None:
         if not self._values and not self._deferred:
             return
         # On the thread that calls it: the scheduler's event loop for a
         # streamed flush (reads wait behind it), else the restore's own.
+        by_device = self._bytes_by_device()
         with trace_annotation(
             telemetry.names.SPAN_RESTORE_PLACE,
             arrays=len(self._values),
             bytes=sum(int(getattr(v, "nbytes", 0)) for v in self._values),
+            **({"bytes_by_device": by_device} if len(by_device) > 1 else {}),
         ):
             if self._values:
                 import jax

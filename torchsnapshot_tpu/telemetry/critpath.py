@@ -94,6 +94,7 @@ SEG_OTHER = "other"
 _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_TAKE_PLAN: SEG_PLAN,
     names.SPAN_RESTORE_PLAN: SEG_PLAN,
+    names.SPAN_RESHARD_PLAN: SEG_PLAN,
     names.SPAN_DEVICE_CAPTURE: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_CLONE: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_HOST_COPY: SEG_DEVICE_CAPTURE,
@@ -112,6 +113,7 @@ _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_FS_NATIVE_DIRECT_WRITE: SEG_WRITE_DRAIN,
     names.SPAN_PIPELINE_CONSUME: SEG_READ_DRAIN,
     names.SPAN_LEAF_CONSUME: SEG_READ_DRAIN,
+    names.SPAN_RESHARD_COPY: SEG_READ_DRAIN,
     names.SPAN_BATCHER_CONSUME_SPANNING: SEG_READ_DRAIN,
     names.SPAN_STORAGE_READ: SEG_READ_DRAIN,
     names.SPAN_FS_NATIVE_READ: SEG_READ_DRAIN,
@@ -119,6 +121,7 @@ _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_VERIFY_BLOB: SEG_READ_DRAIN,
     names.SPAN_RESTORE_PLACE: SEG_PLACEMENT,
     names.SPAN_RESTORE_APPLY: SEG_PLACEMENT,
+    names.SPAN_RESHARD_ASSEMBLE: SEG_PLACEMENT,
     names.SPAN_COMMIT_FINALIZE: SEG_COMMIT,
     names.SPAN_MANAGER_INDEX: SEG_COMMIT,
     names.SPAN_MANAGER_RETENTION: SEG_COMMIT,

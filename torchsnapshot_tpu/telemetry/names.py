@@ -261,9 +261,11 @@ SPAN_STAGE_D2H = "stage:d2h"
 # arg) and each stateful's destination allocation + read planning (a
 # leaf bound for an accelerator gets its destination later, under
 # restore:dest_acquire); restore:place is one batched device_put with
-# its deferred conversions, and, with bytes=0, a restore's wait for its
-# last pooled placements to land before it returns; restore:apply is a
-# stateful's remaining placements + load_state_dict.
+# its deferred conversions (args: arrays, bytes, and bytes_by_device
+# where the batch reaches more than one device), and, with bytes=0, a
+# restore's wait for its last pooled placements to land before it
+# returns; restore:apply is a stateful's remaining placements +
+# load_state_dict.
 SPAN_TAKE_PLAN = "take:plan"
 SPAN_COMMIT_FINALIZE = "commit:finalize"
 SPAN_RESTORE_PLAN = "restore:plan"
@@ -277,8 +279,25 @@ SPAN_VERIFY_BLOB = "verify:blob"
 # scheduler.py: from the moment an admitted read asks for its host
 # destination until it has one, the wait for a slab of dest_pool
 # included (args: blob, bytes, recycled = 1 where the slab had been read
-# into before, 0 where it was made now or the read brought its own).
+# into before, 0 where it was made now or the read brought its own;
+# direct = 1 where the read was handed that destination to land in, 0
+# where its consumer takes a buffer and copies).
 SPAN_RESTORE_DEST_ACQUIRE = "restore:dest_acquire"
+# sharded_io_preparer.py: a leaf saved in shards, restored onto the
+# layout its destination has now (docs/restore.md "Resharding").
+# reshard:plan is one leaf's planning inside restore:plan: destination
+# boxes, box overlaps, row bands (args: saved_shards, dest_boxes, reads,
+# bytes_needed = bytes of the boxes, bytes_to_read = bytes of the planned
+# ranges). reshard:copy is, inside consume:leaf, the np.copyto loop that
+# carries one read buffer's overlaps into their boxes (args: bytes =
+# copied, buf_bytes = the read buffer's); a read handed its box to land
+# in opens none and ends its restore:dest_acquire with direct=1.
+# reshard:assemble is one leaf's boxes made into the global array, one
+# box per device (args: devices, bytes): inside restore:place where the
+# placement batch carries the transfers, with its own device_put else.
+SPAN_RESHARD_PLAN = "reshard:plan"
+SPAN_RESHARD_COPY = "reshard:copy"
+SPAN_RESHARD_ASSEMBLE = "reshard:assemble"
 
 # manager.py, after the commit, on the thread that called save()/wait():
 # the index update (retention nested inside it: step deletes + chunk GC)
