@@ -474,6 +474,54 @@ def _pool_cap_is_a_high_water_mark(pool) -> None:
     assert pool.retained_bytes() == 1008  # both are out; none was free
 
 
+def _pool_takes_several_or_none(pool) -> None:
+    # A leaf's four boxes under a cap of six: all four, then none of the
+    # next four (never two of them), then those that came back and two new.
+    first = pool.try_take_all([100] * 4, 600)
+    assert len(first) == 4 and not any(s.recycled for s in first)
+    assert pool.try_take_all([100] * 4, 600) is None
+    assert pool.retained_bytes() == 400 and sum(pool._out_sizes.values()) == 4
+    for slab in first[:2]:
+        pool.give_back(slab)
+    assert pool.try_take_all([100] * 4, 600, may_grow=False) is None
+    second = pool.try_take_all([100] * 4, 600)
+    assert [s.recycled for s in second] == [True, True, False, False]
+    assert pool.retained_bytes() == 600 and sum(pool._out_sizes.values()) == 6
+
+
+def _pool_goes_over_its_cap_only_when_told(pool) -> None:
+    # A leaf's boxes fill the cap; the buffer its reads are copied out of
+    # gets no room, but for the pipeline none of whose buffers is out.
+    boxes = pool.try_take_all([100] * 4, 400)
+    landed = FakePlaced()
+    landed.land()
+    spare = pool.try_take(50, 500)
+    pool.placed(spare, landed)
+    pool.sweep()  # a free slab of another size
+    assert boxes is not None and pool.retained_bytes() == 450
+    # No 200 is out to wait for, so the free 50 gives way; still no room.
+    assert pool.try_take_all([200], 500, keep_sizes={50, 200}) is None
+    assert pool.retained_bytes() == 400
+    (buffer,) = pool.try_take_all([200], 500, keep_sizes={50, 200}, over_cap=True)
+    assert pool.retained_bytes() == 600 and not buffer.recycled
+    pool.give_back(buffer)
+    assert pool.try_take(200, 500) is buffer
+
+
+def _pool_waits_for_a_box_held_by_several_devices(pool) -> None:
+    from torchsnapshot_tpu.dest_pool import PlacedTogether
+
+    slab = pool.try_take(100, 100)
+    on_two = [FakePlaced(), FakePlaced()]
+    pool.placed(slab, PlacedTogether(on_two))
+    on_two[0].land()
+    pool.sweep()
+    assert pool.try_take(100, 100) is None  # the second device still reads it
+    on_two[1].land()
+    pool.settle()
+    assert pool.try_take(100, 100) is slab
+
+
 @pytest.mark.parametrize(
     "case",
     [
@@ -482,6 +530,9 @@ def _pool_cap_is_a_high_water_mark(pool) -> None:
         _pool_drops_deleted,
         _pool_keeps_the_plans_sizes,
         _pool_cap_is_a_high_water_mark,
+        _pool_takes_several_or_none,
+        _pool_goes_over_its_cap_only_when_told,
+        _pool_waits_for_a_box_held_by_several_devices,
     ],
     ids=lambda f: f.__name__.strip("_"),
 )
@@ -503,6 +554,12 @@ def test_pipeline_cap_bytes() -> None:
     # Clamped to the budget, never below one destination.
     assert pipeline_cap_bytes(neox, 500 * MiB) == 500 * MiB
     assert pipeline_cap_bytes(neox, 100 * MiB) == 394 * MiB
+    # Read buffers add what is in flight of the largest, not their sum
+    # over the plan, and the floor holds one leaf's boxes and one buffer.
+    halves = [n // 2 for n in neox for _ in range(2)]
+    assert pipeline_cap_bytes(neox, 1 << 40, halves, 4) == (4 * 394 + 4 * 197) * MiB
+    assert pipeline_cap_bytes(neox, 1 << 40, halves, 16) == 4 * 394 * MiB + sum(halves)
+    assert pipeline_cap_bytes(neox, 100 * MiB, halves, 16) == (394 + 197) * MiB
 
 
 class LateConsumer(BufferConsumer):
@@ -623,5 +680,117 @@ def test_failed_leased_reads_leave_the_pool_usable() -> None:
         assert copies == {f"b/{i}": bytes([i]) * 64 for i in range(6)}
         pool.settle()
         assert pool.retained_bytes() == 128
+    finally:
+        loop.close()
+
+
+class SharedBoxes:
+    """A sharded leaf's destinations as the read pipeline sees them: two
+    boxes its two reads fill together, bound when the first read comes."""
+
+    def __init__(self, nbytes: int) -> None:
+        self.nbytes = nbytes
+        self.halves = None
+        self.on_placed = []
+        self.recycled = False
+
+    def unbound_sizes(self):
+        return [self.nbytes // 2] * 2 if self.halves is None else []
+
+    def bind(self, bufs, on_placed, recycled) -> None:
+        self.halves, self.on_placed, self.recycled = list(bufs), list(on_placed), recycled
+
+
+class CopiedRead(BufferConsumer):
+    """One of the two reads of a leaf: lands in a lent buffer when the
+    storage plugin takes it, and is copied into its half of the leaf."""
+
+    def __init__(self, boxes: SharedBoxes, half: int) -> None:
+        self.boxes, self.half = boxes, half
+
+    async def consume_buffer(self, buf, executor=None) -> None:
+        self.boxes.halves[self.half][:] = bytearray(buf)
+
+    def get_consuming_cost_bytes(self) -> int:
+        return self.boxes.nbytes // 2
+
+    def shared_destination(self):
+        return self.boxes
+
+    def read_buffer_bytes(self) -> int:
+        return self.boxes.nbytes // 2
+
+
+class ReadsIntoDest(SlowStorage):
+    """Takes a lent destination where it fits, as the fs plugin does."""
+
+    async def read(self, read_io: ReadIO) -> None:
+        await asyncio.sleep(0.002)
+        data = self.blobs[read_io.path]
+        if read_io.dest is not None and read_io.dest.nbytes == len(data):
+            read_io.dest[:] = data
+            read_io.buf = read_io.dest
+        else:
+            read_io.buf = memoryview(data)
+
+
+@pytest.mark.parametrize("held_by_another_restore", [0, 64], ids=["room-for-a-buffer", "no-room"])
+def test_a_leaf_that_holds_its_boxes_always_gets_a_buffer(held_by_another_restore: int) -> None:
+    """Six leaves of two reads each under a cap of one leaf's boxes and one
+    buffer: a leaf takes both its boxes or neither, each read is copied out
+    of a lent buffer, and where another restore's slab has taken the
+    buffer's room the pipeline's first buffer is made over the cap (with
+    none out, nothing would come back for the leaf that holds its boxes)."""
+    from torchsnapshot_tpu.dest_pool import DestinationLeases, DestinationPool
+
+    loop = asyncio.new_event_loop()
+    storage, pool, leaf, n = ReadsIntoDest(), DestinationPool(), 128, 6
+    leaves = [SharedBoxes(leaf) for _ in range(n)]
+    reqs = []
+    for i, boxes in enumerate(leaves):
+        for half in range(2):
+            storage.blobs[f"b/{i}/{half}"] = bytes([2 * i + half + 1]) * (leaf // 2)
+            reqs.append(ReadReq(path=f"b/{i}/{half}", buffer_consumer=CopiedRead(boxes, half)))
+    done, placed = [], {}
+
+    def flush() -> None:
+        while done:
+            boxes = done.pop()
+            placed[id(boxes)] = b"".join(bytes(h) for h in boxes.halves)
+            for on_placed in boxes.on_placed:
+                value = FakePlaced()
+                on_placed(value)
+                threading.Timer(0.005, value.land).start()
+
+    remaining = {id(b): 2 for b in leaves}
+    cap = leaf + leaf // 2
+    if held_by_another_restore:
+        assert pool.try_take(held_by_another_restore, cap) is not None
+    leases = DestinationLeases.for_reads(
+        pool, [r.buffer_consumer for r in reqs], cap, flush, reads_in_flight=16
+    )
+    assert leases._cap == cap
+    peak = [0]
+
+    def on_req_complete(req) -> None:
+        peak[0] = max(peak[0], pool.retained_bytes())
+        boxes = req.buffer_consumer.boxes
+        remaining[id(boxes)] -= 1
+        if not remaining[id(boxes)]:
+            done.append(boxes)
+            if leases.starved:
+                flush()
+
+    try:
+        sync_execute_read_reqs(
+            reqs, storage, 10**6, 0, loop, on_req_complete=on_req_complete, destinations=leases
+        )
+        flush()
+        pool.settle()
+        for i, boxes in enumerate(leaves):
+            assert placed[id(boxes)] == bytes([2 * i + 1]) * 64 + bytes([2 * i + 2]) * 64
+        assert peak[0] == cap + held_by_another_restore and not leases._out
+        assert leases.bytes_fresh == cap
+        assert leases.bytes_recycled == n * 2 * leaf - cap
     finally:
         loop.close()

@@ -323,7 +323,10 @@ def test_column_partial_destinations_use_ranged_reads(tmp_path) -> None:
     Before the shared planner, any trailing-sliced overlap fell back to
     a whole-shard read."""
     from torchsnapshot_tpu.manifest import ShardedArrayEntry
-    from torchsnapshot_tpu.sharded_io_preparer import ShardedArrayIOPreparer
+    from torchsnapshot_tpu.sharded_io_preparer import (
+        ShardedArrayIOPreparer,
+        _LeafBoxes,
+    )
     from torchsnapshot_tpu.serialization import array_size_bytes
 
     sharding = NamedSharding(_mesh((2,), ("x",)), P(None, "x"))  # 2 col shards
@@ -340,8 +343,9 @@ def test_column_partial_destinations_use_ranged_reads(tmp_path) -> None:
     dst_box = Box((8, 0), (8, 6))
     ov = box_overlap(saved_box, dst_box)
     view = np.zeros((8, 6), np.float32)
+    boxes = _LeafBoxes(np.float32, [dst_box], arrays={dst_box: view})
     reqs = ShardedArrayIOPreparer._reqs_for_saved_shard(
-        saved, saved_box, [(view, ov)]
+        saved, saved_box, boxes, [(dst_box, ov)]
     )
     assert reqs and all(r.byte_range is not None for r in reqs)
     fetched = sum(r.byte_range[1] - r.byte_range[0] for r in reqs)
@@ -415,3 +419,59 @@ def test_sharded_prepare_read_requires_np_destination(tmp_path) -> None:
     entry = snap.get_manifest()["0/m/w"]
     with pytest.raises(ValueError, match="np.ndarray destination"):
         prepare_read(entry, obj_out=None)
+
+
+def test_contiguous_in_agrees_with_numpy() -> None:
+    """Whether a read lands in its box is decided before the box exists
+    (a pooled box is bound when its leaf's first read comes): the geometry
+    alone has to say what numpy says of the view."""
+    from torchsnapshot_tpu.sharded_io_preparer import _contiguous_in
+
+    rng = np.random.default_rng(11)
+    seen = set()
+    for _ in range(400):
+        ndim = int(rng.integers(1, 4))
+        sizes = tuple(int(rng.integers(1, 5)) for _ in range(ndim))
+        starts = [int(rng.integers(0, s)) for s in sizes]
+        slices = tuple(
+            slice(a, int(rng.integers(a + 1, s + 1))) for a, s in zip(starts, sizes)
+        )
+        want = np.empty(sizes, np.float32)[slices].flags.c_contiguous
+        assert _contiguous_in(sizes, slices) == want, (sizes, slices)
+        seen.add(want)
+    assert seen == {True, False}
+    assert _contiguous_in((), ())
+
+
+def test_late_boxes_nobody_bound_are_made_once_under_racing_consumers() -> None:
+    """A leaf's consumers run on several executor threads; where no read
+    pipeline bound the leaf's late boxes, whichever thread comes first
+    makes them, and every other thread copies into the same arrays."""
+    import sys
+    import threading
+
+    from torchsnapshot_tpu.sharded_io_preparer import _LeafBoxes
+
+    boxes = [Box((0, 0), (4, 8)), Box((4, 0), (4, 8))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for _ in range(50):
+            leaf = _LeafBoxes(np.float32, boxes, late=True)
+            assert leaf.unbound_sizes() == [128, 128]
+            start, seen = threading.Barrier(16), []
+
+            def consume() -> None:
+                start.wait(10)
+                seen.append(leaf.arrays())
+
+            threads = [threading.Thread(target=consume) for _ in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(10)
+            assert not any(t.is_alive() for t in threads)
+            assert len(seen) == 16 and all(arrays is seen[0] for arrays in seen)
+            assert leaf.unbound_sizes() == []
+    finally:
+        sys.setswitchinterval(interval)

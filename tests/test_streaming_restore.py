@@ -373,3 +373,238 @@ def test_one_slab_pool_does_not_deadlock(tmp_path, accelerator_path, flush_bytes
     report = telemetry.last_report("restore")
     assert report.dest_bytes_fresh == leaf
     assert report.dest_bytes_recycled == (len(src) - 1) * leaf
+
+
+# ---------------------------------------------------------------------------
+# Pooled boxes and read buffers of sharded leaves (sharded_io_preparer.py)
+# ---------------------------------------------------------------------------
+
+N_SHARDED = 6
+SHARDED_SHAPE = (64, 8)
+LEAF = 64 * 8 * 4
+
+
+def _mesh(shape, names):
+    n = int(np.prod(shape))
+    return jax.sharding.Mesh(np.asarray(jax.devices()[:n]).reshape(shape), names)
+
+
+def _sharded_tree(seed, sharding):
+    """Half the leaves split by rows, half by columns where the layout
+    has both; every element distinct within its leaf and between seeds."""
+    rng = np.random.default_rng(seed)
+    full = {
+        f"w{i}": rng.standard_normal(SHARDED_SHAPE).astype(np.float32)
+        for i in range(N_SHARDED)
+    }
+    return full, {k: jax.device_put(v, sharding(i)) for i, (k, v) in enumerate(full.items())}
+
+
+def _sharding(mesh, row_spec, col_spec):
+    from jax.sharding import NamedSharding
+
+    return lambda i: NamedSharding(mesh, row_spec if i % 2 else col_spec)
+
+
+def _layouts(name):
+    """(saved, restored) shardings by leaf number."""
+    from jax.sharding import PartitionSpec as P
+
+    if name == "tp2->4":
+        rows, cols = P("tp"), P(None, "tp")
+        return (_sharding(_mesh((2,), ("tp",)), rows, cols),
+                _sharding(_mesh((4,), ("tp",)), rows, cols))
+    if name == "rows->cols-on-two-devices-each":
+        # Every restored box lives on two devices (replicated over "sp").
+        return (_sharding(_mesh((2,), ("tp",)), P("tp"), P("tp")),
+                _sharding(_mesh((2, 2), ("sp", "tp")), P(None, "tp"), P(None, "tp")))
+    assert name == "same"
+    both = _sharding(_mesh((4,), ("tp",)), P("tp"), P(None, "tp"))
+    return both, both
+
+
+def _assert_every_shard_is_the_saved_one(tree, full):
+    for key, leaf in tree.items():
+        for shard in leaf.addressable_shards:
+            got, want = np.asarray(shard.data), full[key][shard.index]
+            assert got.shape == want.shape, (key, shard.index)
+            np.testing.assert_array_equal(
+                got.view(np.uint32), want.view(np.uint32), err_msg=f"{key} {shard.device}"
+            )
+
+
+def _restore_sharded(path, restored_sharding, seed=99):
+    from torchsnapshot_tpu import telemetry
+
+    _, live = _sharded_tree(seed, restored_sharding)
+    dest = {"m": ts.PyTreeState(live)}
+    ts.Snapshot(path).restore(dest)
+    return dest["m"].tree, telemetry.last_report("restore")
+
+
+def _dest_spans(mark):
+    from torchsnapshot_tpu.telemetry import names, trace
+
+    events = trace.get_recorder().events_since(mark)
+    return (
+        [e["args"] for e in events if e.get("name") == names.SPAN_RESTORE_DEST_ACQUIRE],
+        [e["args"] for e in events if e.get("name") == names.SPAN_RESHARD_COPY],
+    )
+
+
+@pytest.mark.parametrize("layout", ["tp2->4", "rows->cols-on-two-devices-each"])
+def test_resharded_restores_recycle_boxes_and_buffers(tmp_path, accelerator_path, layout):
+    """Two resharding restores of different snapshots in one process: the
+    second takes every box and every read buffer from what the first
+    faulted in, every shard of both is the saved one at its own index bit
+    for bit, and the first restore's arrays do not change under the
+    second."""
+    from torchsnapshot_tpu.telemetry import trace
+
+    pool = accelerator_path
+    saved, restored = _layouts(layout)
+    full_a, tree_a = _sharded_tree(1, saved)
+    full_b, tree_b = _sharded_tree(2, saved)
+    ts.Snapshot.take(str(tmp_path / "a"), {"m": ts.PyTreeState(tree_a)})
+    ts.Snapshot.take(str(tmp_path / "b"), {"m": ts.PyTreeState(tree_b)})
+
+    out_a, report_a = _restore_sharded(str(tmp_path / "a"), restored)
+    _assert_every_shard_is_the_saved_one(out_a, full_a)
+    bits_a = _bits(out_a)
+    # Boxes and buffers: every leaf's bytes twice (each saved shard is read
+    # whole into a buffer and copied into two boxes).
+    assert report_a.dest_bytes_fresh + report_a.dest_bytes_recycled == 2 * N_SHARDED * LEAF
+    assert 0 < report_a.dest_bytes_fresh == pool.retained_bytes()
+    assert pool.unsettled() == 0 and sum(pool._out_sizes.values()) == 0
+
+    mark = trace.get_recorder().mark()
+    out_b, report_b = _restore_sharded(str(tmp_path / "b"), restored)
+    assert (report_b.dest_bytes_recycled, report_b.dest_bytes_fresh) == (2 * N_SHARDED * LEAF, 0)
+    _assert_every_shard_is_the_saved_one(out_b, full_b)
+    _assert_bits(out_a, bits_a)
+    _assert_every_shard_is_the_saved_one(out_a, full_a)
+    assert pool.retained_bytes() == report_a.dest_bytes_fresh
+
+    acquired, copied = _dest_spans(mark)
+    assert len(acquired) == 2 * N_SHARDED and not any(a["direct"] for a in acquired)
+    assert all(a["recycled"] == 1 for a in acquired)
+    # One read a leaf bound its boxes; every read was copied out of a buffer.
+    assert sorted(a["box_bytes"] for a in acquired) == [0] * N_SHARDED + [LEAF] * N_SHARDED
+    assert all(a["box_bytes_recycled"] == a["box_bytes"] for a in acquired)
+    assert sum(c["bytes"] for c in copied) == sum(c["buf_bytes"] for c in copied) == N_SHARDED * LEAF
+
+
+@pytest.mark.parametrize("flush_bytes", [1, 1 << 30], ids=["flush-1B", "flush-1GiB"])
+def test_a_pool_of_one_leafs_boxes_and_one_buffer_does_not_deadlock(
+    tmp_path, accelerator_path, flush_bytes
+):
+    """A cap that holds one leaf's boxes and one read buffer: every other
+    leaf waits for all its boxes at once (never for half of them), the
+    leaf that has its boxes always finds the room of a buffer, and its
+    placement is flushed for the leaves that wait."""
+    from torchsnapshot_tpu.knobs import override_per_rank_memory_budget_bytes
+
+    pool = accelerator_path
+    saved, restored = _layouts("tp2->4")
+    full, tree = _sharded_tree(3, saved)
+    ts.Snapshot.take(str(tmp_path / "snap"), {"m": ts.PyTreeState(tree)})
+    cap = LEAF + LEAF // 2
+    with override_per_rank_memory_budget_bytes(cap), override_restore_placement_flush_bytes(flush_bytes):
+        out, report = _restore_sharded(str(tmp_path / "snap"), restored)
+    _assert_every_shard_is_the_saved_one(out, full)
+    assert pool.retained_bytes() == cap
+    assert report.dest_bytes_fresh == cap
+    assert report.dest_bytes_recycled == 2 * N_SHARDED * LEAF - cap
+
+
+def test_a_corrupt_shard_fails_the_restore_and_leaves_the_pool_usable(tmp_path, accelerator_path):
+    """A saved shard that fails its checksum: the restore raises, the boxes
+    and buffers it had out are dropped rather than reused (a thread may
+    still write into them), and the next restore is correct."""
+    import glob
+
+    from torchsnapshot_tpu.integrity import ChecksumError
+
+    pool = accelerator_path
+    saved, restored = _layouts("tp2->4")
+    full, tree = _sharded_tree(4, saved)
+    good, bad = str(tmp_path / "good"), str(tmp_path / "bad")
+    ts.Snapshot.take(good, {"m": ts.PyTreeState(tree)})
+    ts.Snapshot.take(bad, {"m": ts.PyTreeState(tree)})
+    _restore_sharded(good, restored)  # fills the pool
+    held = pool.retained_bytes()
+    slabs_before = {id(s) for s in _slabs(pool)}
+
+    (victim,) = glob.glob(f"{bad}/sharded/m/w4_0_0")
+    with open(victim, "r+b") as f:
+        f.seek(100)
+        f.write(b"\xff\x00\xff\x00")
+    with pytest.raises(ChecksumError):
+        _restore_sharded(bad, restored)
+    pool.settle()
+    assert sum(pool._out_sizes.values()) == 0
+    dropped = held - pool.retained_bytes()  # what the failed restore had out
+    assert dropped > 0
+    assert {id(s) for s in _slabs(pool)} < slabs_before
+
+    out, report = _restore_sharded(good, restored)
+    _assert_every_shard_is_the_saved_one(out, full)
+    assert report.dest_bytes_fresh == dropped
+    assert pool.retained_bytes() == held
+
+
+def test_a_same_layout_sharded_restore_leases_boxes_and_no_buffer(tmp_path, accelerator_path):
+    """Saved and restored under one layout: every read lands in its box,
+    which is a slab of the pool; no buffer is leased, nothing is copied."""
+    from torchsnapshot_tpu.telemetry import trace
+
+    pool = accelerator_path
+    saved, restored = _layouts("same")
+    full, tree = _sharded_tree(5, saved)
+    ts.Snapshot.take(str(tmp_path / "snap"), {"m": ts.PyTreeState(tree)})
+    _, report = _restore_sharded(str(tmp_path / "snap"), restored)
+    assert report.dest_bytes_fresh + report.dest_bytes_recycled == N_SHARDED * LEAF
+    mark = trace.get_recorder().mark()
+    out, report = _restore_sharded(str(tmp_path / "snap"), restored)
+    _assert_every_shard_is_the_saved_one(out, full)
+    assert (report.dest_bytes_recycled, report.dest_bytes_fresh) == (N_SHARDED * LEAF, 0)
+    acquired, copied = _dest_spans(mark)
+    assert not copied
+    assert len(acquired) == 4 * N_SHARDED and all(a["direct"] and a["recycled"] for a in acquired)
+    assert sum(a["box_bytes"] for a in acquired) == N_SHARDED * LEAF
+    assert {s.nbytes for s in _slabs(pool)} == {LEAF // 4}
+
+
+@pytest.mark.parametrize("target", ["cpu-backend", "host-array", "uncommitted"])
+def test_sharded_leaves_nobody_may_pool_allocate_as_ever(tmp_path, request, target):
+    """On the real CPU backend (no fixture: a placed array may alias its
+    host buffer), and on the accelerator's path for a host ``np.ndarray``
+    target and an uncommitted leaf, a sharded entry's boxes and read
+    buffers are fresh allocations: the pool stays empty and the report
+    counts nothing."""
+    from torchsnapshot_tpu import dest_pool, telemetry
+
+    saved, restored = _layouts("tp2->4")
+    full, tree = _sharded_tree(6, saved)
+    path = str(tmp_path / "snap")
+    ts.Snapshot.take(path, {"m": ts.PyTreeState(tree)})
+    if target == "cpu-backend":
+        pool = dest_pool.process_pool()
+        pool.clear()
+        _, live = _sharded_tree(7, restored)
+    else:
+        pool = request.getfixturevalue("accelerator_path")
+        make = np.zeros if target == "host-array" else jnp.zeros
+        live = {k: make(SHARDED_SHAPE, np.float32) for k in full}
+    for _ in range(2):
+        dest = {"m": ts.PyTreeState(dict(live))}
+        ts.Snapshot(path).restore(dest)
+        for key, leaf in dest["m"].tree.items():
+            np.testing.assert_array_equal(np.asarray(leaf), full[key], err_msg=key)
+            if target == "host-array":
+                assert leaf is live[key]
+            if target == "uncommitted":
+                assert not leaf._committed
+        assert pool.retained_bytes() == 0
+        report = telemetry.last_report("restore")
+        assert report.dest_bytes_recycled is None and report.dest_bytes_fresh is None

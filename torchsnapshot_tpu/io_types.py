@@ -107,9 +107,11 @@ class ReadIO:
     storage plugin.
 
     ``dest``, when set, is a writable view of the read's final destination
-    (exactly the requested length). Plugins MAY read straight into it and
-    set ``buf = dest`` — skipping the intermediate allocation and the
-    consumer's copy — or ignore it and fill ``buf`` as usual.
+    (exactly the requested length), or of a buffer the read pipeline lends
+    the read (``BufferConsumer.read_buffer_bytes``). Plugins MAY read
+    straight into it and set ``buf = dest`` — skipping the intermediate
+    allocation and, for a final destination, the consumer's copy — or
+    ignore it and fill ``buf`` as usual.
 
     ``served_by`` is stamped by multi-source plugins (tiered, the peer
     ladder) with the tier that produced ``buf`` — the state
@@ -182,6 +184,26 @@ class BufferConsumer(abc.ABC):
         what was placed on a device from it: ``buf`` is the caller's again
         when ``value`` is ready."""
         raise NotImplementedError
+
+    def shared_destination(self) -> Optional[Any]:
+        """The destinations this consumer fills together with the other
+        reads of its leaf (a sharded leaf's boxes), where the read
+        pipeline may bind them, all at once, when the first of those
+        reads comes: an object with ``unbound_sizes()`` (the bytes of
+        each destination still to bind), ``bind(bufs, on_placed,
+        recycled)`` (a uint8 array and an ``on_placed(value)`` as in
+        :meth:`bind_destination` for each, and whether all of them were
+        used before) and ``recycled``. None for a consumer whose
+        destinations are its own or exist already."""
+        return None
+
+    def read_buffer_bytes(self) -> int:
+        """Bytes of a buffer the read pipeline may lend this consumer's
+        read to land in (``ReadIO.dest``) before :meth:`consume_buffer`
+        copies out of it; the buffer is the pipeline's again when that
+        returns. 0 for a read that lands in its destination, and for one
+        whose storage plugin is to allocate as it always did."""
+        return 0
 
 
 @dataclass

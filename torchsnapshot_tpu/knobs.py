@@ -101,7 +101,7 @@ _COLD_START_BUDGET_FRACTION_ENV = (
     "TORCHSNAPSHOT_TPU_COLD_START_BUDGET_FRACTION"
 )
 
-_DEFAULT_TRACE_BUFFER_EVENTS: int = 16384
+_DEFAULT_TRACE_BUFFER_EVENTS: int = 65536
 _DEFAULT_WATCHDOG_SECONDS: float = 60.0
 _DEFAULT_WAIT_DURABLE_TIMEOUT_SECONDS: float = 1800.0
 _DEFAULT_PROGRESS_SECONDS: float = 1.0

@@ -41,6 +41,7 @@ if TYPE_CHECKING:  # pragma: no cover - typing only
     from .telemetry.progress import ProgressTracker
 
 from . import knobs, telemetry
+from .dest_pool import Lease
 from .telemetry.trace import get_recorder as _trace_recorder
 from .utils.tracing import run_in_executor, trace_annotation
 from .integrity import (
@@ -824,9 +825,12 @@ async def execute_read_reqs(
     exchanged shards — the exchange already accounted those). The
     telemetry dict reports the sum as ``bytes_fetched``.
 
-    ``destinations`` gives an admitted read whose consumer came without a
+    ``destinations`` gives a read whose consumer came without a
     destination a slab of the process's pool (``dest_pool``), waiting for
-    one where the pool is at its cap."""
+    one where the pool is at its cap: a dense leaf's when the read is
+    admitted, a sharded leaf's boxes (all of them, with its first read)
+    before that, and a read that is copied into its boxes a buffer to land
+    in, which is the pool's again when the copy returns."""
     budget = MemoryBudget(memory_budget_bytes)
     stats = _PipelineStats()
     stats.pending = len(read_reqs)
@@ -855,9 +859,37 @@ async def execute_read_reqs(
 
     recorder = _trace_recorder()
 
+    def begin_dest_span(req: ReadReq, cost: int) -> int:
+        # Recorder-only: the span crosses the wait for a slab.
+        return recorder.begin(
+            telemetry.names.SPAN_RESTORE_DEST_ACQUIRE, blob=req.path, bytes=cost
+        )
+
+    def end_dest_span(span: int, lease: Lease, direct: bool) -> None:
+        recorder.end(
+            span,
+            recycled=int(lease.recycled),
+            direct=int(direct),
+            box_bytes=lease.box_bytes,
+            box_bytes_recycled=lease.box_bytes_recycled,
+        )
+
     async def read_one(req: ReadReq) -> None:
         nonlocal fused_read_declined
-        cost = req.buffer_consumer.get_consuming_cost_bytes()
+        consumer = req.buffer_consumer
+        cost = consumer.get_consuming_cost_bytes()
+        lease = Lease()
+        dest_span = None
+        if destinations is not None and consumer.shared_destination() is not None:
+            # A leaf's boxes come before the budget: a read that waits
+            # for them must not hold budget that the reads of the leaves
+            # holding them wait for. Its span then covers that wait too.
+            dest_span = begin_dest_span(req, cost)
+            try:
+                await destinations.bind_shared(consumer, lease)
+            except BaseException:
+                end_dest_span(dest_span, lease, False)
+                raise
         await budget.acquire(cost)
         stats.pending -= 1
         try:
@@ -867,24 +899,18 @@ async def execute_read_reqs(
                 else None
             )
             fused_pages = None
-            # Recorder-only: the span crosses the wait for a slab.
-            dest_span = recorder.begin(
-                telemetry.names.SPAN_RESTORE_DEST_ACQUIRE,
-                blob=req.path,
-                bytes=cost,
-            )
-            recycled = False
+            if dest_span is None:
+                dest_span = begin_dest_span(req, cost)
             dest = None
             try:
                 if destinations is not None:
-                    recycled = await destinations.bind(req.buffer_consumer)
-                dest = req.buffer_consumer.direct_destination()
+                    await destinations.bind(consumer, lease)
+                dest = consumer.direct_destination()
             finally:
-                recorder.end(
-                    dest_span,
-                    recycled=int(recycled),
-                    direct=int(dest is not None),
-                )
+                end_dest_span(dest_span, lease, dest is not None)
+            if dest is None and lease.buffer is not None:
+                # Not the destination: the consumer copies out of it.
+                dest = memoryview(lease.buffer.array)
             async with io_slots:
                 stats.io += 1
                 read_io = ReadIO(
@@ -1015,7 +1041,11 @@ async def execute_read_reqs(
                         first_err,
                         tier,
                     )
-            if read_io.dest is not None and buf is read_io.dest:
+            if (
+                read_io.dest is not None
+                and buf is read_io.dest
+                and lease.buffer is None
+            ):
                 # The plugin read straight into the destination; nothing
                 # left to deserialize or copy.
                 pass
@@ -1030,6 +1060,9 @@ async def execute_read_reqs(
                         await req.buffer_consumer.consume_buffer(buf, executor)
                 finally:
                     stats.staging -= 1
+            if destinations is not None:
+                # Not where the read failed: a thread may still write it.
+                destinations.release(lease)
             stats.done += 1
             stats.bytes_moved += buf.nbytes
             kind = (

@@ -34,11 +34,13 @@ Read side (resharding):
 
 from __future__ import annotations
 
+import threading
 from concurrent.futures import Executor
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .dest_pool import PlacedTogether
 from .io_types import BufferConsumer, BufferType, ReadReq, WriteReq
 from .manifest import ArrayEntry, Shard, ShardedArrayEntry
 from .resharding import (
@@ -71,21 +73,115 @@ def _shard_location(logical_path: str, box: Box) -> str:
     return f"sharded/{logical_path}_{suffix}"
 
 
+Slices = Tuple[slice, ...]
+
+
+def _view(arr: np.ndarray, slices: Slices) -> np.ndarray:
+    # A 0-d array indexed with () gives a scalar, not a view.
+    return arr[slices] if slices else arr
+
+
+def _contiguous_in(sizes: Sequence[int], slices: Slices) -> bool:
+    """Whether ``slices`` of a C-contiguous array of shape ``sizes`` is one
+    run of its bytes: full in every dimension after the first it cuts,
+    one wide in every dimension before that."""
+    full_so_far = True
+    for size, slc in zip(reversed(sizes), reversed(slices)):
+        width = slc.stop - slc.start
+        if not full_so_far and width != 1:
+            return False
+        if width != size:
+            full_so_far = False
+    return True
+
+
+class _LeafBoxes:
+    """One sharded leaf's destination boxes as host arrays, by box.
+
+    Made with the plan (``np.empty``, or the application's own array), or,
+    ``late``, when the leaf's first read comes: the read pipeline binds a
+    slab of ``dest_pool`` to each (``BufferConsumer.shared_destination``),
+    and a leaf nobody bound makes its own then. Late boxes are those of a
+    leaf bound for an accelerator, whose host bytes nobody sees after
+    placement (``snapshot._bound_for_accelerator``)."""
+
+    def __init__(
+        self,
+        np_dtype: Any,
+        boxes: Iterable[Box],
+        late: bool = False,
+        arrays: Optional[Dict[Box, np.ndarray]] = None,
+    ) -> None:
+        self.dtype = np.dtype(np_dtype)
+        self.sizes: Dict[Box, int] = {
+            box: box.numel() * self.dtype.itemsize for box in boxes
+        }
+        self.late = late and all(self.sizes.values())
+        # Every box is a slab a read landed in before (once bound).
+        self.recycled = False
+        self._arrays = arrays
+        self._on_placed: Dict[Box, Callable[[Any], None]] = {}
+        self._lock = threading.Lock()
+        if arrays is None and not self.late:
+            self._arrays = self._fresh()
+
+    def _fresh(self) -> Dict[Box, np.ndarray]:
+        return {box: np.empty(box.sizes, dtype=self.dtype) for box in self.sizes}
+
+    def nbytes(self) -> int:
+        return sum(self.sizes.values())
+
+    def unbound_sizes(self) -> List[int]:
+        return list(self.sizes.values()) if self._arrays is None else []
+
+    def bind(
+        self,
+        bufs: Sequence[np.ndarray],
+        on_placed: Sequence[Callable[[Any], None]],
+        recycled: bool,
+    ) -> None:
+        self._arrays = {
+            box: buf.view(self.dtype).reshape(box.sizes)
+            for box, buf in zip(self.sizes, bufs)
+        }
+        self._on_placed = dict(zip(self.sizes, on_placed))
+        self.recycled = recycled
+
+    def arrays(self) -> Dict[Box, np.ndarray]:
+        if self._arrays is None:
+            # Nobody bound them; consumers of one leaf run on several
+            # threads.
+            with self._lock:
+                if self._arrays is None:
+                    self._arrays = self._fresh()
+        return self._arrays
+
+    def placed(self, box: Box, values: Sequence[Any]) -> None:
+        """``values`` are on their devices from ``box``'s array: whoever
+        bound it has it back once all of them are ready."""
+        on_placed = self._on_placed.pop(box, None)
+        if on_placed is not None:
+            on_placed(values[0] if len(values) == 1 else PlacedTogether(values))
+
+
 class _OverlapConsumer(BufferConsumer):
     """Deserializes one saved shard (or a row range of it) and copies every
-    overlap region into its destination view (reference
+    overlap region into its destination box (reference
     ShardedTensorBufferConsumer, io_preparer.py:460-492)."""
 
     def __init__(
         self,
         dtype: str,
         buf_shape: Tuple[int, ...],
-        copies: List[Tuple[np.ndarray, Tuple[slice, ...]]],
+        boxes: _LeafBoxes,
+        copies: List[Tuple[Box, Slices, Slices]],
         dest_owned: bool = False,
     ) -> None:
         self.dtype = dtype
         self.buf_shape = buf_shape
-        self.copies = copies  # (dst_view, src_slices into the read buffer)
+        self.boxes = boxes
+        # (box, slices of the box's array, slices of the read buffer)
+        self.copies = copies
         self.dest_owned = dest_owned
 
     async def consume_buffer(
@@ -96,13 +192,18 @@ class _OverlapConsumer(BufferConsumer):
     def _consume_sync(self, buf: BufferType) -> None:
         with trace_annotation(metric_names.SPAN_LEAF_CONSUME):
             src = array_from_memoryview(buf, self.dtype, self.buf_shape)
+            arrays = self.boxes.arrays()
             with trace_annotation(
                 metric_names.SPAN_RESHARD_COPY,
                 bytes=self.destination_nbytes(),
                 buf_bytes=int(src.nbytes),
             ):
-                for dst_view, src_slices in self.copies:
-                    np.copyto(dst_view, src[src_slices], casting="no")
+                for box, dst_slices, src_slices in self.copies:
+                    np.copyto(
+                        _view(arrays[box], dst_slices),
+                        src[src_slices],
+                        casting="no",
+                    )
 
     def get_consuming_cost_bytes(self) -> int:
         return array_size_bytes(self.buf_shape, self.dtype)
@@ -114,26 +215,49 @@ class _OverlapConsumer(BufferConsumer):
         destination has a buffer larger than the bytes it delivers,
         and that gap is exactly what the doctor's
         ``restore-read-amplified`` rule exists to see."""
-        return sum(int(v.nbytes) for v, _ in self.copies)
+        return sum(
+            array_size_bytes([s.stop - s.start for s in dst_slices], self.dtype)
+            for _, dst_slices, _ in self.copies
+        )
 
-    def direct_destination(self) -> Optional[memoryview]:
-        # Direct read only when this is a straight whole-buffer copy into
-        # one framework-owned destination view (the no-resharding fast
-        # path); user-owned in-place arrays keep copy-on-success semantics.
-        if not self.dest_owned:
+    def _lands_in_its_box(self) -> Optional[Tuple[Box, Slices]]:
+        """Where this read is a straight whole-buffer copy into one run
+        of bytes of one framework-owned box (the no-resharding fast
+        path), that box and the slices of it; user-owned in-place arrays
+        keep copy-on-success semantics."""
+        if not self.dest_owned or len(self.copies) != 1:
             return None
-        if len(self.copies) != 1:
-            return None
-        dst_view, src_slices = self.copies[0]
-        if tuple(dst_view.shape) != self.buf_shape or src_slices != tuple(
+        box, dst_slices, src_slices = self.copies[0]
+        if tuple(
+            s.stop - s.start for s in dst_slices
+        ) != self.buf_shape or src_slices != tuple(
             slice(0, s) for s in self.buf_shape
         ):
             return None
+        if dtype_to_string(self.boxes.dtype) != self.dtype:
+            return None
+        if not _contiguous_in(box.sizes, dst_slices):
+            return None
+        return box, dst_slices
+
+    def direct_destination(self) -> Optional[memoryview]:
+        lands = self._lands_in_its_box()
+        if lands is None:
+            return None
         from .serialization import try_writable_byte_view
 
-        if dtype_to_string(dst_view.dtype) != self.dtype:
-            return None
-        return try_writable_byte_view(dst_view)
+        box, dst_slices = lands
+        return try_writable_byte_view(_view(self.boxes.arrays()[box], dst_slices))
+
+    def shared_destination(self) -> Optional[_LeafBoxes]:
+        return self.boxes if self.boxes.late else None
+
+    def read_buffer_bytes(self) -> int:
+        # Only beside pooled boxes: any other read leaves the allocation
+        # to the storage plugin, as ever.
+        if not self.boxes.late or self._lands_in_its_box() is not None:
+            return 0
+        return self.get_consuming_cost_bytes()
 
 
 class ShardedArrayIOPreparer:
@@ -236,12 +360,8 @@ class ShardedArrayIOPreparer:
 
     @staticmethod
     def _sharding_destination(
-        sharding: Any, shape: Tuple[int, ...], np_dtype: Any
-    ) -> Tuple[
-        Dict[Box, np.ndarray],
-        Callable[..., Any],
-        bool,
-    ]:
+        sharding: Any, shape: Tuple[int, ...], np_dtype: Any, late: bool = False
+    ) -> Tuple[_LeafBoxes, Callable[..., Any], bool]:
         """Destination boxes + assembler for an arbitrary target
         ``Sharding`` over ``shape`` — the elastic core: the sharding
         need not match the one the array was saved under, nor the saved
@@ -250,20 +370,19 @@ class ShardedArrayIOPreparer:
         import jax
 
         groups = target_boxes_for_sharding(sharding, shape)
-        boxes: Dict[Box, np.ndarray] = {
-            box: np.empty(box.sizes, dtype=np_dtype) for box in groups
-        }
+        boxes = _LeafBoxes(np_dtype, groups, late=late)
         device_to_box: Dict[Any, Box] = {
             device: box for box, devices in groups.items() for device in devices
         }
 
         def assemble(
-            filled: Dict[Box, np.ndarray], batch=None, on_done=None
+            boxes: _LeafBoxes, batch=None, on_done=None
         ) -> Any:
             # One batched H2D dispatch for all shards (a per-device
             # device_put loop pays per-call dispatch latency 8x over);
             # with a shared ``batch`` the shards ride the restore-wide
             # dispatch instead, and assembly defers until it runs.
+            filled = boxes.arrays()
             devices = list(device_to_box)
             per_device = [filled[device_to_box[d]] for d in devices]
             span = trace_annotation(
@@ -271,24 +390,28 @@ class ShardedArrayIOPreparer:
                 devices=len(devices),
                 bytes=sum(int(box.nbytes) for box in per_device),
             )
+
+            def placed(arrays: List[Any]) -> Any:
+                # A box's memory is its binder's again when every device
+                # that got it has it.
+                on_device = dict(zip(devices, arrays))
+                for box, its_devices in groups.items():
+                    boxes.placed(box, [on_device[d] for d in its_devices])
+                return jax.make_array_from_single_device_arrays(
+                    shape, sharding, arrays
+                )
+
             if batch is not None and on_done is not None:
                 slots = [batch.put(box, d) for box, d in zip(per_device, devices)]
 
                 def make() -> None:
                     with span:
-                        on_done(
-                            jax.make_array_from_single_device_arrays(
-                                shape, sharding, [s.value for s in slots]
-                            )
-                        )
+                        on_done(placed([s.value for s in slots]))
 
                 batch.defer(make)
                 return _DEFERRED
             with span:
-                arrays = jax.device_put(per_device, devices)
-                return jax.make_array_from_single_device_arrays(
-                    shape, sharding, arrays
-                )
+                return placed(jax.device_put(per_device, devices))
 
         return boxes, assemble, True
 
@@ -297,18 +420,17 @@ class ShardedArrayIOPreparer:
         entry: ShardedArrayEntry,
         current_leaf: Any,
         target_sharding: Optional[Any] = None,
-    ) -> Tuple[
-        Dict[Box, np.ndarray],
-        Optional[Callable[[Dict[Box, np.ndarray]], Any]],
-        bool,
-    ]:
+        late: bool = False,
+    ) -> Tuple[_LeafBoxes, Callable[..., Any], bool]:
         """Host buffers to read into, keyed by destination box, plus an
         assembler back to the application's leaf flavor, plus whether the
         buffers are framework-allocated (owned) — only owned buffers may be
         direct-read targets; a user's in-place array must keep
         copy-on-success semantics so a failed restore never tears it.
         An explicit ``target_sharding`` wins over the current leaf's
-        layout (restore-into-a-new-topology without a template leaf)."""
+        layout (restore-into-a-new-topology without a template leaf).
+        ``late``: the boxes of a committed ``jax.Array`` leaf may wait for
+        the leaf's first read (``_LeafBoxes``)."""
         from .serialization import string_to_dtype
 
         np_dtype = string_to_dtype(entry.dtype)
@@ -336,28 +458,26 @@ class ShardedArrayIOPreparer:
             # concrete device makes the restored state unusable in a jit
             # alongside differently-placed arrays. An uncommitted array is
             # single-device by construction, so it has exactly one box.
-            if not getattr(current_leaf, "_committed", True):
+            committed = getattr(current_leaf, "_committed", True)
+            if not committed:
                 groups = target_boxes_for_sharding(sharding, shape)
                 if len(groups) == 1:
-                    boxes = {
-                        box: np.empty(box.sizes, dtype=np_dtype)
-                        for box in groups
-                    }
 
                     def assemble_uncommitted(
-                        filled: Dict[Box, np.ndarray], batch=None, on_done=None
+                        boxes: _LeafBoxes, batch=None, on_done=None
                     ) -> Any:
                         import jax.numpy as jnp
 
-                        return jnp.asarray(next(iter(filled.values())))
+                        return jnp.asarray(next(iter(boxes.arrays().values())))
 
-                    return boxes, assemble_uncommitted, True
+                    return _LeafBoxes(np_dtype, groups), assemble_uncommitted, True
 
             return ShardedArrayIOPreparer._sharding_destination(
-                sharding, shape, np_dtype
+                sharding, shape, np_dtype, late=late and committed
             )
 
         # Host destination (np.ndarray in-place, or fresh allocation).
+        full_box = Box(tuple(0 for _ in shape), shape)
         if isinstance(current_leaf, np.ndarray):
             if tuple(current_leaf.shape) != shape or current_leaf.dtype != np_dtype:
                 raise ValueError(
@@ -365,15 +485,14 @@ class ShardedArrayIOPreparer:
                     f"{current_leaf.dtype}) does not match saved sharded "
                     f"array (shape {list(shape)}, dtype {entry.dtype})"
                 )
-            full = current_leaf
+            boxes = _LeafBoxes(np_dtype, [full_box], arrays={full_box: current_leaf})
             owned = False
         else:
-            full = np.empty(shape, dtype=np_dtype)
+            boxes = _LeafBoxes(np_dtype, [full_box])
             owned = True
-        full_box = Box(tuple(0 for _ in shape), shape)
         return (
-            {full_box: full},
-            (lambda filled, batch=None, on_done=None: filled[full_box]),
+            boxes,
+            (lambda boxes, batch=None, on_done=None: boxes.arrays()[full_box]),
             owned,
         )
 
@@ -386,6 +505,7 @@ class ShardedArrayIOPreparer:
         buffer_size_limit_bytes: Optional[int] = None,
         dest_owned: Optional[bool] = None,
         target_sharding: Optional[Any] = None,
+        late: bool = False,
     ) -> Tuple[List[ReadReq], Optional[Callable[[], None]]]:
         """Build resharding reads into ``restored[path]``; the returned
         finalize callback must run after the reads complete. ``dest_owned``
@@ -393,13 +513,16 @@ class ShardedArrayIOPreparer:
         allocated itself may declare it framework-owned to keep direct
         reads). ``target_sharding`` restores under an arbitrary jax
         ``Sharding`` — any layout, any world size — regardless of what
-        ``current_leaf`` is (the template-free elastic entry point)."""
+        ``current_leaf`` is (the template-free elastic entry point).
+        ``late``: the caller's read pipeline may bind the host boxes of a
+        committed ``jax.Array`` leaf when its first read comes
+        (``dest_pool``); they are then not made here."""
         with trace_annotation(
             metric_names.SPAN_RESHARD_PLAN, saved_shards=len(entry.shards)
         ) as span:
             boxes, assemble, derived_owned = (
                 ShardedArrayIOPreparer._destination_boxes(
-                    entry, current_leaf, target_sharding=target_sharding
+                    entry, current_leaf, target_sharding=target_sharding, late=late
                 )
             )
             if dest_owned is None:
@@ -408,23 +531,23 @@ class ShardedArrayIOPreparer:
 
             for saved in entry.shards:
                 saved_box = Box(tuple(saved.offsets), tuple(saved.sizes))
-                overlaps: List[Tuple[np.ndarray, Overlap]] = []
-                for dst_box, dst_buf in boxes.items():
+                overlaps: List[Tuple[Box, Overlap]] = []
+                for dst_box in boxes.sizes:
                     ov = box_overlap(saved_box, dst_box)
                     if ov is not None:
-                        overlaps.append((dst_buf[ov.dst_slices], ov))
+                        overlaps.append((dst_box, ov))
                 if not overlaps:
                     continue
                 read_reqs.extend(
                     ShardedArrayIOPreparer._reqs_for_saved_shard(
-                        saved, saved_box, overlaps, buffer_size_limit_bytes,
-                        dest_owned=dest_owned,
+                        saved, saved_box, boxes, overlaps,
+                        buffer_size_limit_bytes, dest_owned=dest_owned,
                     )
                 )
             span.annotate(
-                dest_boxes=len(boxes),
+                dest_boxes=len(boxes.sizes),
                 reads=len(read_reqs),
-                bytes_needed=sum(int(b.nbytes) for b in boxes.values()),
+                bytes_needed=boxes.nbytes(),
                 bytes_to_read=sum(
                     r.buffer_consumer.get_consuming_cost_bytes()
                     for r in read_reqs
@@ -445,7 +568,8 @@ class ShardedArrayIOPreparer:
     def _reqs_for_saved_shard(
         saved: Shard,
         saved_box: Box,
-        overlaps: List[Tuple[np.ndarray, Overlap]],
+        boxes: _LeafBoxes,
+        overlaps: List[Tuple[Box, Overlap]],
         buffer_size_limit_bytes: Optional[int] = None,
         dest_owned: bool = False,
     ) -> List[ReadReq]:
@@ -475,15 +599,24 @@ class ShardedArrayIOPreparer:
                 buffer_limit_bytes=buffer_size_limit_bytes,
             )
         if plan is not None:
-            views = [dst_view for dst_view, _ in overlaps]
+
+            def rows_of(overlap_index: int, rows: slice) -> Tuple[Box, Slices]:
+                # ``rows`` of the overlap's own rows, as rows of its box.
+                box, ov = overlaps[overlap_index]
+                row0 = ov.dst_slices[0].start
+                return box, (
+                    slice(row0 + rows.start, row0 + rows.stop),
+                ) + ov.dst_slices[1:]
+
             return [
                 ReadReq(
                     path=entry.location,
                     buffer_consumer=_OverlapConsumer(
                         entry.dtype,
                         read.buf_shape,
+                        boxes,
                         [
-                            (views[c.overlap_index][c.dst_rows], c.src_slices)
+                            rows_of(c.overlap_index, c.dst_rows) + (c.src_slices,)
                             for c in read.copies
                         ],
                         dest_owned=dest_owned,
@@ -493,12 +626,12 @@ class ShardedArrayIOPreparer:
                 for read in plan
             ]
 
-        copies = [(dst_view, ov.src_slices) for dst_view, ov in overlaps]
+        copies = [(box, ov.dst_slices, ov.src_slices) for box, ov in overlaps]
         return [
             ReadReq(
                 path=entry.location,
                 buffer_consumer=_OverlapConsumer(
-                    entry.dtype, shard_shape, copies, dest_owned=dest_owned
+                    entry.dtype, shard_shape, boxes, copies, dest_owned=dest_owned
                 ),
                 byte_range=entry.byte_range_tuple,
             )

@@ -1541,6 +1541,7 @@ class Snapshot:
                     restored,
                     path,
                     buffer_size_limit_bytes=memory_budget_bytes,
+                    late=_bound_for_accelerator(current_leaf),
                 )
                 read_reqs.extend(reqs)
                 if finalize is not None:
@@ -1833,16 +1834,20 @@ class _StreamingPlacer:
     def lease_destinations(
         self, read_reqs: List[Any], memory_budget_bytes: int
     ) -> None:
-        """Reads that came without a destination take slabs of the
-        process's pool, under a cap from what this pipeline shows. A
-        slab comes back through a placement, so without streaming (all
-        placements after all reads) nothing is leased: such reads make
-        their own destination, as one nobody binds always does."""
-        sizes = [r.buffer_consumer.unbound_destination_bytes() for r in read_reqs]
-        sizes = [n for n in sizes if n]
-        if sizes and self.flush_bytes > 0:
-            self.leases = DestinationLeases(
-                dest_pool.process_pool(), sizes, memory_budget_bytes, self.flush
+        """Reads that came without a destination (a dense leaf's, or a
+        sharded leaf's boxes and the buffers its reads are copied out of)
+        take slabs of the process's pool, under a cap from what this
+        pipeline shows. A destination's slab comes back through a
+        placement, so without streaming (all placements after all reads)
+        nothing is leased: such reads make their own destination, as one
+        nobody binds always does."""
+        if self.flush_bytes > 0:
+            self.leases = DestinationLeases.for_reads(
+                dest_pool.process_pool(),
+                [r.buffer_consumer for r in read_reqs],
+                memory_budget_bytes,
+                self.flush,
+                knobs.get_per_rank_io_concurrency(),
             )
 
     def report_destinations(self, pipeline_telemetry: dict) -> None:
@@ -2678,9 +2683,8 @@ def _restore_destination(
         dst = None
         if not (
             late
-            and committed
             and isinstance(entry, ArrayEntry)
-            and _placement_copies(sharding)
+            and _bound_for_accelerator(current_leaf)
         ):
             dst = ArrayIOPreparer.empty_array_from_entry(entry)
 
@@ -2713,6 +2717,19 @@ def _settle_destinations() -> None:
             telemetry.names.SPAN_RESTORE_PLACE, arrays=waiting, bytes=0
         ):
             pool.settle()
+
+
+def _bound_for_accelerator(leaf: Any) -> bool:
+    """Whether nobody sees the host bytes a restore reads for ``leaf``
+    once they are placed, so that they may land in recycled memory of
+    ``dest_pool``: a committed ``jax.Array`` whose placement copies. A
+    host ``np.ndarray`` and an uncommitted leaf (``jnp.asarray``) hand
+    their buffer on as it is."""
+    return (
+        is_jax_array(leaf)
+        and getattr(leaf, "_committed", True)
+        and _placement_copies(leaf.sharding)
+    )
 
 
 def _placement_copies(sharding: Any) -> bool:

@@ -290,9 +290,17 @@ def _assemble(
     wall_s = wall_us / 1e6
     attributed = sum(segments.values())
     chain: List[Dict[str, Any]] = []
+    rows = sorted(evidence.items(), key=lambda kv: -kv[1]["gated_s"])
+    # Every segment's heaviest row is cited before any segment's second:
+    # a short op's peer pull or wire RPC gates tens of microseconds, and
+    # whether that is its eighth- or ninth-heaviest span is chance.
+    heads: Dict[str, Tuple[str, str]] = {}
+    for key, _ in rows:
+        heads.setdefault(key[0], key)
+    rows.sort(key=lambda kv: heads[kv[0][0]] != kv[0])  # stable
     for (seg, name), slot in sorted(
-        evidence.items(), key=lambda kv: -kv[1]["gated_s"]
-    )[:EVIDENCE_TOP_N]:
+        rows[:EVIDENCE_TOP_N], key=lambda kv: -kv[1]["gated_s"]
+    ):
         entry: Dict[str, Any] = {
             "span": name,
             "segment": seg,

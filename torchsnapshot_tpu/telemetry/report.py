@@ -137,8 +137,9 @@ class SnapshotReport:
     bytes_received: Optional[int] = None
     bytes_needed: Optional[int] = None
     # Restores whose reads took slabs of the destination pool only
-    # (None elsewhere): the bytes read into a slab that had been read
-    # into before, and into one made for this restore (docs/restore.md).
+    # (None elsewhere): the bytes of the slabs taken (destinations,
+    # a sharded leaf's boxes, read buffers) that had been read into
+    # before, and of those made for this restore (docs/restore.md).
     dest_bytes_recycled: Optional[int] = None
     dest_bytes_fresh: Optional[int] = None
     # Peer-tier restores only (None/empty elsewhere): bytes served per

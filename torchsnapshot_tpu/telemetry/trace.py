@@ -6,7 +6,7 @@ BENCH stall (`in_take_stall: true`, 120 s vs 71 s steady state) poses
 and phase sums cannot answer. Design:
 
 - **Always-on bounded ring.** Every span/instant lands in a process-wide
-  ring buffer (capacity knob, default 16384 completed events; oldest
+  ring buffer (capacity knob, default 65536 completed events; oldest
   evict first, evictions counted). Recording is a lock plus a few dict
   ops — the same cost class as a registry observation — so it is never
   gated; only *persistence* is knob-controlled, mirroring the registry's
@@ -344,8 +344,16 @@ class SpanRecorder:
         the mark but finished after it is included — overlap with the
         previous operation is signal, not noise), completion order."""
         seq = mark.seq if isinstance(mark, TraceMark) else mark
+        newer: List[Dict[str, Any]] = []
         with self._lock:
-            return [dict(e) for e in self._events if e["seq"] > seq]
+            # Newest first, up to the mark: the ring is in ``seq`` order,
+            # and an operation's window is a small part of it.
+            for e in reversed(self._events):
+                if e["seq"] <= seq:
+                    break
+                newer.append(dict(e))
+        newer.reverse()
+        return newer
 
     def tid_names(self) -> Dict[int, str]:
         with self._lock:
