@@ -120,3 +120,33 @@ if os.environ.get("TS_TEST_ON_TPU") != "1":
     import jax
 
     jax.config.update("jax_platforms", "cpu")
+
+import pytest  # noqa: E402 - after the environment is pinned
+
+
+@pytest.fixture
+def accelerator_path(monkeypatch):
+    """Take the path of an accelerator on the CPU backend: say that
+    placements copy, and make them copy (``device_put`` of an aligned
+    numpy array may alias it here, which is why this backend never pools).
+    Yields the process's pool, emptied before and after."""
+    import jax
+    import numpy as np
+
+    from torchsnapshot_tpu import dest_pool, snapshot as snapshot_mod
+
+    real_put = jax.device_put
+
+    def copying_put(x, *args, **kwargs):
+        copied = jax.tree_util.tree_map(
+            lambda v: np.array(v) if isinstance(v, np.ndarray) else v, x
+        )
+        return real_put(copied, *args, **kwargs)
+
+    monkeypatch.setattr(snapshot_mod, "_placement_copies", lambda s: True)
+    monkeypatch.setattr(jax, "device_put", copying_put)
+    pool = dest_pool.process_pool()
+    pool.clear()
+    yield pool
+    pool.settle()
+    pool.clear()

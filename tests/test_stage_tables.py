@@ -589,6 +589,85 @@ def test_async_restore_plans_inside_its_op(tmp_path):
     assert cp["stages"][names.SPAN_RESTORE_PLAN]["count"] == len(plans)
 
 
+# What one read pipeline leaves behind whichever driver ran it: the spans of
+# its stages in the op's table, and these keys in the report.
+PIPELINE_SPANS = {
+    names.SPAN_RESTORE_PLAN, names.SPAN_STORAGE_READ, names.SPAN_VERIFY_BLOB,
+    names.SPAN_RESTORE_DEST_ACQUIRE, names.SPAN_RESTORE_PLACE,
+    names.SPAN_RESTORE_APPLY,
+}
+PIPELINE_KEYS = ("bytes_needed", "dest_bytes_recycled", "dest_bytes_fresh", "cold_start_s")
+
+
+@pytest.mark.parametrize("driver", ["restore", "async_restore"])
+def test_both_restore_drivers_run_the_one_read_pipeline(tmp_path, accelerator_path, driver):
+    app = _app_state()
+    path = str(tmp_path / "snap")
+    ts.Snapshot.take(path, app)
+    rec = trace.get_recorder()
+    with knobs.enable_telemetry():
+        mark = rec.mark()
+        if driver == "restore":
+            ts.Snapshot(path).restore(app)
+        else:
+            ts.Snapshot(path).async_restore(app).wait()
+        report = telemetry.last_report(driver, path=path)
+    events = [e for e in rec.events_since(mark) if e["ph"] == "X"]
+    ((op, table),) = _ops(events, driver).items()
+    assert PIPELINE_SPANS <= set(table["stages"]), PIPELINE_SPANS - set(table["stages"])
+    # Set-up reads the manifest and the checksum table under a span of its
+    # own, before any stateful's plan.
+    plans = [e for e in events if e["op"] == op and e["name"] == names.SPAN_RESTORE_PLAN]
+    assert "stateful" not in plans[0]["args"]
+    assert {e["args"]["stateful"] for e in plans[1:]} == {"model", "progress"}
+    applied = [e["args"]["stateful"] for e in events
+               if e["op"] == op and e["name"] == names.SPAN_RESTORE_APPLY]
+    assert applied == ["model", "progress"]
+    carried = report.to_dict()
+    assert [k for k in PIPELINE_KEYS if carried.get(k) is None] == []
+    assert carried["bytes_needed"] >= LEAVES * LEAF_BYTES
+    assert (carried["dest_bytes_recycled"] + carried["dest_bytes_fresh"]
+            == LEAVES * LEAF_BYTES)
+    assert set(carried["cold_start"]) == {"event_loop_s", "plugin_open_s", "native_load_s"}
+    assert accelerator_path.unsettled() == 0  # the restore waited for its placements
+
+
+@pytest.mark.parametrize("driver", ["take", "async_take"])
+def test_both_take_drivers_run_the_one_commit_sequence(tmp_path, driver):
+    app = _app_state()
+    path = str(tmp_path / "snap")
+    rec = trace.get_recorder()
+    with knobs.enable_telemetry():
+        mark = rec.mark()
+        if driver == "take":
+            op = ts.Snapshot.take(path, app).trace_op
+        else:
+            pending = ts.Snapshot.async_take(path, app)
+            pending.wait()
+            op = pending.trace_op
+        assert telemetry.last_report(driver, path=path) is not None
+    mine = sorted((e for e in rec.events_since(mark) if e["ph"] == "X" and e["op"] == op),
+                  key=lambda e: e["bseq"])
+    (finalize,) = [e for e in mine if e["name"] == names.SPAN_COMMIT_FINALIZE]
+    envelope = [e for e in mine if e["name"] in (TAKE, COMMIT)][-1]
+    # The writes drain, then the finalize window opens inside the envelope:
+    # the checksum table and the marker are its two writes, nothing else is.
+    drained = [e for e in mine if e["name"] == names.SPAN_PIPELINE_WRITE_DRAIN]
+    assert drained and all(e["ts"] + e["dur"] <= finalize["ts"] for e in drained)
+    assert finalize["parent"] == envelope["bseq"]
+    commit = [e["name"] for e in mine if e["bseq"] >= finalize["bseq"]
+              and e["name"] != names.SPAN_FS_NATIVE_WRITE]
+    assert commit == [names.SPAN_COMMIT_FINALIZE, names.SPAN_STORAGE_WRITE,
+                      names.SPAN_STORAGE_WRITE, names.SPAN_TELEMETRY_REPORT]
+    writes = [e for e in mine if e["parent"] == finalize["bseq"]]
+    assert [e["name"] for e in writes] == [names.SPAN_STORAGE_WRITE] * 2
+    # The envelope closes before the report is emitted, so the report's
+    # window holds the take's full extent.
+    (reported,) = [e for e in mine if e["name"] == names.SPAN_TELEMETRY_REPORT]
+    assert envelope["ts"] + envelope["dur"] <= reported["ts"]
+    assert os.path.exists(os.path.join(path, ".snapshot_metadata"))
+
+
 def test_a_mirror_job_is_an_operation_of_its_own(tmp_path):
     from torchsnapshot_tpu.tiered import reset_mirror, wait_durable
 

@@ -188,31 +188,6 @@ def _slabs(pool):
     return [s.array for slabs in pool._free.values() for s in slabs]
 
 
-@pytest.fixture
-def accelerator_path(monkeypatch):
-    """Take the path of an accelerator on the CPU backend: say that
-    placements copy, and make them copy (``device_put`` of an aligned
-    numpy array may alias it here, which is why this backend never pools).
-    Yields the process's pool, emptied before and after."""
-    from torchsnapshot_tpu import dest_pool
-
-    real_put = jax.device_put
-
-    def copying_put(x, *args, **kwargs):
-        copied = jax.tree_util.tree_map(
-            lambda v: np.array(v) if isinstance(v, np.ndarray) else v, x
-        )
-        return real_put(copied, *args, **kwargs)
-
-    monkeypatch.setattr(snapshot_mod, "_placement_copies", lambda s: True)
-    monkeypatch.setattr(jax, "device_put", copying_put)
-    pool = dest_pool.process_pool()
-    pool.clear()
-    yield pool
-    pool.settle()
-    pool.clear()
-
-
 def test_cpu_backend_restores_into_fresh_memory(tmp_path):
     """Two restores of different checkpoints in one process on the CPU
     backend leave the first restore's arrays bit-identical: here a placed

@@ -451,120 +451,29 @@ class Snapshot:
         written — the manifest references the base's blob instead
         (incremental.py). ``record_digests`` records digests without a
         base, making this snapshot usable as a future base."""
-        import uuid
-
-        from .cas import cas_eligible
-
-        pg_wrapper = PGWrapper(pg)
-        # Rank-0 path wins; the CAS layout decision rides the same
-        # broadcast (one agreement, no extra collective) so ranks can
-        # never diverge on where data bytes land.
-        path, cas_on = pg_wrapper.broadcast_object(
-            (path, cas_eligible(path))
-        )
-        # Error-propagating commit barrier, same design as async_take's:
-        # a rank whose writes fail must not strand its peers for the full
-        # store timeout — they observe the reported error at arrive() and
-        # abandon (no commit marker anywhere). The nonce keeps barrier
-        # keys from aliasing any earlier take to the same path.
-        barrier = None
-        commit_nonce = ""
-        if pg_wrapper.get_world_size() > 1:
-            commit_nonce = pg_wrapper.broadcast_object(uuid.uuid4().hex)
-            barrier = _nonce_barrier(
-                f"__snapshot_commit/{commit_nonce}", pg_wrapper
-            )
-        event_loop = asyncio.new_event_loop()
-        counter_baseline = telemetry.metrics().counters_snapshot()
-        tunables_at_start = knobs.tunable_snapshot()
-        trace_mark = _trace_recorder().mark()
+        op = _TakeOp("take", path, pg)
         take_span = _tracing.begin(
-            telemetry.names.SPAN_TAKE, path=path, rank=pg_wrapper.get_rank()
+            telemetry.names.SPAN_TAKE, path=op.path, rank=op.rank
         )
-        trace_op = _current_op()
-        # Live-progress heartbeat for the whole op: external pollers see
-        # a stuck rank from outside the process (telemetry/progress.py).
-        tracker = _progress.track("take", path, pg_wrapper.get_rank())
         op_error: Optional[BaseException] = None
         try:
-            storage = _maybe_cas_storage(
-                url_to_storage_plugin(path), path, cas_on
+            op.stage(
+                app_state,
+                replicated or [],
+                incremental_base,
+                record_digests,
+                _custom_array_prepare_func,
             )
-            with _reporting_to(barrier, "take"):
-                pending_io_work, metadata = cls._take_impl(
-                    path=path,
-                    app_state=app_state,
-                    pg_wrapper=pg_wrapper,
-                    replicated=replicated or [],
-                    storage=storage,
-                    event_loop=event_loop,
-                    is_async_snapshot=False,
-                    incremental_base=incremental_base,
-                    record_digests=record_digests,
-                    _custom_array_prepare_func=_custom_array_prepare_func,
-                    progress_tracker=tracker,
-                )
-                pending_io_work.sync_complete(event_loop)
-                _crashpoint(telemetry.names.CRASH_TAKE_WRITES_DONE)
-
-            # All writes are durable on every rank before the commit marker
-            # exists anywhere (commit-after-barrier invariant). The commit
-            # window itself stays under _reporting_to: if rank 0's metadata
-            # write fails between arrive() and depart(), peers polling at
-            # depart() observe the reported error and abandon in seconds
-            # instead of blocking out the store timeout (the async path's
-            # catch-all in PendingSnapshot._complete_snapshot already
-            # covers its equivalent window).
-            with trace_annotation(
-                telemetry.names.SPAN_COMMIT_FINALIZE, rank=pg_wrapper.get_rank()
-            ):
-                with _reporting_to(barrier, "take"):
-                    _write_checksum_and_cas_tables(
-                        pending_io_work, pg_wrapper.get_rank(), storage,
-                        event_loop,
-                    )
-                with _reporting_to(barrier, "commit"):
-                    if barrier is not None:
-                        barrier.arrive()
-                    if pg_wrapper.get_rank() == 0:
-                        cls._write_snapshot_metadata(
-                            metadata, storage, event_loop
-                        )
-                    if barrier is not None:
-                        barrier.depart()
-            # Post-commit: hand this rank's blobs to the peer tier (the
-            # committed step is what a replacement rank would restore).
-            _maybe_push_to_peer(path, pending_io_work)
-            event_loop.run_until_complete(storage.close())
-            # The envelope span closes before the report/trace emission
-            # so the exported timeline carries the take's full extent.
-            _tracing.end(take_span)
-            # Post-close on purpose: a tiered plugin enqueues its mirror
-            # job at close, so the report's mirror state reflects the
-            # durability backlog this take just created.
-            _emit_snapshot_report(
-                kind="take",
-                path=path,
-                pg_wrapper=pg_wrapper,
-                pipeline=pending_io_work.pipeline_telemetry(),
-                counter_baseline=counter_baseline,
-                nonce=commit_nonce,
-                trace_mark=trace_mark,
-                tunables=tunables_at_start,
-                trace_op=trace_op,
-            )
+            op.commit(take_span)
         except BaseException as e:
             op_error = e
             raise
         finally:
-            # Success removes the heartbeat file; failure leaves a
-            # terminal document (doctor evidence the op *ended*).
-            tracker.finish(op_error)
             _tracing.end(take_span)  # no-op if already closed
-            event_loop.close()
-        snapshot = cls(path=path, pg=pg)
-        snapshot._metadata = metadata
-        snapshot.trace_op = trace_op
+            op.settle(op_error)
+        snapshot = cls(path=op.path, pg=pg)
+        snapshot._metadata = op.metadata
+        snapshot.trace_op = op.trace_op
         return snapshot
 
     @classmethod
@@ -595,87 +504,33 @@ class Snapshot:
         pre-deferral behavior (staging completes before this returns —
         reference snapshot.py:245-314 — costing no transient HBM copy).
         ``incremental_base``/``record_digests`` as in :meth:`take`."""
-        import uuid
-
-        from .cas import cas_eligible
-
         op_begin = time.monotonic()
-        pg_wrapper = PGWrapper(pg)
-        # Same combined broadcast as the sync take: rank-0 path wins and
-        # the CAS layout decision is agreed before any write exists.
-        path, cas_on = pg_wrapper.broadcast_object(
-            (path, cas_eligible(path))
-        )
-        # Unique per-take commit nonce: barrier keys from any earlier take
-        # to the same path (including failed ones) must never alias this
-        # take's barrier.
-        commit_nonce = pg_wrapper.broadcast_object(uuid.uuid4().hex)
-        # Error-reporting handle on the SAME commit barrier the background
-        # commit threads key off this nonce: staging (_take_impl) includes
-        # rank-0-only work such as replication verification, and a rank
-        # that fails there must poison the barrier before raising — peers
-        # whose staging succeeded already have commit threads waiting at
-        # arrive(), and without the report they block out the full store
-        # timeout.
-        barrier = _nonce_barrier(
-            f"__snapshot_commit/{commit_nonce}", pg_wrapper
-        )
-        event_loop = asyncio.new_event_loop()
-        counter_baseline = telemetry.metrics().counters_snapshot()
-        tunables_at_start = knobs.tunable_snapshot()
-        trace_mark = _trace_recorder().mark()
-        storage = _maybe_cas_storage(
-            url_to_storage_plugin(path), path, cas_on
-        )
-        tracker = _progress.track("async_take", path, pg_wrapper.get_rank())
-        defer_staging = knobs.is_async_device_snapshot_enabled()
+        op = _TakeOp("async_take", path, pg)
         try:
+            # The commit envelope, on its own thread, joins this op.
             with _tracing.op_annotation(
                 telemetry.names.SPAN_ASYNC_TAKE_STAGE,
-                path=path,
-                rank=pg_wrapper.get_rank(),
-            ), _reporting_to(barrier, "async take staging"):
-                # The commit envelope, on its own thread, joins this op.
-                trace_op = _current_op()
-                pending_io_work, metadata = cls._take_impl(
-                    path=path,
-                    app_state=app_state,
-                    pg_wrapper=pg_wrapper,
-                    replicated=replicated or [],
-                    storage=storage,
-                    event_loop=event_loop,
-                    is_async_snapshot=True,
-                    incremental_base=incremental_base,
-                    record_digests=record_digests,
-                    _custom_array_prepare_func=_custom_array_prepare_func,
-                    progress_tracker=tracker,
-                    defer_staging=defer_staging,
+                path=op.path,
+                rank=op.rank,
+            ):
+                op.stage(
+                    app_state,
+                    replicated or [],
+                    incremental_base,
+                    record_digests,
+                    _custom_array_prepare_func,
+                    defer_staging=knobs.is_async_device_snapshot_enabled(),
                 )
         except BaseException as e:
             # The failure path owns the loop/storage (no PendingSnapshot
             # thread will ever run to close them).
-            tracker.finish(e)
             try:
-                event_loop.run_until_complete(storage.close())
+                op.event_loop.run_until_complete(op.storage.close())
             except Exception:  # noqa: BLE001 - already failing
                 pass
-            event_loop.close()
+            op.settle(e)
             raise
-        return PendingSnapshot(
-            path=path,
-            pending_io_work=pending_io_work,
-            pg_wrapper=pg_wrapper,
-            metadata=metadata,
-            storage=storage,
-            event_loop=event_loop,
-            commit_nonce=commit_nonce,
-            counter_baseline=counter_baseline,
-            trace_mark=trace_mark,
-            progress_tracker=tracker,
-            op_begin=op_begin,
-            tunables=tunables_at_start,
-            trace_op=trace_op,
-        )
+        return PendingSnapshot(op, op_begin)
 
     @classmethod
     def _plan_take(
@@ -997,115 +852,41 @@ class Snapshot:
     # ------------------------------------------------------------------
 
     def restore(self, app_state: AppState) -> None:
-        """In-place restore (reference snapshot.py:442-491)."""
-        import uuid
-
+        """In-place restore (reference snapshot.py:442-491): a key at a
+        time — plan, exchange, read, apply, barrier — on the caller's
+        thread, so that only one stateful's new arrays stand beside its
+        old ones and each stateful is a read pipeline of its own. The
+        steps are :class:`_RestoreOp`'s, shared with
+        :meth:`async_restore`."""
         _validate_app_state(app_state)
         pg_wrapper = PGWrapper(self._pg_arg)
-        rank = pg_wrapper.get_rank()
-        # Error-propagating inter-stateful barriers (same design as the
-        # take commit barrier): a rank whose reads fail — bit rot, a
-        # CRC mismatch — reports before raising, so peers waiting at the
-        # current key's barrier abandon instead of blocking out the full
-        # store timeout.
-        restore_nonce = None
-        fanout_agreed = False
-        if pg_wrapper.get_world_size() > 1:
-            # The fan-out enablement rides the nonce broadcast: ONE
-            # agreement collective, before any failure point, so rank
-            # 0's knob reading decides for the whole job (env skew can
-            # never diverge the schedule) and a later setup failure can
-            # never leave the shared op-seq counter half-advanced.
-            restore_nonce, fanout_agreed = pg_wrapper.broadcast_object(
-                (uuid.uuid4().hex, knobs.is_fanout_restore_enabled())
-            )
-        counter_baseline = telemetry.metrics().counters_snapshot()
-        tunables_at_start = knobs.tunable_snapshot()
-        trace_mark = _trace_recorder().mark()
-        restore_span = _tracing.begin(
-            telemetry.names.SPAN_RESTORE, path=self.path, rank=rank
+        op = _RestoreOp(
+            self, "restore", pg_wrapper, _agree_restore(pg_wrapper)
         )
-        self.trace_op = _current_op()
-        tracker = _progress.track("restore", self.path, rank)
+        restore_span = _tracing.begin(
+            telemetry.names.SPAN_RESTORE, path=self.path, rank=op.rank
+        )
+        self.trace_op = op.trace_op = _current_op()
         op_error: Optional[BaseException] = None
-        pipeline_sink: List[dict] = []
-
-        def key_barrier(i: int) -> Optional[StoreBarrier]:
-            if restore_nonce is None:
-                return None
-            return _nonce_barrier(
-                f"__restore/{restore_nonce}/{i}", pg_wrapper
-            )
-
-        # Cold-start attribution: the envelope work before the first
-        # storage byte can move — event-loop spin-up, plugin open, and
-        # the native digest library's first load — timed separately so
-        # a first-trial restore that dwarfs warm trials convicts its
-        # cause in the report (``cold_start``/``cold_start_s``) instead
-        # of leaving the gap a guess.
-        cold_start: Dict[str, float] = {}
-        _cold_t = time.monotonic()
-        event_loop = asyncio.new_event_loop()
-        cold_start["event_loop_s"] = time.monotonic() - _cold_t
         try:
-            _cold_t = time.monotonic()
-            storage = url_to_storage_plugin(self.path)
-            cold_start["plugin_open_s"] = time.monotonic() - _cold_t
-            _cold_t = time.monotonic()
-            from .integrity import _alg_available
-
-            _alg_available("crc32c")  # first call loads the native lib
-            cold_start["native_load_s"] = time.monotonic() - _cold_t
-            # Peer-tier ladder (docs/peer.md): when surviving peers hold
-            # this step's shards in RAM, reads resolve peer -> fast ->
-            # durable per blob, digest-verified. Build is rank-local
-            # (inventory RPCs, no collectives), so peers building or
-            # not building the ladder independently can never diverge
-            # the restore schedule; every failure degrades to None.
-            from .tiered import peer as _peer_tier
-
-            peer_ctx = _peer_tier.build_restore_context(self.path)
-            if peer_ctx is not None:
-                storage = peer_ctx.wrap(storage)
             # Collectives FIRST, storage reads second (round 5; same
             # principle as _take_impl's budget-before-gather order): the
             # metadata and checksum-table reads are the restore's
             # pre-coordination failure points, and a rank failing there
             # must not leave peers inside an op-seq collective poll —
-            # where a reported error is invisible. After the reorder,
-            # only local work sits between a rank's setup reads and the
-            # first error-aware key barrier, so setup failures reported
-            # into key barrier 0 abandon peers in seconds.
+            # where a reported error is invisible. Only local work sits
+            # between a rank's set-up reads and the first error-aware key
+            # barrier, so set-up failures reported into key barrier 0
+            # abandon peers in seconds.
             rng_key_and_state = _pop_rng_state(app_state)
             rng_key = rng_key_and_state[0] if rng_key_and_state else None
             keys = _gather_keys(app_state, pg_wrapper)
             memory_budget_bytes = get_process_memory_budget_bytes(pg_wrapper)
-            setup_barrier = key_barrier(0) if keys else None
-            fanout_ctx = None
-            with _reporting_to(setup_barrier, "restore setup"):
-                with trace_annotation(telemetry.names.SPAN_RESTORE_PLAN):
-                    available = get_manifest_for_rank(self.metadata, rank)
-                    checksum_table = self._get_checksum_table(
-                        storage, event_loop
-                    )
-                # Single-reader fan-out (docs/restore.md): enablement was
-                # broadcast-agreed above; the owner table is derived
-                # deterministically from the committed manifest (same
-                # bytes on every rank), inside the error-aware setup
-                # window like every other failure-prone setup read.
-                if fanout_agreed:
-                    from .fanout import FanoutRestoreContext
-
-                    fanout_ctx = FanoutRestoreContext.build(
-                        self.metadata.manifest, pg_wrapper
-                    )
-                    if not fanout_ctx.owners:
-                        fanout_ctx = None  # nothing shard-shaped to fan out
+            op.set_up(op.barrier(0) if keys else None, memory_budget_bytes)
             for i, key in enumerate(keys):
-                stateful = app_state.get(key)
-                if key == rng_key:
-                    stateful = None  # restored last, below
-                barrier = key_barrier(i)
+                # The RNG stateful keeps its slot and is restored last.
+                stateful = None if key == rng_key else app_state.get(key)
+                barrier = op.barrier(i)
                 with _reporting_to(barrier, "restore"):
                     # Plan first so the fan-out exchange (a round every
                     # rank runs in the same order, plan or no plan)
@@ -1114,88 +895,35 @@ class Snapshot:
                     # key, so a peer failing anywhere in this block
                     # aborts the round in seconds (_reporting_to writes
                     # that key on the way out).
-                    plan = None
-                    if stateful is not None:
-                        plan = self._plan_stateful_load(
-                            key, stateful, available, memory_budget_bytes
-                        )
-                    round_locs: List[str] = []
-                    if fanout_ctx is not None:
-                        round_locs = fanout_ctx.exchange(
-                            plan.read_reqs if plan is not None else [],
-                            storage,
-                            event_loop,
-                            rendezvous_prefix=(
-                                f"__restore/{restore_nonce}/{i}"
-                            ),
-                        )
+                    plans = self._plan_key(key, stateful, op)
+                    round_locs = op.exchange(plans, i)
                     try:
-                        if plan is not None:
-                            self._execute_load_plan(
-                                plan,
-                                storage=storage,
-                                memory_budget_bytes=memory_budget_bytes,
-                                event_loop=event_loop,
-                                rank=rank,
-                                checksum_table=checksum_table,
-                                pipeline_sink=pipeline_sink,
-                                progress_tracker=tracker,
-                                fanout_ctx=fanout_ctx,
-                            )
+                        if plans:
+                            op.read_plans(plans, apply=True)
                     finally:
-                        if fanout_ctx is not None:
-                            fanout_ctx.drop(round_locs)
+                        if op.fanout_ctx is not None:
+                            op.fanout_ctx.drop(round_locs)
                 if barrier is not None:
                     barrier.arrive()
                     barrier.depart()
             # RNG state is restored last so that load_state_dict side
             # effects of other statefuls cannot disturb it (reference
-            # snapshot.py:478-489).
+            # snapshot.py:478-489): rank-locally, outside the shared
+            # barrier schedule, in no exchange.
             if rng_key_and_state is not None:
-                key, stateful = rng_key_and_state
-                self._load_stateful(
-                    key=key,
-                    stateful=stateful,
-                    available=available,
-                    storage=storage,
-                    memory_budget_bytes=memory_budget_bytes,
-                    event_loop=event_loop,
-                    rank=rank,
-                    checksum_table=checksum_table,
-                    pipeline_sink=pipeline_sink,
-                    progress_tracker=tracker,
-                )
+                plans = self._plan_key(*rng_key_and_state, op)
+                if plans:
+                    op.read_plans(plans, apply=True)
             _settle_destinations()
-            event_loop.run_until_complete(storage.close())
+            op.close_storage()
             _tracing.end(restore_span)
-            pipeline = telemetry.merge_pipeline_telemetry(pipeline_sink)
-            _merge_fanout_telemetry(pipeline, fanout_ctx)
-            _merge_peer_telemetry(pipeline, peer_ctx)
-            # Round the parts BEFORE summing: the report layer rounds
-            # each part to 6dp on serialization, so deriving the total
-            # from the raw values can disagree with the serialized
-            # parts by 1e-06 for unlucky timings.
-            cold_start = {k: round(v, 6) for k, v in cold_start.items()}
-            pipeline["cold_start"] = cold_start
-            pipeline["cold_start_s"] = round(sum(cold_start.values()), 6)
-            _emit_snapshot_report(
-                kind="restore",
-                path=self.path,
-                pg_wrapper=pg_wrapper,
-                pipeline=pipeline,
-                counter_baseline=counter_baseline,
-                nonce=restore_nonce,
-                trace_mark=trace_mark,
-                tunables=tunables_at_start,
-                trace_op=self.trace_op,
-            )
+            op.report(nonce=op.nonce)
         except BaseException as e:
             op_error = e
             raise
         finally:
-            tracker.finish(op_error)
             _tracing.end(restore_span)  # no-op if already closed
-            event_loop.close()
+            op.settle(op_error)
 
     def async_restore(self, app_state: AppState) -> "PendingRestore":
         """Pipelined restore: storage reads (and H2D placement) run on a
@@ -1217,81 +945,55 @@ class Snapshot:
         ``wait()`` re-raises background failures before applying anything,
         leaving app state unmodified on error). In-place numpy leaves are
         the exception: they are read into directly and must not be used
-        until ``wait()`` returns."""
+        until ``wait()`` returns.
+
+        The steps are :class:`_RestoreOp`'s, shared with :meth:`restore`;
+        this driver plans every key, exchanges once and reads all plans
+        in one pipeline, so every stateful's new arrays are held until
+        ``wait()``."""
         _validate_app_state(app_state)
         pg_wrapper = PGWrapper(self._pg_arg)
-        rank = pg_wrapper.get_rank()
-        trace_mark = _trace_recorder().mark()
-        # The op's first envelope: capture, planning and the exchange run
-        # on the calling thread; the read thread's envelope joins it.
-        with _tracing.op_annotation(
-            telemetry.names.SPAN_ASYNC_RESTORE_PLAN, path=self.path, rank=rank
-        ):
-            return self._start_async_restore(
-                app_state, pg_wrapper, rank, trace_mark, _current_op()
-            )
+        op = _RestoreOp(self, "async_restore", pg_wrapper)
+        try:
+            # The op's first envelope: capture, planning and the exchange
+            # run on the calling thread; the read thread's envelope joins
+            # it, and wait() applies and reports under it.
+            with _tracing.op_annotation(
+                telemetry.names.SPAN_ASYNC_RESTORE_PLAN,
+                path=self.path,
+                rank=op.rank,
+            ):
+                op.trace_op = _current_op()
+                return self._start_async_restore(app_state, op)
+        except BaseException as e:
+            op.settle(e)  # no read thread will ever run to do it
+            raise
 
     def _start_async_restore(
-        self,
-        app_state: AppState,
-        pg_wrapper: PGWrapper,
-        rank: int,
-        trace_mark: TraceMark,
-        trace_op: int,
+        self, app_state: AppState, op: "_RestoreOp"
     ) -> "PendingRestore":
-        memory_budget_bytes = get_process_memory_budget_bytes(pg_wrapper)
-
+        memory_budget_bytes = get_process_memory_budget_bytes(op.pg)
         rng_key_and_state = _pop_rng_state(app_state)
-        rng_key = rng_key_and_state[0] if rng_key_and_state else None
         # The key list (and hence the barrier schedule) must be identical
         # on every rank; the RNG key is rank-local knowledge, so it keeps
         # its sorted slot here and only its *apply* is deferred (to last,
-        # after all barriers — RngState application is collective-free),
-        # exactly like the sync path.
-        keys = _gather_keys(app_state, pg_wrapper)
-
-        # Nonce for the plan AND apply phases' error-propagating barriers
-        # — agreed BEFORE any storage read or planning (round 5), so the
-        # whole setup runs with an error-aware rendezvous in place: the
+        # after all barriers — RngState application is collective-free).
+        keys = _gather_keys(app_state, op.pg)
+        # Agreed BEFORE any storage read or planning (round 5), so the
+        # whole set-up runs with an error-aware rendezvous in place: the
         # metadata read and per-key planning report failures into the
         # plan barriers below, and peers abandon there in seconds instead
         # of stranding inside a plain op-seq barrier (where a reported
         # error is invisible) for the full store timeout.
-        restore_nonce = None
-        fanout_agreed = False
-        if pg_wrapper.get_world_size() > 1:
-            import uuid
-
-            # Fan-out enablement rides the nonce broadcast (one
-            # agreement collective before any failure point; rank 0's
-            # knob decides for the job) — same shape as the sync path.
-            restore_nonce, fanout_agreed = pg_wrapper.broadcast_object(
-                (uuid.uuid4().hex, knobs.is_fanout_restore_enabled())
-            )
-
-        def plan_barrier(i: int) -> Optional[StoreBarrier]:
-            if restore_nonce is None:
-                return None
-            return _nonce_barrier(
-                f"__restore/{restore_nonce}/plan{i}", pg_wrapper
-            )
-
-        setup_barrier = plan_barrier(0) if keys else None
-        with _reporting_to(setup_barrier, "async restore setup"):
-            available = get_manifest_for_rank(self.metadata, rank)
-            world_size = self.metadata.world_size
+        op.nonce, op.fanout_agreed = _agree_restore(op.pg)
+        op.set_up(op.barrier("plan0") if keys else None, memory_budget_bytes)
 
         plans: Dict[str, _StatefulLoadPlan] = {}
         for i, key in enumerate(keys):
-            barrier = plan_barrier(i)
+            barrier = op.barrier(f"plan{i}")
             with _reporting_to(barrier, "async restore planning"):
-                stateful = app_state.get(key)
-                if stateful is not None:
-                    plan = self._plan_stateful_load(
-                        key, stateful, available, memory_budget_bytes
-                    )
-                    if plan is not None:
-                        plans[key] = plan
+                for plan in self._plan_key(key, app_state.get(key), op):
+                    plans[key] = plan
             # state_dict() may itself run collectives: keep the capture
             # globally ordered (reference snapshot.py:353-370). The
             # barrier is error-aware: a peer's planning failure abandons
@@ -1300,172 +1002,31 @@ class Snapshot:
                 barrier.arrive()
                 barrier.depart()
 
-        # Single-reader fan-out, async flavor: the exchange is a
-        # cross-rank rendezvous, so it runs HERE — on the calling
-        # thread, after every plan exists — covering all plans in one
-        # round; the owner-side unique-shard fetches land in this
-        # (visible) span and the background pipeline then reads them
-        # from the cache (no rendezvous off the main thread). The
-        # round's error-aware barrier keeps a failing rank from
-        # stranding its peers in the exchange.
-        fanout_ctx = None
-        if fanout_agreed:
-            exchange_prefix = f"__restore/{restore_nonce}/fanout"
-            exchange_barrier = _nonce_barrier(exchange_prefix, pg_wrapper)
-            with _reporting_to(exchange_barrier, "fan-out exchange"):
-                from .fanout import FanoutRestoreContext
-
-                fanout_ctx = FanoutRestoreContext.build(
-                    self.metadata.manifest, pg_wrapper
-                )
-                if fanout_ctx.owners:
-                    reqs = [
-                        r for plan in plans.values() for r in plan.read_reqs
-                    ]
-                    exchange_loop = asyncio.new_event_loop()
-                    try:
-                        exchange_storage = url_to_storage_plugin(self.path)
-                        try:
-                            fanout_ctx.exchange(
-                                reqs,
-                                exchange_storage,
-                                exchange_loop,
-                                rendezvous_prefix=exchange_prefix,
-                            )
-                        finally:
-                            exchange_loop.run_until_complete(
-                                exchange_storage.close()
-                            )
-                    finally:
-                        exchange_loop.close()
-                else:
-                    fanout_ctx = None  # nothing shard-shaped to fan out
-
-        # Peer-tier ladder, async flavor: the owner table is assembled
-        # on the calling thread (inventory RPCs only — cheap, and no
-        # rendezvous belongs on the read thread); the background
-        # pipeline then pulls table-resident blobs from peer RAM.
-        from .tiered import peer as _peer_tier
-
-        peer_ctx = _peer_tier.build_restore_context(self.path)
-
+        # The exchange is a cross-rank rendezvous, so it runs HERE — on
+        # the calling thread, after every plan exists — covering all
+        # plans in one round; the owner-side unique-shard fetches land in
+        # this (visible) span and the background pipeline then reads them
+        # from the cache (no rendezvous off the main thread). The round's
+        # error-aware barrier keeps a failing rank from stranding its
+        # peers in the exchange.
+        if op.fanout_ctx is not None:
+            with _reporting_to(op.barrier("fanout"), "fan-out exchange"):
+                op.exchange(list(plans.values()), "fanout")
         return PendingRestore(
-            path=self.path,
-            keys=keys,
-            plans=plans,
-            pg_wrapper=pg_wrapper,
-            memory_budget_bytes=memory_budget_bytes,
-            rank=rank,
-            world_size=world_size,
-            rng_key=rng_key,
-            restore_nonce=restore_nonce,
-            counter_baseline=telemetry.metrics().counters_snapshot(),
-            trace_mark=trace_mark,
-            tunables=knobs.tunable_snapshot(),
-            fanout_ctx=fanout_ctx,
-            peer_ctx=peer_ctx,
-            trace_op=trace_op,
+            op, keys, plans, rng_key_and_state[0] if rng_key_and_state else None
         )
 
-    def _load_stateful(
-        self,
-        key: str,
-        stateful: Stateful,
-        available: Manifest,
-        storage: StoragePlugin,
-        memory_budget_bytes: int,
-        event_loop: asyncio.AbstractEventLoop,
-        rank: int,
-        checksum_table=None,
-        pipeline_sink: Optional[List[dict]] = None,
-        progress_tracker: Optional[_progress.ProgressTracker] = None,
-    ) -> None:
-        """Memory-frugal restore of one stateful: reuse the leaves already
-        allocated in its current state dict as read destinations so peak
-        footprint stays ~1x (reference snapshot.py:668-766).
-        ``pipeline_sink`` collects the read pipeline's telemetry for the
-        caller's SnapshotReport. Plan + execute in one call, with no
-        fan-out — the entry point for loads outside the shared barrier
-        schedule (the RNG stateful, restored rank-locally last)."""
-        plan = self._plan_stateful_load(
-            key, stateful, available, memory_budget_bytes
-        )
-        if plan is None:
-            return
-        self._execute_load_plan(
-            plan,
-            storage=storage,
-            memory_budget_bytes=memory_budget_bytes,
-            event_loop=event_loop,
-            rank=rank,
-            checksum_table=checksum_table,
-            pipeline_sink=pipeline_sink,
-            progress_tracker=progress_tracker,
-        )
-
-    def _execute_load_plan(
-        self,
-        plan: "_StatefulLoadPlan",
-        storage: StoragePlugin,
-        memory_budget_bytes: int,
-        event_loop: asyncio.AbstractEventLoop,
-        rank: int,
-        checksum_table=None,
-        pipeline_sink: Optional[List[dict]] = None,
-        progress_tracker: Optional[_progress.ProgressTracker] = None,
-        fanout_ctx=None,
-    ) -> None:
-        """Run one planned stateful load's read pipeline and apply it.
-        With ``fanout_ctx`` (an exchange for this plan already ran), the
-        pipeline reads exchanged shard blobs from the fan-out cache and
-        only the rest from the real plugin."""
-        read_reqs = plan.read_reqs
-        # The rank's pre-batching destination bytes — the denominator of
-        # the read-amplification metric restore reports carry.
-        bytes_needed = sum(_req_needed_bytes(r) for r in read_reqs)
-        if knobs.is_batching_enabled():
-            from .batcher import batch_read_requests
-
-            read_reqs = batch_read_requests(read_reqs)
-        # Streaming placement: completed leaves device_put while the
-        # remaining reads are still in flight.
-        placer = _StreamingPlacer()
-        placer.register_plan(plan)
-        placer.lease_destinations(read_reqs, memory_budget_bytes)
-        try:
-            pipeline_telemetry = sync_execute_read_reqs(
-                read_reqs=read_reqs,
-                storage=(
-                    fanout_ctx.wrap(storage)
-                    if fanout_ctx is not None
-                    else storage
-                ),
-                memory_budget_bytes=memory_budget_bytes,
-                rank=rank,
-                event_loop=event_loop,
-                checksum_table=checksum_table,
-                on_req_complete=placer.on_req_complete,
-                progress=progress_tracker,
-                classify_read=(
-                    fanout_ctx.classify_read
-                    if fanout_ctx is not None
-                    else None
-                ),
-                destinations=placer.leases,
+    def _plan_key(
+        self, key: str, stateful: Optional[Stateful], op: "_RestoreOp"
+    ) -> List["_StatefulLoadPlan"]:
+        """The plans of one key on this rank: none where the rank holds
+        no such stateful or the snapshot no entry for it."""
+        plan = None
+        if stateful is not None:
+            plan = self._plan_stateful_load(
+                key, stateful, op.available, op.memory_budget_bytes
             )
-            pipeline_telemetry["bytes_needed"] = bytes_needed
-            placer.report_destinations(pipeline_telemetry)
-            if pipeline_sink is not None:
-                pipeline_sink.append(pipeline_telemetry)
-            placer.flush()
-            with trace_annotation(
-                telemetry.names.SPAN_RESTORE_APPLY, stateful=plan.key
-            ):
-                plan.finish_reads()
-                plan.apply()
-        except BaseException:
-            placer.abandon_destinations()
-            raise
+        return [] if plan is None else [plan]
 
     def _plan_stateful_load(
         self,
@@ -1927,35 +1488,195 @@ class _StatefulLoadPlan:
         self.groups = groups
         self.read_reqs = read_reqs
 
-    def finish_reads(self, batch: Optional[_PlacementBatch] = None) -> None:
-        """Run deferred conversions (np buffers -> device arrays on their
-        original shardings) not already streamed. Safe off the main
-        thread: conversions only ``device_put`` addressable data — no
-        collectives. With a shared ``batch`` the placements only register
-        here; the caller runs the batch (one dispatch spanning many
-        plans). Without one, a local batch runs immediately."""
-        own = batch is None
-        if batch is None:
-            batch = _PlacementBatch()
+    def finish_reads(self, batch: _PlacementBatch) -> None:
+        """Register into ``batch`` the deferred conversions (np buffers
+        -> device arrays on their original shardings) not already
+        streamed; the caller runs the batch (one dispatch spanning many
+        plans). Safe off the main thread: conversions only
+        ``device_put`` addressable data — no collectives."""
         for group in self.groups:
             if not group.done:
                 group.fn(batch)
                 group.done = True
-        if own:
-            batch.run()
 
-    def apply(self) -> None:
-        """Hand the restored state dict to the application. May run
-        arbitrary user code (collectives included) — main thread only."""
-        state_dict = inflate(
-            {**self.container_entries}, self.restored, prefix=self.key
+    def apply(self, finish: bool = False) -> None:
+        """Hand the restored state dict to the application, under the
+        plan's ``restore:apply`` span. May run arbitrary user code
+        (collectives included) — main thread only. ``finish``: the
+        plan's last placements have not run, and run under the span
+        first."""
+        with trace_annotation(
+            telemetry.names.SPAN_RESTORE_APPLY, stateful=self.key
+        ):
+            if finish:
+                _finish_plans([self])
+            state_dict = inflate(
+                {**self.container_entries}, self.restored, prefix=self.key
+            )
+            self.stateful.load_state_dict(state_dict)
+
+
+def _finish_plans(plans: List[_StatefulLoadPlan]) -> None:
+    """Whatever did not stream (streaming off, leaves without reads)
+    places in ONE batched ``device_put`` spanning ``plans``: per-leaf
+    dispatch latency x hundreds of leaves is real cold-start time."""
+    batch = _PlacementBatch()
+    for plan in plans:
+        plan.finish_reads(batch)
+    batch.run()
+
+
+# ---------------------------------------------------------------------------
+# take: one prologue, one commit sequence, two drivers
+# ---------------------------------------------------------------------------
+
+
+class _TakeOp:
+    """One take from its prologue to its report: what
+    :meth:`Snapshot.take` and the :class:`PendingSnapshot` of
+    :meth:`Snapshot.async_take` both hold, and the commit sequence both
+    run — ``take`` on the caller's thread, the handle on its
+    ``snapshot-commit`` thread. Collectives all happen in the prologue
+    and in :meth:`stage`, on the calling thread."""
+
+    def __init__(self, kind: str, path: str, pg: Optional[Any]) -> None:
+        from .cas import cas_eligible
+
+        self.kind = kind
+        self.pg = pg_wrapper = PGWrapper(pg)
+        self.rank = pg_wrapper.get_rank()
+        # Rank-0 path wins; the CAS layout decision rides the same
+        # broadcast (one agreement, no extra collective) so ranks can
+        # never diverge on where data bytes land.
+        self.path, cas_on = pg_wrapper.broadcast_object(
+            (path, cas_eligible(path))
         )
-        self.stateful.load_state_dict(state_dict)
+        # Error-propagating commit barrier: a rank whose staging or
+        # writes fail must not strand its peers for the full store
+        # timeout — they observe the reported error at arrive() and
+        # abandon (no commit marker anywhere). Staging includes
+        # rank-0-only work such as replication verification, and peers
+        # whose staging succeeded may already be waiting at arrive().
+        # The nonce keeps barrier keys from aliasing any earlier take to
+        # the same path (failed ones included); a world of one has no
+        # barrier and agrees none.
+        self.nonce = ""
+        if pg_wrapper.get_world_size() > 1:
+            import uuid
 
+            self.nonce = pg_wrapper.broadcast_object(uuid.uuid4().hex)
+        self.barrier = _nonce_barrier(
+            f"__snapshot_commit/{self.nonce}", pg_wrapper
+        )
+        self.counter_baseline = telemetry.metrics().counters_snapshot()
+        self.tunables = knobs.tunable_snapshot()
+        self.trace_mark = _trace_recorder().mark()
+        self.storage = _maybe_cas_storage(
+            url_to_storage_plugin(self.path), self.path, cas_on
+        )
+        # Live-progress heartbeat for the whole op: external pollers see
+        # a stuck rank from outside the process (telemetry/progress.py).
+        self.tracker = _progress.track(kind, self.path, self.rank)
+        self.event_loop = asyncio.new_event_loop()
+        # The flight recorder's id of this take: read in stage(), while
+        # the caller's envelope is open.
+        self.trace_op = 0
+        self.pending_io_work: "PendingIOWork | DeferredIOWork | None" = None
+        self.metadata: Optional[SnapshotMetadata] = None
+        # Extra pipeline fields of the report: the async handle's
+        # visible / staged phase split.
+        self.phases: Dict[str, float] = {}
 
-# ---------------------------------------------------------------------------
-# PendingSnapshot
-# ---------------------------------------------------------------------------
+    def stage(
+        self,
+        app_state: AppState,
+        replicated: List[str],
+        incremental_base: Optional[Any],
+        record_digests: bool,
+        _custom_array_prepare_func,
+        defer_staging: bool = False,
+    ) -> None:
+        """Plan and stage (``defer_staging``: capture) inside the
+        caller's envelope; a failure poisons the commit barrier before
+        it raises."""
+        with _reporting_to(self.barrier, f"{self.kind} staging"):
+            self.trace_op = _current_op()
+            self.pending_io_work, self.metadata = Snapshot._take_impl(
+                path=self.path,
+                app_state=app_state,
+                pg_wrapper=self.pg,
+                replicated=replicated,
+                storage=self.storage,
+                event_loop=self.event_loop,
+                is_async_snapshot=self.kind == "async_take",
+                incremental_base=incremental_base,
+                record_digests=record_digests,
+                _custom_array_prepare_func=_custom_array_prepare_func,
+                progress_tracker=self.tracker,
+                defer_staging=defer_staging,
+            )
+
+    def commit(self, span: Any) -> None:
+        """The commit sequence, spelled here alone: drain the writes,
+        make the checksum and CAS tables durable, rendezvous, rank 0
+        writes the marker, hand the blobs to the peer tier, close the
+        storage, close ``span`` (the caller's envelope) and report. A
+        failure anywhere in it is reported to the barrier before it
+        propagates, so peers polling at arrive() or depart() abandon in
+        seconds instead of blocking out the store timeout; ``take``
+        raises it, the commit thread records it for ``wait()``."""
+        work, loop = self.pending_io_work, self.event_loop
+        with _reporting_to(self.barrier, self.kind):
+            work.sync_complete(loop)
+            _crashpoint(telemetry.names.CRASH_TAKE_WRITES_DONE)
+            # All writes are durable on every rank before the commit
+            # marker exists anywhere (commit-after-barrier invariant).
+            with trace_annotation(
+                telemetry.names.SPAN_COMMIT_FINALIZE, rank=self.rank
+            ):
+                _write_checksum_and_cas_tables(
+                    work, self.rank, self.storage, loop
+                )
+                if self.barrier is not None:
+                    self.barrier.arrive()
+                if self.rank == 0:
+                    Snapshot._write_snapshot_metadata(
+                        self.metadata, self.storage, loop
+                    )
+                if self.barrier is not None:
+                    self.barrier.depart()
+            # Post-commit: hand this rank's blobs to the peer tier (the
+            # committed step is what a replacement rank would restore).
+            # The enqueue is queue-put cheap; the job runs on the peer
+            # replicator's own worker.
+            _maybe_push_to_peer(self.path, work)
+            loop.run_until_complete(self.storage.close())
+            # The envelope closes before the report/trace emission so
+            # the exported timeline carries the take's full extent.
+            _tracing.end(span)
+            # Post-close on purpose: a tiered plugin enqueues its mirror
+            # job at close, so the report's mirror state reflects the
+            # durability backlog this take just created. Store-based
+            # gather + local file append only: safe on the commit thread
+            # (no collectives), the rule the commit barrier follows.
+            _emit_snapshot_report(
+                kind=self.kind,
+                path=self.path,
+                pg_wrapper=self.pg,
+                pipeline={**work.pipeline_telemetry(), **self.phases},
+                counter_baseline=self.counter_baseline,
+                nonce=self.nonce,
+                trace_mark=self.trace_mark,
+                tunables=self.tunables,
+                trace_op=self.trace_op,
+            )
+
+    def settle(self, error: Optional[BaseException]) -> None:
+        """The end of the take on the thread that ran it, failed or not.
+        Success removes the heartbeat file; failure leaves a terminal
+        document (doctor evidence the op *ended*)."""
+        self.tracker.finish(error)
+        self.event_loop.close()
 
 
 class PendingSnapshot:
@@ -1981,62 +1702,36 @@ class PendingSnapshot:
       marker exists. ``wait()`` / ``wait(phase="committed")``.
     """
 
-    def __init__(
-        self,
-        path: str,
-        pending_io_work: "PendingIOWork | DeferredIOWork",
-        pg_wrapper: PGWrapper,
-        metadata: Optional[SnapshotMetadata],
-        storage: StoragePlugin,
-        event_loop: asyncio.AbstractEventLoop,
-        commit_nonce: str = "",
-        counter_baseline: Optional[Dict[str, float]] = None,
-        trace_mark: Optional[TraceMark] = None,
-        progress_tracker: Optional[_progress.ProgressTracker] = None,
-        op_begin: Optional[float] = None,
-        tunables: Optional[Dict[str, Any]] = None,
-        trace_op: int = 0,
-    ) -> None:
+    def __init__(self, op: _TakeOp, op_begin: float) -> None:
         import threading
 
-        self.path = path
+        self._op = op
+        self.path = op.path
         # The flight recorder's id of this take (the stage envelope's):
         # the commit envelope joins it, and so does what the manager
         # does for the step in wait().
-        self.trace_op = trace_op
-        self.commit_nonce = commit_nonce
-        self.pg = pg_wrapper
-        self._metadata = metadata
-        self._storage = storage
-        self._event_loop = event_loop
-        self._pending_io_work = pending_io_work
-        self._counter_baseline = counter_baseline or {}
-        self._trace_mark = trace_mark
-        # Effective tunable values captured at async_take entry — the
-        # ones the take ran under, regardless of what the autotuner
-        # applies between now and the commit thread's report emission.
-        self._tunables = tunables
-        self._progress_tracker = progress_tracker
+        self.trace_op = op.trace_op
+        self.commit_nonce = op.nonce
+        self.pg = op.pg
         self._exc_info: Optional[BaseException] = None
         self._done = threading.Event()
         self._staged = threading.Event()
-        # Phase-split telemetry, relative to async_take's entry: the
-        # visible span is over by construction time (this handle IS the
-        # return value); staged_s is stamped by the drain callback.
-        self._op_begin = op_begin if op_begin is not None else time.monotonic()
-        self._visible_s = time.monotonic() - self._op_begin
-        self._staged_s: Optional[float] = None
-        if isinstance(pending_io_work, DeferredIOWork):
+        # Phase-split telemetry, relative to async_take's entry, for the
+        # doctor's async-visible-stall rule: the visible span is over by
+        # construction time (this handle IS the return value); staged_s
+        # is stamped by the drain callback.
+        op.phases["visible_s"] = round(time.monotonic() - op_begin, 6)
+        if isinstance(op.pending_io_work, DeferredIOWork):
             # Wired BEFORE the thread starts: the drain may reach the
             # staged boundary arbitrarily fast.
             def _mark_staged() -> None:
-                self._staged_s = time.monotonic() - self._op_begin
+                op.phases["staged_s"] = round(time.monotonic() - op_begin, 6)
                 self._staged.set()
 
-            pending_io_work.on_staged = _mark_staged
+            op.pending_io_work.on_staged = _mark_staged
         else:
             # Non-deferred takes staged before this handle existed.
-            self._staged_s = self._visible_s
+            op.phases["staged_s"] = op.phases["visible_s"]
             self._staged.set()
         self._thread = threading.Thread(
             target=self._complete_snapshot, name="snapshot-commit", daemon=True
@@ -2044,85 +1739,25 @@ class PendingSnapshot:
         self._thread.start()
 
     def _complete_snapshot(self) -> None:
-        barrier = None
         commit_span = _tracing.begin(
             telemetry.names.SPAN_ASYNC_TAKE_COMMIT,
             op=self.trace_op,
             path=self.path,
-            rank=self.pg.get_rank(),
+            rank=self._op.rank,
         )
         try:
-            barrier = _nonce_barrier(
-                f"__snapshot_commit/{self.commit_nonce}", self.pg
-            )
-            self._pending_io_work.sync_complete(self._event_loop)
-            _crashpoint(telemetry.names.CRASH_TAKE_WRITES_DONE)
-            with trace_annotation(
-                telemetry.names.SPAN_COMMIT_FINALIZE, rank=self.pg.get_rank()
-            ):
-                _write_checksum_and_cas_tables(
-                    self._pending_io_work,
-                    self.pg.get_rank(),
-                    self._storage,
-                    self._event_loop,
-                )
-                if barrier is not None:
-                    barrier.arrive()
-                if self.pg.get_rank() == 0:
-                    Snapshot._write_snapshot_metadata(
-                        self._metadata, self._storage, self._event_loop
-                    )
-                if barrier is not None:
-                    barrier.depart()
-            # Post-commit peer push, same hook as the sync take's: the
-            # enqueue is queue-put cheap and the job runs on the peer
-            # replicator's own worker, not this commit thread.
-            _maybe_push_to_peer(self.path, self._pending_io_work)
-            self._event_loop.run_until_complete(self._storage.close())
-            _tracing.end(commit_span)
-            # Store-based gather + local file append only — safe on this
-            # background thread (no collectives), same rule the commit
-            # barrier follows. Post-close so a tiered take's report sees
-            # its just-enqueued mirror job. The pipeline dict carries the
-            # visible/staged phase split for the doctor's
-            # async-visible-stall rule.
-            pipeline = dict(self._pending_io_work.pipeline_telemetry())
-            pipeline["visible_s"] = round(self._visible_s, 6)
-            if self._staged_s is not None:
-                pipeline["staged_s"] = round(self._staged_s, 6)
-            _emit_snapshot_report(
-                kind="async_take",
-                path=self.path,
-                pg_wrapper=self.pg,
-                pipeline=pipeline,
-                counter_baseline=self._counter_baseline,
-                nonce=self.commit_nonce,
-                trace_mark=self._trace_mark,
-                tunables=self._tunables,
-                trace_op=self.trace_op,
-            )
+            self._op.commit(commit_span)
         except BaseException as e:  # noqa: BLE001 - must propagate via wait()
-            # Record the failure before telling peers: report_error talks to
-            # the store and may itself fail, but wait() must still raise.
             self._exc_info = e
             logger.error("Async snapshot failed: %r", e)
-            if barrier is not None:
-                try:
-                    barrier.report_error(e)
-                except Exception as report_exc:
-                    logger.error(
-                        "Failed to report snapshot error to peers: %r", report_exc
-                    )
         finally:
             # Ordering matters on the failure path: the error is recorded
             # and the heartbeat settled TERMINAL ("failed", never a
             # crash-shaped non-terminal leftover) before the staged/done
             # events release any waiter — a woken wait() must observe the
             # final state, exactly once, not a half-settled one.
-            if self._progress_tracker is not None:
-                self._progress_tracker.finish(self._exc_info)
             _tracing.end(commit_span)  # no-op if already closed
-            self._event_loop.close()
+            self._op.settle(self._exc_info)
             self._staged.set()  # no-op if staging completed normally
             self._done.set()
 
@@ -2156,7 +1791,7 @@ class PendingSnapshot:
         # Preserve the process group: restore() on the returned snapshot
         # must keep per-rank availability and coordination semantics.
         snapshot = Snapshot(path=self.path, pg=self.pg)
-        snapshot._metadata = self._metadata
+        snapshot._metadata = self._op.metadata
         return snapshot
 
     def done(self) -> bool:
@@ -2167,6 +1802,253 @@ class PendingSnapshot:
         will not block). Also true after a failed drain — ``wait`` then
         raises instead of blocking."""
         return self._staged.is_set()
+
+
+# ---------------------------------------------------------------------------
+# restore: one set-up, one read pipeline, one report, two drivers
+# ---------------------------------------------------------------------------
+
+
+def _agree_restore(pg_wrapper: PGWrapper) -> Tuple[Optional[str], bool]:
+    """A restore's ONE agreement collective, before any failure point:
+    the nonce of its error-propagating barriers and, riding the same
+    broadcast, whether shard blobs fan out — rank 0's knob reading
+    decides for the whole job (env skew can never diverge the schedule)
+    and a later set-up failure can never leave the shared op-seq counter
+    half-advanced. ``(None, False)`` in a world of one."""
+    if pg_wrapper.get_world_size() <= 1:
+        return None, False
+    import uuid
+
+    return pg_wrapper.broadcast_object(
+        (uuid.uuid4().hex, knobs.is_fanout_restore_enabled())
+    )
+
+
+class _RestoreOp:
+    """One restore from its agreement to its report: the steps that
+    :meth:`Snapshot.restore` and :meth:`Snapshot.async_restore` share,
+    each written once — set-up, fan-out exchange, the read pipeline, the
+    report. What differs stays with the drivers: which plans go through
+    one pipeline, on which thread, and when they are applied.
+
+    ``restore`` is not ``async_restore().wait()``: it plans, reads and
+    applies one stateful at a time, so only that stateful's new arrays
+    stand beside its old ones in HBM and its pipeline takes its own cap
+    of the destination pool; the async driver holds every stateful's new
+    arrays until ``wait()`` and reads on another thread."""
+
+    def __init__(
+        self,
+        snapshot: Snapshot,
+        kind: str,
+        pg_wrapper: PGWrapper,
+        agreement: Tuple[Optional[str], bool] = (None, False),
+    ) -> None:
+        self.snapshot = snapshot
+        self.kind = kind
+        self.path = snapshot.path
+        self.pg = pg_wrapper
+        self.rank = pg_wrapper.get_rank()
+        self.nonce, self.fanout_agreed = agreement
+        self.counter_baseline = telemetry.metrics().counters_snapshot()
+        self.tunables = knobs.tunable_snapshot()
+        self.trace_mark = _trace_recorder().mark()
+        self.trace_op = 0
+        self.tracker = _progress.track(kind, self.path, self.rank)
+        self.memory_budget_bytes = 0
+        self.event_loop: Optional[asyncio.AbstractEventLoop] = None
+        self.storage: Optional[StoragePlugin] = None
+        self.available: Manifest = {}
+        self.checksum_table: Any = None
+        self.peer_ctx: Any = None
+        self.fanout_ctx: Any = None
+        self.cold_start: Dict[str, float] = {}
+        # One entry a read pipeline, merged by the report.
+        self.pipelines: List[dict] = []
+
+    def barrier(self, tag: Any) -> Optional[StoreBarrier]:
+        """The error-propagating barrier ``tag`` of this restore (same
+        design as the take commit barrier): a rank whose reads fail —
+        bit rot, a CRC mismatch — reports before raising, so peers
+        waiting there abandon instead of blocking out the full store
+        timeout. All of a restore's barriers hang off its one nonce."""
+        if self.nonce is None:
+            return None
+        return _nonce_barrier(f"__restore/{self.nonce}/{tag}", self.pg)
+
+    def set_up(
+        self, barrier: Optional[StoreBarrier], memory_budget_bytes: int
+    ) -> None:
+        """Open what the reads need, on the calling thread, after the
+        driver's collectives: the event loop, the storage plugin behind
+        the peer ladder, the rank's manifest, the checksum table and the
+        fan-out owner table. A failure is reported to ``barrier``, the
+        first one peers wait at."""
+        self.memory_budget_bytes = memory_budget_bytes
+        with _reporting_to(barrier, f"{self.kind} setup"):
+            # Cold-start attribution: the envelope work before the first
+            # storage byte can move — event-loop spin-up, plugin open,
+            # and the native digest library's first load — timed
+            # separately so a first-trial restore that dwarfs warm trials
+            # convicts its cause in the report (``cold_start`` /
+            # ``cold_start_s``) instead of leaving the gap a guess.
+            t = time.monotonic()
+            self.event_loop = asyncio.new_event_loop()
+            self.cold_start["event_loop_s"] = time.monotonic() - t
+            t = time.monotonic()
+            self.storage = url_to_storage_plugin(self.path)
+            self.cold_start["plugin_open_s"] = time.monotonic() - t
+            t = time.monotonic()
+            from .integrity import _alg_available
+
+            _alg_available("crc32c")  # first call loads the native lib
+            self.cold_start["native_load_s"] = time.monotonic() - t
+            # Peer-tier ladder (docs/peer.md): when surviving peers hold
+            # this step's shards in RAM, reads resolve peer -> fast ->
+            # durable per blob, digest-verified. Build is rank-local
+            # (inventory RPCs, no collectives), so peers building or
+            # not building the ladder independently can never diverge
+            # the restore schedule; every failure degrades to None. The
+            # pulls are point-to-point socket reads, safe on a read
+            # thread.
+            from .tiered import peer as _peer_tier
+
+            self.peer_ctx = _peer_tier.build_restore_context(self.path)
+            if self.peer_ctx is not None:
+                self.storage = self.peer_ctx.wrap(self.storage)
+            with trace_annotation(telemetry.names.SPAN_RESTORE_PLAN):
+                self.available = get_manifest_for_rank(
+                    self.snapshot.metadata, self.rank
+                )
+                self.checksum_table = self.snapshot._get_checksum_table(
+                    self.storage, self.event_loop
+                )
+            # Single-reader fan-out (docs/restore.md): enablement was
+            # broadcast-agreed; the owner table is derived
+            # deterministically from the committed manifest (same bytes
+            # on every rank), inside the error-aware set-up window like
+            # every other failure-prone set-up read.
+            if self.fanout_agreed:
+                from .fanout import FanoutRestoreContext
+
+                fanout_ctx = FanoutRestoreContext.build(
+                    self.snapshot.metadata.manifest, self.pg
+                )
+                if fanout_ctx.owners:  # else nothing shard-shaped to fan out
+                    self.fanout_ctx = fanout_ctx
+
+    def exchange(
+        self, plans: List["_StatefulLoadPlan"], tag: Any
+    ) -> List[str]:
+        """One fan-out round over ``plans`` (none: this rank loads
+        nothing this round, and still takes part) under barrier ``tag``,
+        whose error key the round's waits poll. On the thread that owns
+        collective ordering. Returns the locations it cached."""
+        if self.fanout_ctx is None:
+            return []
+        return self.fanout_ctx.exchange(
+            [r for plan in plans for r in plan.read_reqs],
+            self.storage,
+            self.event_loop,
+            rendezvous_prefix=f"__restore/{self.nonce}/{tag}",
+        )
+
+    def read_plans(
+        self, plans: List["_StatefulLoadPlan"], apply: bool
+    ) -> None:
+        """The read pipeline, spelled here alone: one pipeline over the
+        reads of ``plans``, with streaming placement and destinations
+        leased from the process's pool, the exchanged shard blobs served
+        from the fan-out cache and the rest from the plugin. On the
+        thread that calls it. ``apply``: each plan is handed to the
+        application as soon as the pipeline is through (the sync driver,
+        a key at a time); otherwise its last placements are dispatched
+        and ``wait()`` applies it."""
+        read_reqs = [r for plan in plans for r in plan.read_reqs]
+        # The rank's pre-batching destination bytes — the denominator of
+        # the read-amplification metric restore reports carry.
+        bytes_needed = sum(_req_needed_bytes(r) for r in read_reqs)
+        if knobs.is_batching_enabled():
+            from .batcher import batch_read_requests
+
+            read_reqs = batch_read_requests(read_reqs)
+        # Streaming placement: completed leaves device_put in rolling
+        # batches while the remaining reads are still in flight.
+        placer = _StreamingPlacer()
+        for plan in plans:
+            placer.register_plan(plan)
+        placer.lease_destinations(read_reqs, self.memory_budget_bytes)
+        fanout_ctx = self.fanout_ctx
+        try:
+            pipeline = sync_execute_read_reqs(
+                read_reqs=read_reqs,
+                storage=(
+                    fanout_ctx.wrap(self.storage)
+                    if fanout_ctx is not None
+                    else self.storage
+                ),
+                memory_budget_bytes=self.memory_budget_bytes,
+                rank=self.rank,
+                event_loop=self.event_loop,
+                checksum_table=self.checksum_table,
+                on_req_complete=placer.on_req_complete,
+                progress=self.tracker,
+                classify_read=(
+                    fanout_ctx.classify_read
+                    if fanout_ctx is not None
+                    else None
+                ),
+                destinations=placer.leases,
+            )
+            pipeline["bytes_needed"] = bytes_needed
+            placer.report_destinations(pipeline)
+            self.pipelines.append(pipeline)
+            placer.flush()
+            if apply:
+                for plan in plans:
+                    plan.apply(finish=True)
+            else:
+                _finish_plans(plans)
+        except BaseException:
+            placer.abandon_destinations()
+            raise
+
+    def close_storage(self) -> None:
+        self.event_loop.run_until_complete(self.storage.close())
+
+    def report(self, nonce: Optional[str]) -> None:
+        """Emit the restore's report: the pipelines merged, the fan-out
+        and peer-tier byte accounting, the cold-start split. ``nonce``
+        None keeps it rank-local (no cross-rank gather)."""
+        pipeline = telemetry.merge_pipeline_telemetry(self.pipelines)
+        _merge_fanout_telemetry(pipeline, self.fanout_ctx)
+        _merge_peer_telemetry(pipeline, self.peer_ctx)
+        # Round the parts BEFORE summing: the report layer rounds each
+        # part to 6dp on serialization, so deriving the total from the
+        # raw values can disagree with the serialized parts by 1e-06 for
+        # unlucky timings.
+        cold_start = {k: round(v, 6) for k, v in self.cold_start.items()}
+        pipeline["cold_start"] = cold_start
+        pipeline["cold_start_s"] = round(sum(cold_start.values()), 6)
+        _emit_snapshot_report(
+            kind=self.kind,
+            path=self.path,
+            pg_wrapper=self.pg,
+            pipeline=pipeline,
+            counter_baseline=self.counter_baseline,
+            nonce=nonce,
+            trace_mark=self.trace_mark,
+            tunables=self.tunables,
+            trace_op=self.trace_op,
+        )
+
+    def settle(self, error: Optional[BaseException]) -> None:
+        """The end of the restore's reads on the thread that ran them,
+        failed or not."""
+        self.tracker.finish(error)
+        if self.event_loop is not None:
+            self.event_loop.close()
 
 
 class PendingRestore:
@@ -2182,52 +2064,21 @@ class PendingRestore:
 
     def __init__(
         self,
-        path: str,
+        op: _RestoreOp,
         keys: List[str],
         plans: Dict[str, _StatefulLoadPlan],
-        pg_wrapper: PGWrapper,
-        memory_budget_bytes: int,
-        rank: int,
-        world_size: int,
-        rng_key: Optional[str] = None,
-        restore_nonce: Optional[str] = None,
-        counter_baseline: Optional[Dict[str, float]] = None,
-        trace_mark: Optional[TraceMark] = None,
-        tunables: Optional[Dict[str, Any]] = None,
-        fanout_ctx=None,
-        peer_ctx=None,
-        trace_op: int = 0,
+        rng_key: Optional[str],
     ) -> None:
         import threading
 
-        self.path = path
+        self._op = op
+        self.path = op.path
         self._keys = keys
         self._plans = plans
         self._rng_key = rng_key
-        self._restore_nonce = restore_nonce
-        self._pg = pg_wrapper
-        self._memory_budget_bytes = memory_budget_bytes
-        self._rank = rank
-        self._world_size = world_size
-        self._counter_baseline = counter_baseline or {}
-        self._trace_mark = trace_mark
-        self._tunables = tunables
-        # Fan-out cache populated by the calling-thread exchange; the
-        # background pipeline serves exchanged shard blobs from it (no
-        # collectives off the main thread — the bytes already moved).
-        self._fanout_ctx = fanout_ctx
-        # Peer-tier owner table built on the calling thread; pulls are
-        # point-to-point socket reads, safe on the read thread.
-        self._peer_ctx = peer_ctx
-        # Created on the initiating thread; fed and settled by the
-        # background read thread.
-        self._progress_tracker = _progress.track(
-            "async_restore", path, rank
-        )
-        self._pipeline_telemetry: Optional[dict] = None
         # The op async_restore's planning envelope opened; the reads
         # envelope joins it, and wait() applies and reports under it.
-        self.trace_op = trace_op
+        self.trace_op = op.trace_op
         self._exc_info: Optional[BaseException] = None
         self._applied = False
         self._done = threading.Event()
@@ -2237,95 +2088,28 @@ class PendingRestore:
         self._thread.start()
 
     def _run_reads(self) -> None:
-        event_loop = asyncio.new_event_loop()
+        op = self._op
         reads_span = _tracing.begin(
             telemetry.names.SPAN_ASYNC_RESTORE_READS,
             op=self.trace_op,
             path=self.path,
-            rank=self._rank,
+            rank=op.rank,
         )
-        # A handle made without a planning envelope starts the op here.
-        self.trace_op = _current_op()
-        placer: Optional[_StreamingPlacer] = None
         try:
-            storage = url_to_storage_plugin(self.path)
-            if self._peer_ctx is not None:
-                storage = self._peer_ctx.wrap(storage)
-            read_reqs = [
-                r for plan in self._plans.values() for r in plan.read_reqs
-            ]
-            bytes_needed = sum(_req_needed_bytes(r) for r in read_reqs)
-            if knobs.is_batching_enabled():
-                from .batcher import batch_read_requests
-
-                read_reqs = batch_read_requests(read_reqs)
-            checksum_table = _get_checksum_table_impl(
-                self._world_size, storage, event_loop
-            )
-            # Streaming placement across every plan: leaves whose reads
-            # completed device_put in rolling batches while later reads
-            # are still draining.
-            placer = _StreamingPlacer()
-            for plan in self._plans.values():
-                placer.register_plan(plan)
-            placer.lease_destinations(read_reqs, self._memory_budget_bytes)
-            fanout_ctx = self._fanout_ctx
-            self._pipeline_telemetry = sync_execute_read_reqs(
-                read_reqs=read_reqs,
-                storage=(
-                    fanout_ctx.wrap(storage)
-                    if fanout_ctx is not None
-                    else storage
-                ),
-                memory_budget_bytes=self._memory_budget_bytes,
-                rank=self._rank,
-                event_loop=event_loop,
-                checksum_table=checksum_table,
-                on_req_complete=placer.on_req_complete,
-                progress=self._progress_tracker,
-                classify_read=(
-                    fanout_ctx.classify_read
-                    if fanout_ctx is not None
-                    else None
-                ),
-                destinations=placer.leases,
-            )
-            self._pipeline_telemetry["bytes_needed"] = bytes_needed
-            placer.report_destinations(self._pipeline_telemetry)
-            _merge_fanout_telemetry(self._pipeline_telemetry, fanout_ctx)
-            _merge_peer_telemetry(self._pipeline_telemetry, self._peer_ctx)
-            placer.flush()
-            # Whatever didn't stream (flush disabled, zero-read leaves)
-            # places in one final batched device_put spanning all plans
-            # (per-leaf dispatch latency × hundreds of leaves is real
-            # cold-start time).
-            placement = _PlacementBatch()
-            for plan in self._plans.values():
-                plan.finish_reads(placement)
-            placement.run()
+            op.read_plans(list(self._plans.values()), apply=False)
             _settle_destinations()
-            event_loop.run_until_complete(storage.close())
+            op.close_storage()
         except BaseException as e:  # noqa: BLE001 - must propagate via wait()
             self._exc_info = e
             logger.error("Async restore failed: %r", e)
-            if placer is not None:
-                placer.abandon_destinations()
         finally:
             # Release the exchanged shard bytes whether or not the reads
             # succeeded; the handle may outlive the restore.
-            if self._fanout_ctx is not None:
-                self._fanout_ctx.clear()
-            self._progress_tracker.finish(self._exc_info)
+            if op.fanout_ctx is not None:
+                op.fanout_ctx.clear()
             _tracing.end(reads_span)
-            event_loop.close()
+            op.settle(self._exc_info)
             self._done.set()
-
-    def _key_barrier(self, i: int) -> Optional[StoreBarrier]:
-        if self._restore_nonce is None:
-            return None
-        return _nonce_barrier(
-            f"__restore/{self._restore_nonce}/{i}", self._pg
-        )
 
     def wait(self) -> None:
         """Block until reads finish, then apply the state dicts. Must be
@@ -2338,6 +2122,7 @@ class PendingRestore:
         seconds (no commit-style retry — a failed distributed restore is
         fatal to the job, not recoverable per-rank)."""
         self._thread.join()
+        op = self._op
         if self._exc_info is not None:
             # State was never applied; the read buffers are useless.
             # Release them before raising (the handle may be kept for
@@ -2345,22 +2130,20 @@ class PendingRestore:
             # reads succeeded are waiting at the FIRST apply barrier —
             # tell them before raising.
             self._plans = {}
-            first = self._key_barrier(0) if self._keys else None
+            first = op.barrier(0) if self._keys else None
             with _reporting_to(first, "restore-read"):
                 raise self._exc_info
         if self._applied:
             return
-        # One barrier per gathered KEY, plan or no plan: different ranks
-        # may hold plans for different keys (per-rank statefuls, elastic
-        # world-size changes), and a per-plan barrier count would diverge
-        # and deadlock. Mirrors the sync path (restore(): barrier after
-        # every key, whether or not this rank loaded it). The RNG plan is
-        # skipped here — its key is rank-local knowledge, so it must not
-        # perturb the shared schedule — and applied after all barriers
-        # (RngState application is collective-free), the sync path's
-        # restore-RNG-last invariant.
+        # One barrier per gathered KEY, plan or no plan, as in restore():
+        # different ranks may hold plans for different keys (per-rank
+        # statefuls, elastic world-size changes), and a per-plan barrier
+        # count would diverge and deadlock. The RNG plan is skipped here
+        # — its key is rank-local knowledge, so it must not perturb the
+        # shared schedule — and applied after all barriers (RngState
+        # application is collective-free): the restore-RNG-last invariant.
         for i, key in enumerate(self._keys):
-            barrier = self._key_barrier(i)
+            barrier = op.barrier(i)
             with _reporting_to(barrier, "restore-apply"):
                 plan = self._plans.get(key)
                 if plan is not None and key != self._rng_key:
@@ -2370,8 +2153,6 @@ class PendingRestore:
             if barrier is not None:
                 barrier.arrive()
                 barrier.depart()
-            else:
-                self._pg.barrier()
         rng_plan = self._plans.get(self._rng_key) if self._rng_key else None
         if rng_plan is not None:
             self._apply(rng_plan)
@@ -2379,28 +2160,16 @@ class PendingRestore:
         # handle un-applied, so a retried wait() re-applies from the start
         # (deterministic) instead of silently succeeding half-restored.
         self._applied = True
-        # Local report only (nonce=None -> no cross-rank gather): wait()
-        # call times are application-controlled, and the emission must
-        # not add a rendezvous of its own to the apply schedule.
-        _emit_snapshot_report(
-            kind="async_restore",
-            path=self.path,
-            pg_wrapper=self._pg,
-            pipeline=self._pipeline_telemetry,
-            counter_baseline=self._counter_baseline,
-            nonce=None,
-            trace_mark=self._trace_mark,
-            tunables=self._tunables,
-            trace_op=self.trace_op,
-        )
+        # Local report only (no cross-rank gather): wait() call times are
+        # application-controlled, and the emission must not add a
+        # rendezvous of its own to the apply schedule.
+        op.report(nonce=None)
         # Release the checkpoint-sized host buffers the plans hold; the
         # handle itself may outlive the restore (done()-polling callers).
         self._plans = {}
 
     def _apply(self, plan: _StatefulLoadPlan) -> None:
-        with _op_scope(self.trace_op), trace_annotation(
-            telemetry.names.SPAN_RESTORE_APPLY, stateful=plan.key
-        ):
+        with _op_scope(self.trace_op):
             plan.apply()
 
     def done(self) -> bool:
