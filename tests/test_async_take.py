@@ -484,3 +484,83 @@ def test_async_take_distributed_commit(pg) -> None:
     fresh = {"prog": ts.StateDict(rank=-1)}
     snapshot.restore(fresh)
     assert fresh["prog"]["rank"] == pg.rank
+
+
+
+# ---------------------------------------------------------------------------
+# The staging window of a device-snapshot drain comes from the plan (PR 32)
+# ---------------------------------------------------------------------------
+
+
+def _last_async_report(path: str) -> dict:
+    import dataclasses
+
+    from torchsnapshot_tpu import telemetry
+
+    return dataclasses.asdict(telemetry.last_report("async_take", path=path))
+
+
+def _roundtrip_ok(path: str, tree: dict) -> None:
+    restored = {"p": ts.PyTreeState({k: jnp.zeros_like(v) for k, v in tree.items()})}
+    ts.Snapshot(path).restore(restored)
+    for k, v in tree.items():
+        np.testing.assert_array_equal(np.asarray(restored["p"].tree[k]), np.asarray(v))
+
+
+def test_async_take_drains_through_a_window_derived_from_its_plan(tmp_path) -> None:
+    from torchsnapshot_tpu import knobs
+
+    tree = {f"w{i}": jnp.full((64, 64), i, jnp.float32) for i in range(6)}
+    with knobs.enable_telemetry():
+        ts.Snapshot.async_take(str(tmp_path), {"p": ts.PyTreeState(tree)}).wait()
+    report = _last_async_report(str(tmp_path))
+    pool = report["staging_pool"]
+    assert pool["chosen"] == "derived"
+    # Six equal leaves: 32 requests of the plan's mean size is more than
+    # the plan, so the window is the plan's bytes.
+    assert pool["capacity_bytes"] == 6 * 64 * 64 * 4
+    assert report["peak_staged_bytes"] <= pool["capacity_bytes"]
+    _roundtrip_ok(str(tmp_path), tree)
+
+
+@pytest.mark.parametrize("slab_bytes, slabs", [(20000, 2), (4096, 3)])
+def test_async_take_env_pinned_pool_is_honoured_to_the_byte(
+    tmp_path, slab_bytes, slabs
+) -> None:
+    """The operator's two variables give the pool they gave before: the
+    drain never holds more than slabs x slab_bytes (a leaf larger than
+    that is admitted alone), and the take commits bit-identically."""
+    from torchsnapshot_tpu import knobs
+
+    tree = {f"w{i}": jnp.full((64, 64), i, jnp.float32) for i in range(6)}
+    with knobs.enable_telemetry(), knobs.override_staging_pool_slab_bytes(
+        slab_bytes
+    ), knobs.override_staging_pool_slabs(slabs):
+        ts.Snapshot.async_take(str(tmp_path), {"p": ts.PyTreeState(tree)}).wait()
+    report = _last_async_report(str(tmp_path))
+    assert report["staging_pool"] == {
+        "capacity_bytes": slab_bytes * slabs,
+        "slab_bytes": slab_bytes,
+        "slabs": slabs,
+        "chosen": "env",
+    }
+    leaf = 64 * 64 * 4
+    assert report["peak_staged_bytes"] <= max(slab_bytes * slabs, leaf)
+    _roundtrip_ok(str(tmp_path), tree)
+
+
+def test_async_take_tuner_override_cannot_shrink_the_window(tmp_path) -> None:
+    from torchsnapshot_tpu import knobs
+
+    tree = {f"w{i}": jnp.full((64, 64), i, jnp.float32) for i in range(6)}
+    try:
+        knobs.set_tuner_override(knobs._STAGING_POOL_SLAB_BYTES_ENV, 1024)
+        knobs.set_tuner_override(knobs._STAGING_POOL_SLABS_ENV, 2)
+        with knobs.enable_telemetry():
+            ts.Snapshot.async_take(str(tmp_path), {"p": ts.PyTreeState(tree)}).wait()
+    finally:
+        knobs.clear_tuner_override(knobs._STAGING_POOL_SLAB_BYTES_ENV)
+        knobs.clear_tuner_override(knobs._STAGING_POOL_SLABS_ENV)
+    pool = _last_async_report(str(tmp_path))["staging_pool"]
+    assert (pool["chosen"], pool["capacity_bytes"]) == ("derived", 6 * 64 * 64 * 4)
+    _roundtrip_ok(str(tmp_path), tree)

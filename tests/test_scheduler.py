@@ -298,6 +298,7 @@ def test_staging_pool_capacity_is_slab_bounded() -> None:
         "capacity_bytes": 200,
         "slab_bytes": 100,
         "slabs": 2,
+        "chosen": "caller",
     }
     # ...but never above the process budget it is accounted against.
     clamped = StagingPool(150, slab_bytes=100, slabs=2)
@@ -382,6 +383,254 @@ def test_deferred_io_work_runs_pipeline_and_fires_on_staged() -> None:
     telemetry = work.pipeline_telemetry()
     assert telemetry["blobs"] == 12
     assert "staging" in telemetry["phases"]
+
+
+# ---------------------------------------------------------------------------
+# The staging window: the pool's capacity comes from the plan (PR 32)
+# ---------------------------------------------------------------------------
+
+MIB = 1 << 20
+GIB = 1 << 30
+
+
+class WindowStager(TrackingStager):
+    """Records, on a shared log, the order of stages, what the budget had
+    reserved when its stage began, and the high-water mark of bytes whose
+    stage has begun and whose write has not finished."""
+
+    def __init__(self, payload: bytes, log: list, pool=None, fail: bool = False):
+        super().__init__(payload)
+        self.log, self.pool, self.fail = log, pool, fail
+
+    started = 0
+    peak_started = 0
+
+    async def stage_buffer(self, executor=None):
+        cls = WindowStager
+        cls.started += len(self.payload)
+        cls.peak_started = max(cls.peak_started, cls.started)
+        reserved = (
+            self.pool.total_bytes - self.pool.available_bytes
+            if self.pool is not None
+            else None
+        )
+        self.log.append(("stage", self.payload[:1], reserved))
+        if self.fail:
+            raise RuntimeError("injected stage failure")
+        return await super().stage_buffer(executor)
+
+
+class WindowStorage(SlowStorage):
+    """A write that has finished gives its bytes back to WindowStager's
+    count, before the pipeline releases them to the pool."""
+
+    async def write(self, write_io: WriteIO) -> None:
+        await super().write(write_io)
+        WindowStager.started -= len(write_io.buf)
+
+
+def _run_window(reqs, pool, storage=None):
+    from torchsnapshot_tpu.scheduler import execute_write_reqs
+
+    WindowStager.started = WindowStager.peak_started = 0
+    storage = storage or WindowStorage(delay=0.001)
+    loop = asyncio.new_event_loop()
+    try:
+        pending = loop.run_until_complete(
+            execute_write_reqs(reqs, storage, 10**9, rank=0, staging_pool=pool)
+        )
+        pending.sync_complete(loop)
+    finally:
+        loop.close()
+    return storage
+
+
+def test_a_request_stages_once_and_only_after_admission() -> None:
+    from torchsnapshot_tpu.scheduler import StagingPool
+
+    log: list = []
+    pool = StagingPool(10**9, slab_bytes=100, slabs=2)
+    reqs = [
+        WriteReq(
+            path=f"w/{i}",
+            buffer_stager=WindowStager(bytes([i]) * 100, log, pool),
+        )
+        for i in range(8)
+    ]
+    storage = _run_window(reqs, pool)
+    assert len(storage.blobs) == 8
+    assert sorted(e[1] for e in log) == [bytes([i]) for i in range(8)]
+    # After admission: the request's own bytes were reserved already, and
+    # never more than the pool holds.
+    assert all(100 <= e[2] <= 200 for e in log)
+
+
+def test_bytes_in_flight_never_exceed_the_pool() -> None:
+    """20 x 100 B through a pool of two requests: the bytes whose stage
+    has begun and whose write has not finished stay within the pool."""
+    from torchsnapshot_tpu.scheduler import StagingPool
+
+    log: list = []
+    pool = StagingPool(10**9, slab_bytes=100, slabs=2)
+    reqs = [
+        WriteReq(path=f"f/{i}", buffer_stager=WindowStager(b"x" * 100, log, pool))
+        for i in range(20)
+    ]
+    storage = _run_window(reqs, pool)
+    assert len(storage.blobs) == 20
+    assert 100 <= WindowStager.peak_started <= 200
+    assert pool.peak_reserved_bytes <= 200
+    assert WindowStager.started == 0
+
+
+def _train_state_leaves(d: int, ff: int, vocab: int, layers: int) -> list:
+    """Bytes of a bf16 train state's array leaves (parameters and both Adam
+    moments), as chipbench's configurations make them."""
+    layer = [d * ff, ff * d, d * d, d * 3 * d, d, d]
+    return [2 * n for n in layer * layers + [vocab * d, d * vocab, d]] * 3
+
+
+NEOX_L2 = _train_state_leaves(4096, 16384, 50432, 2)  # 45 leaves, 4.56 GiB
+PYTHIA_1B = _train_state_leaves(2048, 8192, 50304, 16)  # 297 leaves, 5.65 GiB
+
+
+@pytest.mark.parametrize(
+    "plan, budget, expected",
+    [
+        pytest.param([10 * GIB], 64 * GIB, 10 * GIB, id="one-huge-leaf"),
+        pytest.param(
+            NEOX_L2, 64 * GIB, 32 * sum(NEOX_L2) // len(NEOX_L2), id="47-large"
+        ),
+        pytest.param(
+            PYTHIA_1B,
+            64 * GIB,
+            32 * sum(PYTHIA_1B) // len(PYTHIA_1B),
+            id="299-small",
+        ),
+        pytest.param([], 64 * GIB, 0, id="empty-plan"),
+        pytest.param(NEOX_L2, 1 * GIB, 1 * GIB, id="larger-than-the-budget"),
+        pytest.param(
+            [394 * MIB, 394 * MIB] + [8192] * 200,
+            64 * GIB,
+            788 * MIB,
+            id="two-largest-together",
+        ),
+        pytest.param([MIB] * 4, 64 * GIB, 4 * MIB, id="never-above-the-plan"),
+    ],
+)
+def test_derived_staging_window(plan, budget, expected) -> None:
+    from torchsnapshot_tpu.scheduler import (
+        StagingPool,
+        derived_staging_window_bytes,
+    )
+
+    assert derived_staging_window_bytes(plan, budget) == expected
+    pool = StagingPool(budget, request_bytes=plan)
+    assert pool.total_bytes == max(1, expected)
+    assert pool.geometry()["chosen"] == "derived"
+    if plan:
+        # A leaf's write can overlap the next leaf's transfer.
+        assert pool.total_bytes >= min(budget, sum(sorted(plan)[-2:]))
+        assert pool.total_bytes <= sum(plan)
+
+
+@pytest.mark.parametrize(
+    "slab_bytes, slabs, capacity",
+    [(1000, 3, 3000), (1000, None, 2000), (None, 5, 5 * 128 * MIB)],
+)
+def test_env_pinned_pool_geometry_is_honoured_to_the_byte(
+    slab_bytes, slabs, capacity
+) -> None:
+    import contextlib
+
+    from torchsnapshot_tpu import knobs
+    from torchsnapshot_tpu.scheduler import StagingPool
+
+    with contextlib.ExitStack() as stack:
+        if slab_bytes is not None:
+            stack.enter_context(knobs.override_staging_pool_slab_bytes(slab_bytes))
+        if slabs is not None:
+            stack.enter_context(knobs.override_staging_pool_slabs(slabs))
+        pool = StagingPool(64 * GIB, request_bytes=NEOX_L2)
+        # ... as StagingPool(budget) gave before there was a plan to read.
+        assert pool.total_bytes == StagingPool(64 * GIB).total_bytes == capacity
+    assert pool.geometry() == {
+        "capacity_bytes": capacity,
+        "slab_bytes": slab_bytes or 128 * MIB,
+        "slabs": slabs or 2,
+        "chosen": "env",
+    }
+
+
+def test_tuner_override_raises_the_window_and_cannot_lower_it() -> None:
+    from torchsnapshot_tpu import knobs
+    from torchsnapshot_tpu.scheduler import (
+        StagingPool,
+        derived_staging_window_bytes,
+    )
+
+    window = derived_staging_window_bytes(NEOX_L2, 64 * GIB)
+    try:
+        # The tuner's first +1 move from the defaults: 256 MiB x 2.
+        knobs.set_tuner_override(knobs._STAGING_POOL_SLAB_BYTES_ENV, 256 * MIB)
+        pool = StagingPool(64 * GIB, request_bytes=NEOX_L2)
+        assert (pool.total_bytes, pool.chosen) == (window, "derived")
+        knobs.set_tuner_override(knobs._STAGING_POOL_SLAB_BYTES_ENV, 16 * MIB)
+        assert StagingPool(64 * GIB, request_bytes=NEOX_L2).total_bytes == window
+        knobs.set_tuner_override(knobs._STAGING_POOL_SLAB_BYTES_ENV, GIB)
+        knobs.set_tuner_override(knobs._STAGING_POOL_SLABS_ENV, 8)
+        pool = StagingPool(64 * GIB, request_bytes=NEOX_L2)
+        assert (pool.total_bytes, pool.chosen) == (8 * GIB, "tuner")
+        # The process budget clamps that too.
+        assert StagingPool(2 * GIB, request_bytes=NEOX_L2).total_bytes == 2 * GIB
+    finally:
+        knobs.clear_tuner_override(knobs._STAGING_POOL_SLAB_BYTES_ENV)
+        knobs.clear_tuner_override(knobs._STAGING_POOL_SLABS_ENV)
+    assert StagingPool(64 * GIB, request_bytes=NEOX_L2).chosen == "derived"
+
+
+def test_failed_stage_releases_its_bytes() -> None:
+    from torchsnapshot_tpu.scheduler import StagingPool
+
+    log: list = []
+    pool = StagingPool(10**9, slab_bytes=100, slabs=2)
+    reqs = [
+        WriteReq(
+            path=f"x/{i}",
+            buffer_stager=WindowStager(b"z" * 100, log, pool, fail=(i == 3)),
+        )
+        for i in range(8)
+    ]
+    with pytest.raises(RuntimeError, match="injected stage failure"):
+        _run_window(reqs, pool)
+    assert pool.available_bytes == pool.total_bytes == 200
+    assert pool.inflight == 0
+
+
+def test_deferred_io_work_sizes_its_pool_from_the_plan() -> None:
+    from torchsnapshot_tpu.scheduler import DeferredIOWork
+
+    log: list = []
+    reqs = [
+        WriteReq(path=f"p/{i}", buffer_stager=WindowStager(bytes([i]) * 64, log))
+        for i in range(40)
+    ]
+    work = DeferredIOWork(
+        write_reqs=reqs,
+        storage=WindowStorage(delay=0.0),
+        memory_budget_bytes=10**9,
+        rank=0,
+    )
+    WindowStager.started = WindowStager.peak_started = 0
+    loop = asyncio.new_event_loop()
+    work.sync_complete(loop)
+    loop.close()
+    pool = work.pipeline_telemetry()["staging_pool"]
+    assert pool["chosen"] == "derived"
+    # 16 transfers + 16 I/O slots, in requests of this plan's size.
+    assert pool["capacity_bytes"] == 32 * 64
+    assert WindowStager.peak_started <= 32 * 64
+    assert len(log) == 40
 
 
 # ---------------------------------------------------------------------------

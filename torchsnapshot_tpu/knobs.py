@@ -468,20 +468,36 @@ def is_async_device_snapshot_enabled() -> bool:
 
 def get_staging_pool_slab_bytes() -> int:
     """Slab size of the background drain's host staging pool
-    (scheduler.StagingPool). Together with the slab count this bounds
-    the deferred async take's host staging footprint; the pool never
-    exceeds the process memory budget it is accounted against."""
+    (scheduler.StagingPool). Set in the environment, slab size x slab
+    count pins the deferred async take's staging window; unset, the
+    pool derives its window from the plan and the autotuner's override
+    of this knob can only raise it. The pool never exceeds the process
+    memory budget it is accounted against."""
     return _get_tunable_int(
         _STAGING_POOL_SLAB_BYTES_ENV, _DEFAULT_STAGING_POOL_SLAB_BYTES
     )
 
 
 def get_staging_pool_slabs() -> int:
-    """Slab count of the background drain's host staging pool. The
-    default of 2 is classic double buffering: one slab's worth of
-    requests stages (D2H + serialize) while the previous slab's worth
-    drains to storage."""
+    """Slab count of the background drain's host staging pool: with
+    the slab size, the geometry an operator pins (see
+    :func:`get_staging_pool_slab_bytes`)."""
     return _get_tunable_int(_STAGING_POOL_SLABS_ENV, _DEFAULT_STAGING_POOL_SLABS)
+
+
+def staging_pool_geometry_source() -> Optional[str]:
+    """Who, if anyone, set the staging pool's two knobs: ``"env"`` when
+    the operator set either variable (the geometry is then pinned to
+    the byte), ``"tuner"`` when only the autotuner's override of either
+    is installed, None when both read their defaults (the pool then
+    sizes its window from the plan)."""
+    names = (_STAGING_POOL_SLAB_BYTES_ENV, _STAGING_POOL_SLABS_ENV)
+    if any(os.environ.get(n) is not None for n in names):
+        return "env"
+    with _TUNER_OVERRIDES_LOCK:
+        if any(n in _TUNER_OVERRIDES for n in names):
+            return "tuner"
+    return None
 
 
 def get_async_visible_budget_seconds() -> float:

@@ -19,20 +19,22 @@ has finished for every request (scheduler.py:224-234): from then on the
 application may mutate/free device arrays while storage I/O drains in the
 background. Device-snapshot async takes go further: :class:`DeferredIOWork`
 defers the WHOLE pipeline to the background commit thread, running it
-through a :class:`StagingPool` (a slab-bounded admission controller) so
-host staging memory never scales with checkpoint size — the training-
+through a :class:`StagingPool` (an admission controller whose window is
+sized from the plan, or pinned by the operator) so host staging memory is
+bounded by that window, not by the checkpoint's size — the training-
 visible span ends at capture, before any staging ran (docs/async.md).
 """
 
 from __future__ import annotations
 
 import asyncio
+import heapq
 import logging
 import math
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import TYPE_CHECKING, Callable, List, Optional
+from typing import TYPE_CHECKING, Callable, List, Optional, Sequence
 
 import psutil
 
@@ -197,20 +199,59 @@ class MemoryBudget:
             self._cond.notify_all()
 
 
+# Device-to-host transfers the window leaves room for: with a train loop
+# beside it the host side of the link stops gaining between 8 and 16 in
+# flight (chipbench/probe_d2h_depth.py; PERF.md section 6, PR 32), which
+# is as far as raising ``staging_threads`` can pay.
+LINK_TRANSFERS_IN_FLIGHT = 16
+
+
+def derived_staging_window_bytes(
+    request_bytes: Sequence[int], memory_budget_bytes: int
+) -> int:
+    """The staging window a plan asks for. A request holds its bytes from
+    admission until its write has finished, so the window is the
+    transfers the link can use in flight plus the writes that may run
+    at once (the I/O slots), counted in requests of the plan's own mean
+    size: few large leaves and many small ones get the same depth, not
+    the same bytes. Never below the two largest requests together (a
+    leaf's write overlaps the next leaf's transfer), never above the
+    plan's bytes or the process budget."""
+    if not request_bytes:
+        return 0
+    total = sum(request_bytes)
+    depth = LINK_TRANSFERS_IN_FLIGHT + knobs.get_per_rank_io_concurrency()
+    window = max(
+        depth * total // len(request_bytes),
+        sum(heapq.nlargest(2, request_bytes)),
+    )
+    return min(window, total, memory_budget_bytes)
+
+
 class StagingPool(MemoryBudget):
-    """Double-buffered host staging pool for background D2H drains.
+    """Host staging window of a background D2H drain.
 
     A device-snapshot async take runs its whole staging pipeline on the
     background commit thread; this pool is that pipeline's admission
-    controller. Capacity is ``slabs x slab_bytes`` (knob-set; default
-    2 x 128 MiB — classic double buffering: one slab's worth of
-    requests stages D2H while the previous slab's worth drains to
-    storage), clamped to the process memory budget it is accounted
-    against — so a 1 GiB checkpoint drains through ~256 MiB of host
-    headroom instead of materializing entirely. Inherits the
-    idle-admission escape hatch: a single request larger than the whole
-    pool is admitted alone (it serializes instead of deadlocking), and
-    all of MemoryBudget's wait/peak telemetry.
+    controller. What it admits is what is in flight between the device
+    and storage: a request's bytes are reserved from admission, before
+    its device-to-host transfer, until its write has finished, so the
+    window has to hold the transfers and the writes that run at once or
+    the two take turns. ``chosen`` says where the capacity came from:
+
+    - ``derived``: from the plan's ``request_bytes``
+      (:func:`derived_staging_window_bytes`), the default;
+    - ``env``: ``TORCHSNAPSHOT_TPU_STAGING_POOL_SLAB_BYTES`` / ``_SLABS``
+      set by the operator pin ``slabs x slab_bytes`` exactly (the way to
+      cap host memory);
+    - ``tuner``: the autotuner's override of the two asks for more than
+      the plan does; it can raise the window, never shrink it;
+    - ``caller``: ``slab_bytes`` / ``slabs`` passed in, or no plan given.
+
+    Always clamped to the process memory budget it is accounted against.
+    Inherits the idle-admission escape hatch: a single request larger
+    than the whole pool is admitted alone (it serializes instead of
+    deadlocking), and all of MemoryBudget's wait/peak telemetry.
     """
 
     def __init__(
@@ -218,7 +259,11 @@ class StagingPool(MemoryBudget):
         memory_budget_bytes: int,
         slab_bytes: Optional[int] = None,
         slabs: Optional[int] = None,
+        request_bytes: Optional[Sequence[int]] = None,
     ) -> None:
+        by_hand = (
+            slab_bytes is not None or slabs is not None or request_bytes is None
+        )
         self.slab_bytes = (
             slab_bytes
             if slab_bytes is not None
@@ -228,15 +273,23 @@ class StagingPool(MemoryBudget):
             slabs if slabs is not None else knobs.get_staging_pool_slabs()
         )
         self.memory_budget_bytes = memory_budget_bytes
-        super().__init__(
-            min(memory_budget_bytes, max(1, self.slab_bytes * self.slabs))
-        )
+        capacity = self.slab_bytes * self.slabs
+        chosen = "caller" if by_hand else knobs.staging_pool_geometry_source()
+        if chosen in (None, "tuner"):
+            window = derived_staging_window_bytes(
+                request_bytes, memory_budget_bytes
+            )
+            if chosen is None or window >= capacity:
+                chosen, capacity = "derived", window
+        self.chosen: str = chosen
+        super().__init__(min(memory_budget_bytes, max(1, capacity)))
 
     def geometry(self) -> dict:
         return {
             "capacity_bytes": self.total_bytes,
             "slab_bytes": self.slab_bytes,
             "slabs": self.slabs,
+            "chosen": self.chosen,
         }
 
 
@@ -728,9 +781,10 @@ class DeferredIOWork:
     capture pass (on-device clones dispatched, mutable host leaves
     copied) and returns; the background commit thread then calls
     ``sync_complete``, which runs the WHOLE pipeline: staging (D2H +
-    serialize) through a :class:`StagingPool` so host memory stays
-    slab-bounded, overlapped with the storage writes by the ordinary
-    stage/write machinery of :func:`execute_write_reqs`.
+    serialize) through a :class:`StagingPool` sized from these write
+    requests so host memory stays bounded, overlapped with the storage
+    writes by the ordinary stage/write machinery of
+    :func:`execute_write_reqs`.
 
     Mirrors :class:`PendingIOWork`'s surface (``sync_complete`` /
     ``finalize_checksums`` / ``checksums`` / ``checksum_finalizer`` /
@@ -762,7 +816,13 @@ class DeferredIOWork:
         self._inner: Optional[PendingIOWork] = None
 
     def sync_complete(self, event_loop: asyncio.AbstractEventLoop) -> None:
-        pool = StagingPool(self._memory_budget_bytes)
+        pool = StagingPool(
+            self._memory_budget_bytes,
+            request_bytes=[
+                r.buffer_stager.get_staging_cost_bytes()
+                for r in self.write_reqs
+            ],
+        )
         inner = event_loop.run_until_complete(
             execute_write_reqs(
                 write_reqs=self.write_reqs,

@@ -494,9 +494,9 @@ class Snapshot:
         write plan needs (dispatched, not awaited), host copies of mutable
         numpy leaves — and the ENTIRE staging (D2H + serialize) plus
         storage drain and commit run on a background thread through a
-        slab-bounded host staging pool (``scheduler.StagingPool``). The
-        application may mutate, donate, or delete the live arrays freely
-        once this returns. ``PendingSnapshot.wait(phase=)`` distinguishes
+        host staging pool bounded by a window sized from the plan
+        (``scheduler.StagingPool``). The application may mutate, donate,
+        or delete the live arrays freely once this returns. ``PendingSnapshot.wait(phase=)`` distinguishes
         the ``"staged"`` point (D2H done; host buffers hold the bytes)
         from the default ``"committed"`` barrier.
 

@@ -120,10 +120,12 @@ class SnapshotReport:
     visible_s: Optional[float] = None
     staged_s: Optional[float] = None
     # Device-snapshot drains only: the StagingPool geometry
-    # ({capacity_bytes, slab_bytes, slabs}) that bounded this
+    # ({capacity_bytes, slab_bytes, slabs, chosen}) that bounded this
     # pipeline's host staging — the context an operator needs to read
     # peak_staged_bytes / budget_wait_s on a pool-bounded drain.
-    staging_pool: Optional[Dict[str, int]] = None
+    # ``chosen`` says where capacity_bytes came from: derived (from the
+    # plan), env (the two pool variables), tuner or caller.
+    staging_pool: Optional[Dict[str, Any]] = None
     # Restore pipelines only (None elsewhere): the read-amplification
     # triple. ``bytes_needed`` is what this rank's read plan had to fill
     # (pre-batching consuming costs); ``bytes_fetched`` is what it
