@@ -56,6 +56,8 @@ from .manifest import (
 )
 from .ops import device_digest as dd
 from .serialization import Serializer, dtype_to_string
+from .telemetry import names as metric_names
+from .utils.tracing import trace_annotation
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -117,11 +119,15 @@ class LeafIncrementalPlan:
         refs: Dict[ChunkKey, Tuple[ArrayEntry, str]],
         digests: Dict[ChunkKey, str],
         on_ref_used: Callable[[str, str], None],
+        on_decision: Callable[[bool, Tuple[int, ...]], None],
     ) -> None:
         # refs: chunk key -> (ref entry template, base-manifest location)
         self._refs = refs
         self._digests = digests
         self._on_ref_used = on_ref_used
+        # (referenced, the chunk's sizes): a preparer asks ``ref_entry``
+        # of every chunk and ``digest_for`` of those it goes on to write.
+        self._on_decision = on_decision
 
     def ref_entry(
         self,
@@ -143,6 +149,7 @@ class LeafIncrementalPlan:
             digest=template.digest,
         )
         self._on_ref_used(clone.location, base_location)
+        self._on_decision(True, tuple(sizes))
         return clone
 
     def digest_for(
@@ -150,6 +157,7 @@ class LeafIncrementalPlan:
         offsets: Tuple[int, ...] | List[int],
         sizes: Tuple[int, ...] | List[int],
     ) -> Optional[str]:
+        self._on_decision(False, tuple(sizes))
         return self._digests.get((tuple(offsets), tuple(sizes)))
 
 
@@ -163,6 +171,23 @@ class _DigestBatch:
         self.specs: List[Tuple[Any, Optional[Tuple[Tuple[int, int], ...]]]] = []
         # Output-row mapping: one (logical_path, chunk_key) per digest row.
         self.rows: List[Tuple[str, ChunkKey]] = []
+        # Bytes of the rows' chunks: what the program reads.
+        self.nbytes = 0
+
+    def add(
+        self,
+        arr: Any,
+        ranges: Optional[Tuple[Tuple[int, int], ...]],
+        logical_path: str,
+        keys: List[ChunkKey],
+    ) -> None:
+        self.specs.append((arr, ranges))
+        self.rows.extend((logical_path, k) for k in keys)
+        self.nbytes += sum(_chunk_nbytes(sizes, arr.dtype) for _, sizes in keys)
+
+
+def _chunk_nbytes(sizes: Tuple[int, ...], dtype: Any) -> int:
+    return int(np.prod(sizes, dtype=np.int64)) * np.dtype(dtype).itemsize
 
 
 def _base_chunk_map(entry: Entry) -> Dict[ChunkKey, ArrayEntry]:
@@ -206,9 +231,23 @@ class IncrementalTakeContext:
         # (logical_path, chunk_key) -> (d1, d2); host digests land here at
         # launch, device digests at first plan_for (materialization).
         self._results: Dict[Tuple[str, ChunkKey], Tuple[int, int]] = {}
-        # In-flight device groups: (future, output-row mapping).
-        self._group_futs: List[Tuple[Any, List[Tuple[str, ChunkKey]]]] = []
+        # In-flight device groups: (future, output-row mapping, bytes of
+        # the rows' chunks).
+        self._group_futs: List[
+            Tuple[Any, List[Tuple[str, ChunkKey]], int]
+        ] = []
         self._materialized = False
+        # Bytes of the leaves digested on the host at launch.
+        self._host_bytes = 0
+        # What the preparers decided chunk by chunk, on this rank and
+        # before partitioning: a chunk of a digested leaf is referenced
+        # into the base or written (docs/incremental.md).
+        self.decisions: Dict[str, int] = {
+            "chunks_referenced": 0,
+            "bytes_referenced": 0,
+            "chunks_written": 0,
+            "bytes_written": 0,
+        }
         self._current_leaves: Dict[str, Any] = {}
         self._replicated_paths: Set[str] = set()
         # new (normalized) ref location -> base-manifest location
@@ -229,6 +268,21 @@ class IncrementalTakeContext:
         base whose location can't be referenced relatively) yields a
         digest-record-only context — the take writes everything but its
         manifest can serve as a base for the next one."""
+        with trace_annotation(metric_names.SPAN_INCREMENTAL_BASE) as span:
+            ctx = cls._build(path, incremental_base, rank)
+            span.annotate(
+                entries=len(ctx._base_available),
+                usable=int(ctx._ref_prefix is not None),
+            )
+        return ctx
+
+    @classmethod
+    def _build(
+        cls,
+        path: str,
+        incremental_base: Optional[Any],
+        rank: int,
+    ) -> "IncrementalTakeContext":
         if incremental_base is None:
             return cls(None, None, None, 0)
         from .snapshot import Snapshot
@@ -281,6 +335,17 @@ class IncrementalTakeContext:
             # Written bytes are a function of the hook, not the leaf;
             # digests of the leaf would lie.
             return
+        with trace_annotation(metric_names.SPAN_INCREMENTAL_DIGEST_LAUNCH) as span:
+            self._launch(flattened)
+            span.annotate(
+                leaves=len(self._layouts),
+                chunks=sum(len(keys) for keys in self._layouts.values()),
+                bytes=sum(nbytes for _, _, nbytes in self._group_futs),
+                host_bytes=self._host_bytes,
+                programs=len(self._group_futs),
+            )
+
+    def _launch(self, flattened: Dict[str, Any]) -> None:
         # Device digest work batches per device group — one fused dispatch
         # per group instead of one round-trip per chunk.
         batches: Dict[Tuple[int, ...], _DigestBatch] = {}
@@ -310,7 +375,7 @@ class IncrementalTakeContext:
                 for p, _ in batch.rows:
                     self._layouts.pop(p, None)
                 continue
-            self._group_futs.append((fut, batch.rows))
+            self._group_futs.append((fut, batch.rows, batch.nbytes))
 
     @staticmethod
     def _device_group(arr: Any) -> Tuple[int, ...]:
@@ -364,10 +429,10 @@ class IncrementalTakeContext:
                 batch = batches.setdefault(
                     self._device_group(leaf), _DigestBatch()
                 )
-                batch.specs.append((leaf, tuple(ranges)))
-                batch.rows.extend((logical_path, k) for k in keys)
+                batch.add(leaf, tuple(ranges), logical_path, keys)
             else:
                 host = np.asarray(leaf)
+                self._host_bytes += host.nbytes
                 for (start, stop), key in zip(ranges, keys):
                     self._results[(logical_path, key)] = dd.digest_host(
                         host[start:stop]
@@ -379,12 +444,11 @@ class IncrementalTakeContext:
                 batch = batches.setdefault(
                     self._device_group(leaf), _DigestBatch()
                 )
-                batch.specs.append((leaf, None))
-                batch.rows.append((logical_path, key))
+                batch.add(leaf, None, logical_path, [key])
             else:
-                self._results[(logical_path, key)] = dd.digest_host(
-                    np.asarray(leaf)
-                )
+                host = np.asarray(leaf)
+                self._host_bytes += host.nbytes
+                self._results[(logical_path, key)] = dd.digest_host(host)
         self._layouts[logical_path] = keys
 
     def _collect_sharded(
@@ -415,10 +479,12 @@ class IncrementalTakeContext:
             batch = batches.setdefault(
                 self._device_group(dev_shard.data), _DigestBatch()
             )
-            batch.specs.append(
-                (dev_shard.data, None if whole else tuple(shard_ranges))
+            batch.add(
+                dev_shard.data,
+                None if whole else tuple(shard_ranges),
+                logical_path,
+                shard_keys,
             )
-            batch.rows.extend((logical_path, k) for k in shard_keys)
             keys.extend(shard_keys)
         if keys:
             self._layouts[logical_path] = keys
@@ -429,7 +495,14 @@ class IncrementalTakeContext:
         if self._materialized:
             return
         self._materialized = True
-        for fut, rows in self._group_futs:
+        with trace_annotation(
+            metric_names.SPAN_INCREMENTAL_DIGEST_WAIT,
+            chunks=sum(len(rows) for _, rows, _ in self._group_futs),
+        ):
+            self._materialize_groups()
+
+    def _materialize_groups(self) -> None:
+        for fut, rows, _ in self._group_futs:
             try:
                 values = dd.materialize_many(fut)
             except Exception as e:  # noqa: BLE001 - digest is an optimization
@@ -557,7 +630,14 @@ class IncrementalTakeContext:
         def on_ref_used(ref_location: str, base_location: str) -> None:
             self.used_refs[ref_location] = base_location
 
-        return LeafIncrementalPlan(refs, digests, on_ref_used)
+        dtype = self._current_leaves[logical_path].dtype
+
+        def on_decision(referenced: bool, sizes: Tuple[int, ...]) -> None:
+            kind = "referenced" if referenced else "written"
+            self.decisions[f"chunks_{kind}"] += 1
+            self.decisions[f"bytes_{kind}"] += _chunk_nbytes(sizes, dtype)
+
+        return LeafIncrementalPlan(refs, digests, on_ref_used, on_decision)
 
     def _is_replicated_dense(self, logical_path: str) -> bool:
         """The replicated flag the preparers will stamp on this leaf's

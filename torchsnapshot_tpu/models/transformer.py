@@ -75,6 +75,12 @@ class TransformerConfig:
     # per-step blockwise attention runs in the flash kernel (long context
     # without the O(seq_local^2) HBM intermediate either).
     attn_impl: str = "ulysses"
+    # > 0: a fine-tune over a frozen base (LoRA, arXiv:2106.09685). Every
+    # block gains a rank-r adapter pair on the fused qkv projection
+    # (scale alpha / r = 1), and the adapters are all that trains: every
+    # other leaf leaves a step as the bits it entered with, and the
+    # optimizer state holds moments for the adapters alone.
+    lora_rank: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -130,6 +136,9 @@ def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Params:
         else:
             block["w_in"] = ns("dp", "tp")  # (d_model, d_ff)
             block["w_out"] = ns("tp", "dp")  # (d_ff, d_model)
+        if cfg.lora_rank:
+            block["lora_a"] = ns("dp", None)  # (d_model, r)
+            block["lora_b"] = ns(None, "tp")  # (r, 3 * d_model)
         layers.append(block)
     return {
         # d_model over tp: the token gather is then local on every device
@@ -156,6 +165,10 @@ def init_params(
     def _init(rng: jax.Array) -> Params:
         n_keys = 3 + 5 * cfg.n_layers
         keys = iter(jax.random.split(rng, n_keys))
+        # The adapters draw from a stream of their own: the base keeps
+        # the key schedule, and so the weights, it has without them.
+        if cfg.lora_rank:
+            lora_keys = jax.random.split(jax.random.fold_in(rng, 1), cfg.n_layers)
 
         def dense(key: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
             fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
@@ -184,6 +197,11 @@ def init_params(
                 next(keys)  # keep key schedule layer-count-stable
                 block["w_in"] = dense(next(keys), (cfg.d_model, cfg.d_ff))
                 block["w_out"] = dense(next(keys), (cfg.d_ff, cfg.d_model))
+            if cfg.lora_rank:
+                block["lora_a"] = dense(lora_keys[i], (cfg.d_model, cfg.lora_rank))
+                block["lora_b"] = jnp.zeros(
+                    (cfg.lora_rank, 3 * cfg.d_model), dtype=cfg.dtype
+                )
             layers.append(block)
         return {
             "embed": dense(next(keys), (cfg.vocab_size, cfg.d_model)),
@@ -277,6 +295,12 @@ def forward(
     for i, block in enumerate(params["layers"]):
         h = _rmsnorm(x, block["ln1_scale"])
         qkv = jnp.einsum("bsd,dz->bsz", h, block["wqkv"])
+        if "lora_a" in block:
+            qkv = qkv + jnp.einsum(
+                "bsr,rz->bsz",
+                jnp.einsum("bsd,dr->bsr", h, block["lora_a"]),
+                block["lora_b"],
+            )
         qkv = qkv.reshape(b, s, 3, cfg.n_heads, cfg.head_dim)
         if cfg.attn_impl in ("ring", "ring_flash") and mesh is not None:
             # Sequence stays sp-sharded; KV blocks rotate the ring.
@@ -359,6 +383,37 @@ def _optimizer(cfg: TransformerConfig) -> optax.GradientTransformation:
     return optax.adamw(cfg.learning_rate)
 
 
+_ADAPTER_LEAVES = ("lora_a", "lora_b")
+
+
+def _trainable(cfg: TransformerConfig, params: Params) -> Params:
+    """The leaves the optimizer sees, of ``params`` or of any tree laid
+    out like it (its shardings): all of it, or with ``lora_rank`` the
+    adapters alone."""
+    if not cfg.lora_rank:
+        return params
+    return {
+        "layers": [
+            {k: block[k] for k in _ADAPTER_LEAVES} for block in params["layers"]
+        ]
+    }
+
+
+def _with_trainable(
+    cfg: TransformerConfig, params: Params, trainable: Params
+) -> Params:
+    """``params`` with its trainable leaves replaced. A frozen leaf is
+    the object that came in: through a donated step it goes untouched,
+    never as ``p + 0`` (which is not ``p`` for a negative zero)."""
+    if not cfg.lora_rank:
+        return trainable
+    layers = [
+        {**block, **adapters}
+        for block, adapters in zip(params["layers"], trainable["layers"])
+    ]
+    return {**params, "layers": layers}
+
+
 def init_train_state(
     cfg: TransformerConfig,
     seed: int = 0,
@@ -368,8 +423,9 @@ def init_train_state(
     params = init_params(cfg, rng, mesh=mesh)
     opt = _optimizer(cfg)
     step = jnp.zeros((), dtype=jnp.int32)
+    trainable = _trainable(cfg, params)
     if mesh is None:
-        opt_state = jax.jit(opt.init)(params)
+        opt_state = jax.jit(opt.init)(trainable)
     else:
         # Adam moments are zeros_like(params): no data flows from the
         # params, so sharding propagation has nothing to follow and a bare
@@ -382,11 +438,11 @@ def init_train_state(
         opt_shardings = optax.tree_map_params(
             opt,
             lambda _, sharding: sharding,
-            jax.eval_shape(opt.init, params),
-            param_shardings(cfg, mesh),
+            jax.eval_shape(opt.init, trainable),
+            _trainable(cfg, param_shardings(cfg, mesh)),
             transform_non_params=lambda _: replicated,
         )
-        opt_state = jax.jit(opt.init, out_shardings=opt_shardings)(params)
+        opt_state = jax.jit(opt.init, out_shardings=opt_shardings)(trainable)
         step, rng = jax.device_put((step, rng), replicated)
     return TrainState(params=params, opt_state=opt_state, step=step, rng=rng)
 
@@ -402,7 +458,9 @@ def make_train_step(
     cfg: TransformerConfig,
     mesh: Optional[Mesh] = None,
 ) -> Callable[[TrainState, jax.Array], Tuple[TrainState, jax.Array]]:
-    """Build the jitted full training step (fwd + loss + bwd + adamw)."""
+    """Build the jitted full training step (fwd + loss + bwd + adamw).
+    With ``cfg.lora_rank`` the loss is differentiated, and adamw run,
+    over the adapters alone."""
     opt = _optimizer(cfg)
 
     def loss_fn(params: Params, tokens: jax.Array) -> jax.Array:
@@ -419,9 +477,14 @@ def make_train_step(
             tokens = jax.lax.with_sharding_constraint(
                 tokens, NamedSharding(mesh, P("dp", None))
             )
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens)
-        updates, new_opt_state = opt.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        trainable = _trainable(cfg, state.params)
+        loss, grads = jax.value_and_grad(
+            lambda t: loss_fn(_with_trainable(cfg, state.params, t), tokens)
+        )(trainable)
+        updates, new_opt_state = opt.update(grads, state.opt_state, trainable)
+        new_params = _with_trainable(
+            cfg, state.params, optax.apply_updates(trainable, updates)
+        )
         new_rng = jax.random.fold_in(state.rng, state.step)
         return (
             TrainState(

@@ -687,10 +687,16 @@ class Snapshot:
         _custom_array_prepare_func=None,
         progress_tracker: Optional[_progress.ProgressTracker] = None,
         defer_staging: bool = False,
-    ) -> Tuple["PendingIOWork | DeferredIOWork", Optional[SnapshotMetadata]]:
+    ) -> Tuple[
+        "PendingIOWork | DeferredIOWork",
+        Optional[SnapshotMetadata],
+        Optional[Dict[str, int]],
+    ]:
         """Shared take core (reference snapshot.py:316-440). The returned
         metadata is None on non-leader ranks (manifests gather to rank 0
-        only; see :func:`_gather_manifest`).
+        only; see :func:`_gather_manifest`); the third value is the skip
+        decisions of a take that records digests (chunks and bytes
+        referenced into the base and written), None for a plain take.
 
         With ``defer_staging`` (device-snapshot async takes), no staging
         runs here: the write plan's sources are captured (on-device
@@ -699,7 +705,9 @@ class Snapshot:
         thread. Collectives still all happen on this (the calling)
         thread either way."""
         rank = pg_wrapper.get_rank()
-        with trace_annotation(telemetry.names.SPAN_TAKE_PLAN, rank=rank):
+        with trace_annotation(
+            telemetry.names.SPAN_TAKE_PLAN, rank=rank
+        ) as plan_span:
             write_reqs, metadata, memory_budget_bytes, incr_ctx = (
                 cls._plan_take(
                     path=path,
@@ -712,6 +720,10 @@ class Snapshot:
                     _custom_array_prepare_func=_custom_array_prepare_func,
                 )
             )
+            decisions = None
+            if incr_ctx is not None:
+                decisions = dict(incr_ctx.decisions)
+                plan_span.annotate(**decisions)
 
         if defer_staging:
             # Device-snapshot point: pin every write source (on-device
@@ -777,7 +789,7 @@ class Snapshot:
                 storage.rekey_checksums(pending_io_work.checksums)
 
             pending_io_work.checksum_finalizer = _cas_finalize
-        return pending_io_work, metadata
+        return pending_io_work, metadata, decisions
 
     @staticmethod
     def _write_snapshot_metadata(
@@ -1586,6 +1598,9 @@ class _TakeOp:
         # Extra pipeline fields of the report: the async handle's
         # visible / staged phase split.
         self.phases: Dict[str, float] = {}
+        # The skip decisions of a take that records digests, for the
+        # report (None for a plain take).
+        self.incremental: Optional[Dict[str, int]] = None
 
     def stage(
         self,
@@ -1601,7 +1616,11 @@ class _TakeOp:
         it raises."""
         with _reporting_to(self.barrier, f"{self.kind} staging"):
             self.trace_op = _current_op()
-            self.pending_io_work, self.metadata = Snapshot._take_impl(
+            (
+                self.pending_io_work,
+                self.metadata,
+                self.incremental,
+            ) = Snapshot._take_impl(
                 path=self.path,
                 app_state=app_state,
                 pg_wrapper=self.pg,
@@ -1663,7 +1682,11 @@ class _TakeOp:
                 kind=self.kind,
                 path=self.path,
                 pg_wrapper=self.pg,
-                pipeline={**work.pipeline_telemetry(), **self.phases},
+                pipeline={
+                    **work.pipeline_telemetry(),
+                    **self.phases,
+                    "incremental": self.incremental,
+                },
                 counter_baseline=self.counter_baseline,
                 nonce=self.nonce,
                 trace_mark=self.trace_mark,

@@ -95,6 +95,9 @@ _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_TAKE_PLAN: SEG_PLAN,
     names.SPAN_RESTORE_PLAN: SEG_PLAN,
     names.SPAN_RESHARD_PLAN: SEG_PLAN,
+    names.SPAN_INCREMENTAL_BASE: SEG_PLAN,
+    names.SPAN_INCREMENTAL_DIGEST_LAUNCH: SEG_PLAN,
+    names.SPAN_INCREMENTAL_DIGEST_WAIT: SEG_PLAN,
     names.SPAN_DEVICE_CAPTURE: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_CLONE: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_HOST_COPY: SEG_DEVICE_CAPTURE,

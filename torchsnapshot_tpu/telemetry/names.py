@@ -271,6 +271,22 @@ SPAN_COMMIT_FINALIZE = "commit:finalize"
 SPAN_RESTORE_PLAN = "restore:plan"
 SPAN_RESTORE_PLACE = "restore:place"
 SPAN_RESTORE_APPLY = "restore:apply"
+# incremental.py, inside take:plan of a take that records digests, one
+# span of each a take (docs/incremental.md). incremental:base is the read
+# of the base snapshot's metadata (args: entries = the base manifest's
+# entries this rank can reference, usable = 0 where there is no base or
+# it cannot be addressed relatively). incremental:digest_launch collects
+# every leaf's chunks and dispatches the device digests (args: leaves,
+# chunks, bytes = handed to the device programs, host_bytes = digested on
+# the host right there, programs = dispatches, one a device group).
+# incremental:digest_wait is the caller blocked until the device has
+# answered (args: chunks); the device runs the digests behind whatever
+# the runtime had queued, so a job's queued steps are in it. take:plan
+# of such a take ends with the skip decisions: chunks_referenced,
+# bytes_referenced, chunks_written, bytes_written.
+SPAN_INCREMENTAL_BASE = "incremental:base"
+SPAN_INCREMENTAL_DIGEST_LAUNCH = "incremental:digest_launch"
+SPAN_INCREMENTAL_DIGEST_WAIT = "incremental:digest_wait"
 # scheduler.py: one checksum verification of read bytes, inline or on
 # the executor (args: bytes, mode=whole|range|pages, blob). The write
 # side has no counterpart: the fused native write computes the CRC

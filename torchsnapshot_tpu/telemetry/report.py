@@ -126,6 +126,12 @@ class SnapshotReport:
     # ``chosen`` says where capacity_bytes came from: derived (from the
     # plan), env (the two pool variables), tuner or caller.
     staging_pool: Optional[Dict[str, Any]] = None
+    # Takes that record digests only (None elsewhere): what the plan
+    # decided for the chunks of digested leaves on this rank, before
+    # partitioning: {chunks_referenced, bytes_referenced} into the
+    # incremental base, {chunks_written, bytes_written} staged and
+    # written by this take (docs/incremental.md).
+    incremental: Optional[Dict[str, int]] = None
     # Restore pipelines only (None elsewhere): the read-amplification
     # triple. ``bytes_needed`` is what this rank's read plan had to fill
     # (pre-batching consuming costs); ``bytes_fetched`` is what it
@@ -391,6 +397,11 @@ def build_report(
         staging_pool=(
             dict(pipeline["staging_pool"])
             if pipeline.get("staging_pool")
+            else None
+        ),
+        incremental=(
+            dict(pipeline["incremental"])
+            if pipeline.get("incremental")
             else None
         ),
         bytes_fetched=(
