@@ -34,8 +34,8 @@ STAGE, COMMIT = names.SPAN_ASYNC_TAKE_STAGE, names.SPAN_ASYNC_TAKE_COMMIT
 # Every span of the issue's table A, by the operation that emits it.
 SAVE_SPANS = {
     names.SPAN_TAKE_PLAN, names.SPAN_DEVICE_CAPTURE, names.SPAN_CAPTURE_CLONE,
-    names.SPAN_CAPTURE_HOST_COPY, names.SPAN_CAPTURE_OBJECT, names.SPAN_STAGE_D2H,
-    names.SPAN_COMMIT_FINALIZE, names.SPAN_MANAGER_INDEX,
+    names.SPAN_CAPTURE_HOST_COPY, names.SPAN_CAPTURE_OBJECT, names.SPAN_CAPTURE_READY,
+    names.SPAN_STAGE_D2H, names.SPAN_COMMIT_FINALIZE, names.SPAN_MANAGER_INDEX,
     names.SPAN_MANAGER_RETENTION, names.SPAN_MANAGER_TUNE,
     names.SPAN_TELEMETRY_REPORT,
 }
@@ -331,6 +331,9 @@ def test_new_names_are_registered_mapped_and_lint_clean():
         names.SPAN_CAPTURE_CLONE: "device_capture",
         names.SPAN_CAPTURE_HOST_COPY: "device_capture",
         names.SPAN_CAPTURE_OBJECT: "device_capture",
+        # The drain's wait for the device: not the caller's seconds
+        # (`device_capture`) and not the staging's.
+        names.SPAN_CAPTURE_READY: "capture_ready",
         names.SPAN_STAGE_D2H: "staging", names.SPAN_VERIFY_BLOB: "read_drain",
         names.SPAN_COMMIT_FINALIZE: "commit", names.SPAN_MANAGER_INDEX: "commit",
         names.SPAN_MANAGER_RETENTION: "commit", names.SPAN_MANAGER_TUNE: "commit",
@@ -445,18 +448,40 @@ def test_every_stage_span_is_emitted_with_op_and_parent(saved):
                     # The parent is a span of the same op (the envelope for
                     # top-level work), never a guess.
                     assert e["parent"] in ids, e
-    # One clone per jax leaf, with its bytes and the leaf it pins.
+    # One clone program for the device's jax leaves, with their bytes.
     op, table = next(iter(saves.items()))
-    clones = [e for e in events if e["op"] == op and e["name"] == names.SPAN_CAPTURE_CLONE]
-    assert len(clones) == LEAVES
-    assert {e["args"]["bytes"] for e in clones} == {LEAF_BYTES}
-    assert all(e["args"]["leaf"].startswith("0/model/w") for e in clones)
+    (clone,) = [e for e in events if e["op"] == op and e["name"] == names.SPAN_CAPTURE_CLONE]
+    assert clone["args"] == {"kind": "device", "bytes": LEAVES * LEAF_BYTES, "leaves": LEAVES}
+    (capture,) = [e for e in events if e["op"] == op and e["name"] == names.SPAN_DEVICE_CAPTURE]
+    assert (capture["args"]["clone_programs"], capture["args"]["clone_leaves"],
+            capture["args"]["fallback_leaves"]) == (1, LEAVES, 0)
     assert table["stages"][names.SPAN_STAGE_D2H]["bytes"] == LEAVES * LEAF_BYTES
     assert table["stages"][names.SPAN_STORAGE_WRITE]["bytes"] >= LEAVES * LEAF_BYTES
     # Nothing that ran for an op is left outside one.
     stray = {e["name"] for e in events if not e["op"]} - {
         names.SPAN_STORAGE_READ, names.SPAN_FS_NATIVE_READ}  # restore_latest's index read
     assert stray == set(), stray
+
+
+def test_the_drain_waits_for_the_clones_on_its_own_thread(saved):
+    """`capture:ready` is the commit thread's, inside the commit envelope and
+    before the first transfer: the caller's capture span does not hold it."""
+    events = [e for e in saved["events"] if e["ph"] == "X"]
+    for op, table in _ops(events, "async_take").items():
+        mine = {e["name"]: e for e in events if e["op"] == op
+                and e["name"] in (STAGE, COMMIT, names.SPAN_CAPTURE_READY,
+                                  names.SPAN_DEVICE_CAPTURE)}
+        ready, commit = mine[names.SPAN_CAPTURE_READY], mine[COMMIT]
+        assert ready["tid"] == commit["tid"] != mine[STAGE]["tid"]
+        assert ready["parent"] == commit["bseq"]
+        assert mine[names.SPAN_DEVICE_CAPTURE]["tid"] == mine[STAGE]["tid"]
+        assert ready["args"] == {"bytes": LEAVES * LEAF_BYTES, "programs": 1}
+        row = table["stages"][names.SPAN_CAPTURE_READY]
+        assert row["count"] == 1 and row["bytes"] == LEAVES * LEAF_BYTES
+        first_d2h = min(e["ts"] for e in events
+                        if e["op"] == op and e["name"] == names.SPAN_STAGE_D2H)
+        assert commit["ts"] <= ready["ts"] and ready["ts"] + ready["dur"] <= first_d2h
+    assert critpath.segment_for(names.SPAN_CAPTURE_READY) != critpath.SEG_DEVICE_CAPTURE
 
 
 def test_stamps_cross_the_executor_hops(saved):

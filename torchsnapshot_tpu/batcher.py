@@ -133,6 +133,13 @@ class BatchedBufferStager(BufferStager):
         else:
             self._staging_cost = self.total + pack_bytes + peak_member
 
+    def jax_sources(self) -> list:
+        return [
+            arr
+            for req, _, _ in self.members
+            for arr in req.buffer_stager.jax_sources()
+        ]
+
     def capture(self, cache: dict, leaf: str = "") -> None:
         """Device-snapshot capture recurses into the slab's members:
         each member stager pins its own source (shared ``cache``, so a

@@ -237,6 +237,10 @@ class IncrementalTakeContext:
             Tuple[Any, List[Tuple[str, ChunkKey]], int]
         ] = []
         self._materialized = False
+        # Whether this take's plan blocked on a digest program: the
+        # device has then run everything queued before it, and the
+        # capture pass finds the runtime's queue empty.
+        self.waited_for_device = False
         # Bytes of the leaves digested on the host at launch.
         self._host_bytes = 0
         # What the preparers decided chunk by chunk, on this rank and
@@ -495,6 +499,7 @@ class IncrementalTakeContext:
         if self._materialized:
             return
         self._materialized = True
+        self.waited_for_device = bool(self._group_futs)
         with trace_annotation(
             metric_names.SPAN_INCREMENTAL_DIGEST_WAIT,
             chunks=sum(len(rows) for _, rows, _ in self._group_futs),

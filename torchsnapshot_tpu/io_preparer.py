@@ -32,7 +32,7 @@ import functools
 import logging
 import sys
 from concurrent.futures import Executor
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -84,6 +84,31 @@ def _capture_clone_jit():
     def ts_capture_clone(x):
         with jax.named_scope("ts_capture_clone"):
             return jnp.copy(x)
+
+    return jax.jit(ts_capture_clone)
+
+
+# Members of one clone program at the most: what a program costs to
+# compile grows faster than its parameters (on a v5e 0.9 s cold at 299
+# leaves, 1.3 s at 512, 2.7-3.9 s at 1,024, 29 s at 4,096; a warm
+# dispatch 45 us a parameter: PERF.md section 6, PR 35), so a state of
+# 10^5 small leaves is cloned by ceil(n / cap) programs and not by one.
+_CLONE_GROUP_MAX = 512
+
+
+@functools.lru_cache(maxsize=1)
+def _capture_clone_group_jit():
+    """:func:`_capture_clone_jit` over a list: every member of one
+    device group cloned by one program, under the same name on a
+    profile's Modules line. One dispatch takes one of the runtime's
+    slots however many leaves it clones; one compile per distinct list
+    of shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    def ts_capture_clone(xs):
+        with jax.named_scope("ts_capture_clone"):
+            return [jnp.copy(x) for x in xs]
 
     return jax.jit(ts_capture_clone)
 
@@ -190,6 +215,9 @@ class ArrayBufferStager(BufferStager):
             self.get_staging_cost_bytes() < knobs.get_slab_size_threshold_bytes()
         )
 
+    def jax_sources(self) -> List[Any]:
+        return [self.arr] if is_jax_array(self.arr) else []
+
     def capture(self, cache: dict, leaf: str = "") -> None:
         """Device-snapshot capture (the deferred-staging async take's
         pre-return consistency point):
@@ -208,6 +236,10 @@ class ArrayBufferStager(BufferStager):
         cannot re-materialize on device) falls back to an eager HOST
         snapshot of the bytes — slower (it pays the D2H in the visible
         span, for that leaf only) but never inconsistent.
+
+        A jax source that :func:`capture_write_reqs` already cloned
+        with its device group is in ``cache`` and adopted from there;
+        what follows is the path of one it could not clone that way.
 
         One span per distinct source (``leaf`` is the write request's
         path): ``capture:clone`` is the dispatch alone — nothing here
@@ -827,18 +859,119 @@ def prepare_write(
     return ObjectIOPreparer.prepare_write(obj, logical_path, rank, replicated)
 
 
-def capture_write_reqs(write_reqs: List[WriteReq]) -> int:
+class CapturedSources(NamedTuple):
+    """What :func:`capture_write_reqs` pinned: the number of distinct
+    sources, the on-device clones among them (not ready yet: the drain
+    waits for them, ``scheduler.DeferredIOWork``), and how the jax
+    sources were cloned: by how many group programs, how many leaves in
+    those, and how many leaves one by one."""
+
+    sources: int
+    device_clones: List[Any]
+    clone_programs: int
+    clone_leaves: int
+    fallback_leaves: int
+
+    @property
+    def device_programs(self) -> int:
+        """Programs the device clones come from: a clone that no group
+        program made is one of its own."""
+        return self.clone_programs + len(self.device_clones) - self.clone_leaves
+
+
+def _clone_device_groups(
+    sources: List[Any], cache: dict, group_max: int
+) -> Tuple[int, int]:
+    """Clone a take's jax sources on the device, one program a device
+    group (and a ``group_max`` members), into ``cache``. The runtime
+    makes a dispatch wait for one of its slots, which a training loop
+    keeps full of steps: one dispatch a group waits for one step where
+    one a leaf waits for as many steps as there are slots. A group
+    whose program raises at dispatch (no room for all its outputs at
+    once, a member this process cannot address) leaves the cache as it
+    was, and its members to ``capture``'s path for one leaf. Returns the
+    programs dispatched and the leaves they clone."""
+    from .ops.device_pack import device_group_key
+
+    groups: dict = {}
+    for arr in sources:
+        groups.setdefault(device_group_key(arr), []).append(arr)
+    programs = leaves = 0
+    for group in groups.values():
+        # A program is compiled once a list of shapes. Equal shapes side
+        # by side make the programs of a group larger than the cap alike
+        # (a state of 10^5 leaves has a few dozen shapes), and make a
+        # plan's program the same whatever order its leaves came in.
+        group.sort(key=lambda arr: (str(arr.dtype), arr.shape))
+        for i in range(0, len(group), group_max):
+            members = group[i : i + group_max]
+            nbytes = sum(int(arr.nbytes) for arr in members)
+            try:
+                with trace_annotation(
+                    metric_names.SPAN_CAPTURE_CLONE,
+                    kind="device",
+                    bytes=nbytes,
+                    leaves=len(members),
+                ):
+                    clones = _capture_clone_group_jit()(members)
+            except Exception as e:  # noqa: BLE001 - leaf by leaf instead
+                logger.warning(
+                    "Device clone of %d leaves (%d bytes) as one program "
+                    "failed (%r); cloning them one by one",
+                    len(members),
+                    nbytes,
+                    e,
+                )
+                continue
+            cache.update((id(arr), c) for arr, c in zip(members, clones))
+            programs += 1
+            leaves += len(members)
+    return programs, leaves
+
+
+def capture_write_reqs(
+    write_reqs: List[WriteReq], queue_drained: bool = False
+) -> CapturedSources:
     """Device-snapshot capture pass over a take's write plan: every
     stager pins a consistent copy of its source (``BufferStager.capture``
     — on-device clones for jax leaves, host copies for mutable numpy
     leaves, eager pickles for objects) so ``async_take`` may return
     before any staging ran. One shared cache keyed by the source
     object: a leaf sliced into many chunk/shard stagers is snapshotted
-    once. Returns the number of distinct sources captured."""
+    once. The jax sources are cloned first, together
+    (:func:`_clone_device_groups`), so each stager finds its clone in
+    the cache.
+
+    ``queue_drained``: the take's plan has just waited for the device
+    (it recorded digests, ``IncrementalTakeContext.waited_for_device``).
+    The runtime's slots are then free and a dispatch costs the host's
+    work alone, while the set of leaves such a take writes is what its
+    skip decisions leave, another in every save: a program over all of
+    them would be compiled anew nearly every time. Each source is then a
+    program of its own, compiled once a shape."""
     cache: dict = {}
+    # A leaf sliced into chunk or shard stagers, or held by a slab's
+    # member, is one source. The dict keeps every source alive, and so
+    # its ``id`` its own, until the pass is over.
+    jax_sources = {
+        id(arr): arr
+        for req in write_reqs
+        for arr in req.buffer_stager.jax_sources()
+    }
+    programs, leaves = _clone_device_groups(
+        list(jax_sources.values()),
+        cache,
+        group_max=1 if queue_drained else _CLONE_GROUP_MAX,
+    )
     for req in write_reqs:
         req.buffer_stager.capture(cache, leaf=req.path)
-    return len(cache)
+    return CapturedSources(
+        sources=len(cache),
+        device_clones=[v for v in cache.values() if is_jax_array(v)],
+        clone_programs=programs,
+        clone_leaves=leaves,
+        fallback_leaves=len(jax_sources) - leaves,
+    )
 
 
 def prepare_read(

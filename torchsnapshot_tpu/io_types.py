@@ -152,6 +152,12 @@ class BufferStager(abc.ABC):
         before the take returns) need nothing."""
         return None
 
+    def jax_sources(self) -> List[Any]:
+        """The jax arrays :meth:`capture` would clone on the device, for
+        the capture pass to clone them together, one program a device
+        group (``io_preparer.capture_write_reqs``). Default: none."""
+        return []
+
 
 class BufferConsumer(abc.ABC):
     @abc.abstractmethod

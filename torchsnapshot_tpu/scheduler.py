@@ -34,7 +34,7 @@ import math
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from typing import TYPE_CHECKING, Callable, List, Optional, Sequence
+from typing import TYPE_CHECKING, Any, Callable, List, Optional, Sequence
 
 import psutil
 
@@ -792,6 +792,13 @@ class DeferredIOWork:
     identically. ``on_staged`` fires on the drain thread the moment
     staging finished — the take's ``staged`` phase boundary
     (``PendingSnapshot.wait(phase="staged")``).
+
+    ``device_clones`` are the capture pass's on-device clones, made by
+    ``clone_programs`` programs that the device may not have reached
+    yet: ``async_take`` dispatched them behind whatever the runtime had
+    queued and returned. The drain waits for them under a span of its
+    own (``capture:ready``) before it admits a staging request, so that
+    no ``stage:d2h`` holds the runtime's queue.
     """
 
     def __init__(
@@ -801,6 +808,8 @@ class DeferredIOWork:
         memory_budget_bytes: int,
         rank: int,
         progress: Optional["ProgressTracker"] = None,
+        device_clones: Optional[List[Any]] = None,
+        clone_programs: int = 0,
     ) -> None:
         self.write_reqs = write_reqs
         self._storage = storage
@@ -814,8 +823,26 @@ class DeferredIOWork:
         self.checksum_finalizer: Optional[Callable[[], None]] = None
         self.on_staged: Optional[Callable[[], None]] = None
         self._inner: Optional[PendingIOWork] = None
+        self._device_clones = device_clones
+        self._clone_programs = clone_programs
+
+    def _await_device_clones(self) -> None:
+        # The stagers hold the clones; this list must not keep them (and
+        # their HBM) alive past the wait.
+        clones, self._device_clones = self._device_clones, None
+        if not clones:
+            return
+        import jax
+
+        with trace_annotation(
+            telemetry.names.SPAN_CAPTURE_READY,
+            bytes=sum(int(c.nbytes) for c in clones),
+            programs=self._clone_programs,
+        ):
+            jax.block_until_ready(clones)
 
     def sync_complete(self, event_loop: asyncio.AbstractEventLoop) -> None:
+        self._await_device_clones()
         pool = StagingPool(
             self._memory_budget_bytes,
             request_bytes=[

@@ -736,12 +736,21 @@ class Snapshot:
                 telemetry.names.SPAN_DEVICE_CAPTURE,
                 rank=rank,
                 reqs=len(write_reqs),
-            ):
-                captured = capture_write_reqs(write_reqs)
+            ) as capture_span:
+                captured = capture_write_reqs(
+                    write_reqs,
+                    queue_drained=incr_ctx is not None
+                    and incr_ctx.waited_for_device,
+                )
+                capture_span.annotate(
+                    clone_programs=captured.clone_programs,
+                    clone_leaves=captured.clone_leaves,
+                    fallback_leaves=captured.fallback_leaves,
+                )
             logger.debug(
                 "async take captured %d device/host sources for %d "
                 "deferred write requests",
-                captured,
+                captured.sources,
                 len(write_reqs),
             )
             if progress_tracker is not None:
@@ -753,6 +762,8 @@ class Snapshot:
                     memory_budget_bytes=memory_budget_bytes,
                     rank=rank,
                     progress=progress_tracker,
+                    device_clones=captured.device_clones,
+                    clone_programs=captured.device_programs,
                 )
             )
         else:

@@ -65,6 +65,10 @@ from . import names
 # Path-segment vocabulary (stable identifiers: history rows, the
 # cross-rank fold, and the diff CLI all key on these).
 SEG_DEVICE_CAPTURE = "device_capture"
+# The drain of a device-snapshot async take waiting for the device to
+# reach the clones (capture:ready): the runtime's queue, not the caller's
+# time (that is device_capture) and not the staging's.
+SEG_CAPTURE_READY = "capture_ready"
 SEG_BUDGET_WAIT = "budget_wait"
 SEG_STAGING = "staging"
 SEG_WRITE_DRAIN = "write_drain"
@@ -102,6 +106,7 @@ _SEGMENT_BY_SPAN: Dict[str, str] = {
     names.SPAN_CAPTURE_CLONE: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_HOST_COPY: SEG_DEVICE_CAPTURE,
     names.SPAN_CAPTURE_OBJECT: SEG_DEVICE_CAPTURE,
+    names.SPAN_CAPTURE_READY: SEG_CAPTURE_READY,
     names.SPAN_PIPELINE_BUDGET_ACQUIRE: SEG_BUDGET_WAIT,
     names.SPAN_RESTORE_DEST_ACQUIRE: SEG_BUDGET_WAIT,
     names.SPAN_PIPELINE_STAGE: SEG_STAGING,

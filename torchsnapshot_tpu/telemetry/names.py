@@ -239,15 +239,23 @@ SPAN_LEAF_STAGE = "stage:leaf"
 SPAN_LEAF_CONSUME = "consume:leaf"
 # Device-snapshot async takes: the pre-return capture pass (on-device
 # clone dispatch + mutable-host-leaf copies) — the only staging-flavored
-# work left inside async_take's training-visible span.
+# work left inside async_take's training-visible span. It ends with
+# clone_programs, clone_leaves (the members of those programs) and
+# fallback_leaves (jax sources cloned one by one).
 SPAN_DEVICE_CAPTURE = "stage:device_capture"
-# One per distinct source inside that pass (args: kind, bytes, leaf):
-# the dispatch of a jax leaf's on-device clone, the host copy of a
-# mutable numpy leaf (or of a jax leaf whose clone failed), the eager
-# pickle of an object.
+# Inside that pass. capture:clone is the dispatch of one clone program:
+# one a device group over all its jax sources (args: kind, bytes,
+# leaves), or one jax leaf's where its group's program failed (kind,
+# bytes, leaf). One per distinct source (kind, bytes, leaf): the host
+# copy of a mutable numpy leaf (or of a jax leaf whose clone failed),
+# the eager pickle of an object.
 SPAN_CAPTURE_CLONE = "capture:clone"
 SPAN_CAPTURE_HOST_COPY = "capture:host_copy"
 SPAN_CAPTURE_OBJECT = "capture:object"
+# The drain thread's wait for the device to reach the take's clones,
+# behind whatever the runtime had queued when async_take returned,
+# before the first staging request is admitted (args: bytes, programs).
+SPAN_CAPTURE_READY = "capture:ready"
 # Inside stage:leaf, the ``np.asarray`` of a jax array alone: the PJRT
 # transfer plus the host-side untiling, without the slicing and
 # ascontiguousarray around it.
