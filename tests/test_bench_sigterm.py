@@ -33,6 +33,9 @@ def test_sigterm_mid_run_still_emits_parsed_record(tmp_path):
         TS_BENCH_SKIP_PROTOCOL="1",
         TS_BENCH_PARTIAL_PATH=str(tmp_path / "BENCH_partial.json"),
         TMPDIR=str(tmp_path),
+        # Not the checkout's .jax_cache: test_chip_smoke.py, on another
+        # worker, holds that directory to what it was.
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
     )
     proc = subprocess.Popen(
         [sys.executable, str(BENCH)],

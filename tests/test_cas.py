@@ -256,11 +256,16 @@ def test_gc_grace_defers_then_reclaims(tmp_path):
         assert mgr.restore_latest(dest) == 2
 
 
-def test_concurrent_take_dedup_survives_gc_of_its_source(tmp_path):
+def test_concurrent_take_dedup_survives_gc_of_its_source(tmp_path, monkeypatch):
     """The ISSUE's concurrent take + GC pin: an in-flight (not yet
     committed) async take dedups against step 0's chunks; a sync save
     then GCs step 0 — the grace window keeps the shared chunks on disk,
     and the async step commits restorable."""
+    from torchsnapshot_tpu.test_utils import MarkerWrites
+
+    # Step 1's commit is held open at its marker: its commit thread pins
+    # and indexes the step as soon as the marker exists.
+    marker_may_land = MarkerWrites(monkeypatch).hold("step_0000000001")
     root = str(tmp_path / "ckpt")
     with knobs.enable_cas(), knobs.override_cas_gc_grace_seconds(3600):
         mgr = ts.CheckpointManager(root, keep_last_n=1)
@@ -268,12 +273,14 @@ def test_concurrent_take_dedup_survives_gc_of_its_source(tmp_path):
         mgr.save(0, state_a)
         # In-flight take of the SAME state: its writes dedup against
         # step 0's chunks (touching them) but nothing is pinned until
-        # wait().
+        # it commits.
         pending = mgr.async_save(1, state_a)
         pending._pending.wait(phase="staged")
         # A competing commit drops step 0 while step 1 is un-pinned.
         mgr.save(2, _state(offset=9.0))
-        assert pending.wait() is not None  # commits + pins step 1
+        assert not pending.done()
+        marker_may_land.set()
+        assert pending.wait() is not None  # committed + pinned step 1
         dest = _state()
         mgr.restore(1, dest)
         np.testing.assert_array_equal(

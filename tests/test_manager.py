@@ -67,21 +67,27 @@ def test_async_save_commits_on_wait(tmp_path) -> None:
     assert mgr.restore_latest(dst) == 6
 
 
-def test_async_save_staged_wait_does_not_index(tmp_path) -> None:
-    """wait(phase="staged") observes D2H completion only: the step must
-    not enter the index (a half-drained step must never be visible to
-    restore_latest); the committed wait indexes it exactly once."""
+def test_async_save_staged_wait_does_not_index(tmp_path, monkeypatch) -> None:
+    """wait(phase="staged") observes D2H completion only and indexes
+    nothing itself: a half-drained step must never be visible to
+    restore_latest. With the commit held open at the marker's write the
+    step is absent; once the marker exists the commit thread indexes it,
+    exactly once, and it is present when done() reads true."""
+    from torchsnapshot_tpu.test_utils import MarkerWrites
+
+    marker_may_land = MarkerWrites(monkeypatch).hold(_step_dirname(3))
     mgr = ts.CheckpointManager(str(tmp_path))
     pending = mgr.async_save(3, _state(3.0))
     assert pending.wait(phase="staged") is None
-    assert pending.staged()
+    assert pending.staged() and not pending.done()
     assert 3 not in mgr.all_steps()
-    # A typo'd phase must not silently become a committed wait with
-    # index/retention side effects (same contract as PendingSnapshot).
+    # A typo'd phase must not silently become a committed wait (same
+    # contract as PendingSnapshot).
     with pytest.raises(ValueError, match="staged"):
         pending.wait(phase="stagd")
+    marker_may_land.set()
     snapshot = pending.wait()
-    assert snapshot is not None
+    assert snapshot is not None and pending.done()
     assert mgr.all_steps() == [3]
 
 

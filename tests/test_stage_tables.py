@@ -484,6 +484,41 @@ def test_the_drain_waits_for_the_clones_on_its_own_thread(saved):
     assert critpath.segment_for(names.SPAN_CAPTURE_READY) != critpath.SEG_DEVICE_CAPTURE
 
 
+@pytest.mark.parametrize("entry", ["async_save", "save"])
+def test_the_managers_spans_run_where_the_commit_ended(saved, tmp_path, entry):
+    """`manager:index` (with `manager:retention` inside it), the step's
+    `telemetry:report` and `manager:tune` are stages of the take either way:
+    on the commit thread, behind the commit envelope, for an `async_save`
+    (`on="commit"`), on the caller's, behind the take's envelope, for a
+    blocking `save` (`on="caller"`)."""
+    if entry == "async_save":
+        events = [e for e in saved["events"] if e["ph"] == "X"]
+        ops, envelope, on = _ops(events, "async_take"), COMMIT, "commit"
+    else:
+        rec = trace.get_recorder()
+        with knobs.enable_telemetry():
+            mark = rec.mark()
+            ts.CheckpointManager(str(tmp_path), keep_last_n=1).save(0, _app_state())
+            events = [e for e in rec.events_since(mark) if e["ph"] == "X"]
+        ops, envelope, on = _ops(events, "take"), TAKE, "caller"
+    assert ops
+    for op in ops:
+        mine = [e for e in events if e["op"] == op]
+        (ended,) = [e for e in mine if e["name"] == envelope]
+        (stage,) = [e for e in mine if e["name"] in (STAGE, TAKE)]
+        (index,) = [e for e in mine if e["name"] == names.SPAN_MANAGER_INDEX]
+        (retention,) = [e for e in mine if e["name"] == names.SPAN_MANAGER_RETENTION]
+        (report,) = [e for e in mine if e["name"] == names.SPAN_TELEMETRY_REPORT
+                     and e["args"].get("kind") == "step"]
+        (tune,) = [e for e in mine if e["name"] == names.SPAN_MANAGER_TUNE]
+        assert index["args"]["on"] == on
+        assert retention["parent"] == index["bseq"]
+        for span in (index, report, tune):
+            assert span["tid"] == ended["tid"], span["name"]
+            assert (span["tid"] == stage["tid"]) == (entry == "save"), span["name"]
+            assert span["ts"] >= ended["ts"] + ended["dur"], span["name"]
+
+
 def test_stamps_cross_the_executor_hops(saved):
     events = [e for e in saved["events"] if e["ph"] == "X"]
     by_bseq = {e["bseq"]: e for e in events}

@@ -214,52 +214,76 @@ async def read_index_full_async(storage: StoragePlugin) -> Dict[str, Any]:
 
 
 class _PendingManagedSnapshot:
-    """Wraps a PendingSnapshot so index update + retention run once the
-    background commit succeeds."""
+    """Wraps a PendingSnapshot so that the manager's after-commit work
+    for the step (``CheckpointManager._after_commit``: index, retention,
+    history, ledger, the tuner's decision) runs exactly once, as the
+    tail of the take's commit thread: it is done when ``done()`` reads
+    true, whether or not anyone calls ``wait()``. ``wait()`` joins,
+    installs what the tuner decided (knob overrides land between takes,
+    on the thread that drives them) and, where that work raised on the
+    commit thread, runs it once more and raises what that raises."""
 
     def __init__(
         self,
         manager: "CheckpointManager",
         step: int,
-        pending: PendingSnapshot,
         metric: Optional[float] = None,
     ):
         self._manager = manager
         self._step = step
-        self._pending = pending
         self._metric = metric
+        # Set by CheckpointManager.async_save once the take is staged;
+        # the commit thread never reads it.
+        self._pending: PendingSnapshot
         self._committed = False
+        # Exactly once a step: the commit thread and every wait() pass
+        # through here (a duplicate history record widens the trend
+        # baseline).
         self._commit_lock = threading.Lock()
+        # The tuner's decided vector, until wait() installs it.
+        self._decided: Optional[Dict[str, Any]] = None
+
+    def _after_commit(self, snapshot: Snapshot, on: str) -> None:
+        with self._commit_lock:
+            if self._committed:
+                return
+            self._decided = self._manager._after_commit(
+                self._step, snapshot, self._metric, on
+            )
+            self._committed = True
 
     def wait(self, phase: str = "committed") -> Optional[Snapshot]:
-        """Passes ``phase`` through to :meth:`PendingSnapshot.wait`.
-        Index update + retention run only on the ``"committed"`` wait —
-        a ``"staged"`` wait observes D2H completion without making the
-        step visible to ``restore_latest`` (the drain paths that flush
-        checkpoints before teardown must wait for ``"committed"``, and
-        this wrapper's default does)."""
+        """Passes ``phase`` through to :meth:`PendingSnapshot.wait`. A
+        ``"staged"`` wait observes D2H completion and indexes nothing
+        itself; the step becomes visible to ``restore_latest`` when its
+        commit succeeded, never before its marker exists (the drain
+        paths that flush checkpoints before teardown must wait for
+        ``"committed"``, and this wrapper's default does). When a
+        committed wait returns, the marker exists, the index lists the
+        step, retention and chunk GC for it have run, and history and
+        ledger hold its row."""
         if phase not in ("staged", "committed"):
             # Same contract as PendingSnapshot.wait: a typo'd phase must
-            # not silently become a committed wait with index/retention
-            # side effects.
+            # not silently become a committed wait.
             raise ValueError(
                 f'phase must be "staged" or "committed", got {phase!r}'
             )
         if phase == "staged":
             self._pending.wait(phase="staged")
             return None
-        snapshot = self._pending.wait()  # raises on failed take: no index entry
-        # Idempotent join, lock-guarded: wait() may be called from more
-        # than one place (progress loop + shutdown path, possibly on
-        # different threads) and must commit + record history exactly
-        # once — a duplicate history record widens the trend baseline.
+        try:
+            snapshot = self._pending.wait()
+        except BaseException:
+            # A failed take: no index entry, and nothing of it in flight.
+            self._manager._bases_in_flight.pop(self._step, None)
+            raise
+        # wait() may be called from more than one place (progress loop +
+        # shutdown path, possibly on different threads): a no-op unless
+        # the commit thread's pass raised.
+        self._after_commit(snapshot, "caller")
         with self._commit_lock:
-            if self._committed:
-                return snapshot
-            self._manager._after_commit(
-                self._step, snapshot, self._metric, self._pending.trace_op
-            )
-            self._committed = True
+            decided, self._decided = self._decided, None
+        self._manager._install_tuned(self._step, snapshot, decided)
         return snapshot
 
     def done(self) -> bool:
@@ -401,6 +425,18 @@ class CheckpointManager:
         # digest map, for the ledger's bytes_digest_unchanged signal.
         self._last_cas_accounting: Optional[Dict[str, Any]] = None
         self._prev_digest_map: Dict[str, Any] = {}
+        # One step's after-commit work at a time (_after_commit): the
+        # index is a read-modify-write and the accounting above is the
+        # manager's, while two async saves' commit threads, or one and a
+        # blocking save, may get there together. They index and retain
+        # in the order their commits end.
+        self._after_commit_lock = threading.Lock()
+        # {step being saved: the step it diffs against} for this
+        # manager's incremental takes that are not indexed yet (rank 0
+        # resolved the base). Retention keeps such a base's blobs: the
+        # take reads its manifest now and will reference its chunks,
+        # and the index cannot say so before the take commits.
+        self._bases_in_flight: Dict[int, int] = {}
         if self._pg.get_rank() == 0:
             try:
                 self._reconcile_cas()
@@ -436,20 +472,32 @@ class CheckpointManager:
         return join_path(self.root, _step_dirname(step))
 
     def _incremental_take_kwargs(
-        self, incremental: Optional[bool], take_kwargs: Dict[str, Any]
+        self,
+        step: int,
+        incremental: Optional[bool],
+        take_kwargs: Dict[str, Any],
     ) -> Dict[str, Any]:
         """Resolve the per-save incremental setting and, when on, point the
         take at the latest committed step. Rank 0 resolves the base and
-        everyone follows — ranks must never diff against different bases."""
+        everyone follows — ranks must never diff against different bases.
+        The base is held against retention (``_bases_in_flight``) until
+        ``step`` is indexed or its take has failed."""
         if incremental is None:
             incremental = self.incremental
         if not incremental:
             return take_kwargs
         if "incremental_base" in take_kwargs:
             return {**take_kwargs, "record_digests": True}
-        base_step = (
-            self.latest_step() if self._pg.get_rank() == 0 else None
-        )
+        base_step = None
+        if self._pg.get_rank() == 0:
+            # Under the lock, and not over the broadcast below (a peer's
+            # commit thread may hold its own lock waiting for ours): an
+            # after-commit pass that has written the index may still be
+            # deleting the steps it dropped.
+            with self._after_commit_lock:
+                base_step = self.latest_step()
+                if base_step is not None:
+                    self._bases_in_flight[step] = base_step
         base_step = self._pg.broadcast_object(base_step)
         out = {**take_kwargs, "record_digests": True}
         if base_step is not None:
@@ -470,11 +518,19 @@ class CheckpointManager:
         step's score for ``keep_best_n`` retention and ``best_step()``
         (rank 0's value is authoritative)."""
         self._validate_metric(metric)
-        take_kwargs = self._incremental_take_kwargs(incremental, take_kwargs)
-        snapshot = Snapshot.take(
-            self.step_path(step), app_state, pg=self._pg_arg, **take_kwargs
+        take_kwargs = self._incremental_take_kwargs(
+            step, incremental, take_kwargs
         )
-        self._after_commit(step, snapshot, metric, snapshot.trace_op)
+        try:
+            snapshot = Snapshot.take(
+                self.step_path(step), app_state, pg=self._pg_arg, **take_kwargs
+            )
+        except BaseException:
+            self._bases_in_flight.pop(step, None)
+            raise
+        self._install_tuned(
+            step, snapshot, self._after_commit(step, snapshot, metric, "caller")
+        )
         return snapshot
 
     def _after_commit(
@@ -482,33 +538,80 @@ class CheckpointManager:
         step: int,
         snapshot: Snapshot,
         metric: Optional[float],
-        trace_op: int,
-    ) -> None:
+        on: str,
+    ) -> Optional[Dict[str, Any]]:
         """What the manager does for a step once its snapshot is
-        committed, on the thread that called ``save()`` / ``wait()``:
-        index + retention, history / ledger / SLOs, the CDN announce,
-        the tuner's move. Recorded as stages of the take (``trace_op``)
-        that made the step: this is inside the caller's stall."""
-        with _op_scope(trace_op):
-            with trace_annotation(metric_names.SPAN_MANAGER_INDEX, step=step):
-                self._commit_step(
-                    step,
-                    refs=lambda: referenced_steps(snapshot.metadata.manifest),
-                    metric=metric,
-                    chunk_refs=lambda: _manifest_chunk_refs(
-                        snapshot.metadata.manifest
-                    ),
+        committed: index + retention, history / ledger / SLOs, the CDN
+        announce, the tuner's decision. One function with two callers:
+        ``save()`` on the caller's thread (``on="caller"``, inside its
+        stall) and, for ``async_save``, the take's commit thread
+        (``on="commit"``) after the marker is written and before
+        ``done()`` turns true, so the step is visible to
+        ``restore_latest`` from then on and none of this is inside
+        ``wait()``. Recorded as stages of the take that made the step
+        (``snapshot.trace_op``). Returns the knob vector the tuner
+        decided, for ``_install_tuned`` on the thread that drives the
+        takes, or None. Everything but the tuner's pass runs under the
+        manager's lock: its exchange waits for peers, whose own locks
+        may be held for another step."""
+        with _op_scope(snapshot.trace_op):
+            with self._after_commit_lock:
+                with trace_annotation(
+                    metric_names.SPAN_MANAGER_INDEX, step=step, on=on
+                ):
+                    self._commit_step(
+                        step,
+                        refs=lambda: referenced_steps(
+                            snapshot.metadata.manifest
+                        ),
+                        metric=metric,
+                        chunk_refs=lambda: _manifest_chunk_refs(
+                            snapshot.metadata.manifest
+                        ),
+                    )
+                # Indexed with its references: the index holds the base.
+                self._bases_in_flight.pop(step, None)
+                telemetry.metrics().counter_inc(
+                    metric_names.MANAGER_SAVES_TOTAL
                 )
-            telemetry.metrics().counter_inc(metric_names.MANAGER_SAVES_TOTAL)
-            with trace_annotation(
-                metric_names.SPAN_TELEMETRY_REPORT, kind="step", step=step
-            ):
-                self._record_step_history(step)
-                self._post_step_ledger(step, snapshot)
-                self._evaluate_slos(step)
-            self._publish_cdn_step(step, snapshot)
+                with trace_annotation(
+                    metric_names.SPAN_TELEMETRY_REPORT, kind="step", step=step
+                ):
+                    self._record_step_history(step)
+                    self._post_step_ledger(step, snapshot)
+                    self._evaluate_slos(step)
+                self._publish_cdn_step(step, snapshot)
             with trace_annotation(metric_names.SPAN_MANAGER_TUNE, step=step):
-                self._autotune_step(step)
+                return self._autotune_step(step, snapshot)
+
+    def _install_tuned(
+        self,
+        step: int,
+        snapshot: Snapshot,
+        decided: Optional[Dict[str, Any]],
+    ) -> None:
+        """Install the vector the tuner decided after ``step`` committed
+        (None: nothing to install), on the thread that called ``save()``
+        / ``wait()``: overrides change a take's geometry, so they land
+        between two takes in program order on every rank, which a commit
+        thread cannot promise. A handle that is never waited for
+        installs nothing."""
+        if decided is None:
+            return
+        with _op_scope(snapshot.trace_op), trace_annotation(
+            metric_names.SPAN_MANAGER_TUNE, step=step
+        ):
+            try:
+                from .tuner import tunables
+
+                tunables.apply_vector(decided)
+            except Exception as e:  # noqa: BLE001 - tuning is best-effort
+                logger.warning(
+                    "autotuner: could not install the vector decided "
+                    "after step %d: %r",
+                    step,
+                    e,
+                )
 
     @staticmethod
     def _validate_metric(metric: Optional[float]) -> None:
@@ -532,14 +635,30 @@ class CheckpointManager:
         metric: Optional[float] = None,
         **take_kwargs: Any,
     ) -> _PendingManagedSnapshot:
-        """Pipelined checkpoint; the index entry and retention pass happen
-        in ``wait()`` after the background commit succeeds."""
+        """Pipelined checkpoint. The index entry, the retention pass and
+        the step's history and ledger rows follow the commit marker on
+        the take's commit thread (``_after_commit``): a committed step
+        is visible to ``restore_latest`` once ``done()`` reads true,
+        and ``wait()`` only joins and installs the tuner's move."""
         self._validate_metric(metric)
-        take_kwargs = self._incremental_take_kwargs(incremental, take_kwargs)
-        pending = Snapshot.async_take(
-            self.step_path(step), app_state, pg=self._pg_arg, **take_kwargs
+        take_kwargs = self._incremental_take_kwargs(
+            step, incremental, take_kwargs
         )
-        return _PendingManagedSnapshot(self, step, pending, metric=metric)
+        handle = _PendingManagedSnapshot(self, step, metric=metric)
+        try:
+            handle._pending = Snapshot.async_take(
+                self.step_path(step),
+                app_state,
+                pg=self._pg_arg,
+                _after_commit=lambda snapshot: handle._after_commit(
+                    snapshot, "commit"
+                ),
+                **take_kwargs,
+            )
+        except BaseException:
+            self._bases_in_flight.pop(step, None)
+            raise
+        return handle
 
     def _record_step_history(self, step: int) -> None:
         """Append the just-committed step's telemetry summary to the
@@ -718,21 +837,28 @@ class CheckpointManager:
         except Exception as e:  # noqa: BLE001 - publishing is best-effort
             logger.warning("cdn: could not publish step %d: %r", step, e)
 
-    def _autotune_step(self, step: int) -> None:
+    def _autotune_step(
+        self, step: int, snapshot: Snapshot
+    ) -> Optional[Dict[str, Any]]:
         """One closed-loop tuning pass after ``step`` committed: rank 0
-        reads the step's report, decides the next knob vector, and
-        every rank applies the broadcast decision (tuner/autotuner.py).
-        The TORCHSNAPSHOT_TPU_AUTOTUNE=0 kill switch must be set
-        uniformly across ranks (like every geometry-affecting knob) —
-        with it, this is a pure no-op. Best-effort: tuning must never
-        fail a save."""
+        reads the step's report and decides the next knob vector, every
+        rank receives it (tuner/autotuner.py) and returns it for
+        ``_install_tuned``. The exchange is keyed by the take's nonce,
+        not by a place in the process group's op sequence: on a commit
+        thread it falls between the caller's collectives in an order
+        the ranks do not share. The TORCHSNAPSHOT_TPU_AUTOTUNE=0 kill
+        switch must be set uniformly across ranks (like every
+        geometry-affecting knob) — with it, this is a pure no-op.
+        Best-effort: tuning must never fail a save."""
         if not knobs.is_autotune_enabled():
-            return
+            return None
         try:
             if self._autotuner is None:
-                from .tuner import Autotuner
+                with self._after_commit_lock:  # two commit threads, one tuner
+                    if self._autotuner is None:
+                        from .tuner import Autotuner
 
-                self._autotuner = Autotuner(self.root)
+                        self._autotuner = Autotuner(self.root)
             report = None
             if self._pg.get_rank() == 0:
                 from .telemetry import last_report
@@ -740,11 +866,16 @@ class CheckpointManager:
                 report = last_report(
                     "take", "async_take", path=self.step_path(step)
                 )
-            self._autotuner.tune_after_step(step, report, self._pg)
+            return self._autotuner.decide_after_step(
+                step,
+                report,
+                self._pg.keyed(f"tuner/{snapshot.commit_nonce}"),
+            )
         except Exception as e:  # noqa: BLE001 - tuning is best-effort
             logger.warning(
                 "autotuner: skipped tuning after step %d: %r", step, e
             )
+            return None
 
     # ------------------------------------------------------------------
     # resuming
@@ -994,6 +1125,15 @@ class CheckpointManager:
         needed: Set[int] = set()
         for s in steps:
             needed.update(refs_map.get(str(s), ()))
+        # And the base of a take of this manager still in flight, with
+        # the origins its base references: its manifest will name them,
+        # the index cannot yet. It is pinned here like any referenced
+        # step, and dropped by a later pass if the take referenced
+        # nothing of it or failed.
+        for saving, base in list(self._bases_in_flight.items()):
+            if saving != step:
+                needed.add(base)
+                needed.update(refs_map.get(str(base), ()))
         to_delete: List[int] = []
         for old in dropped:
             if old in needed:

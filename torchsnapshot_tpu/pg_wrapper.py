@@ -41,6 +41,10 @@ class PGWrapper:
     object exposing ``store``/``rank``/``world_size``.
     """
 
+    # Where this wrapper's operations keep their store keys: the shared
+    # op sequence's, but for a wrapper that ``keyed`` made.
+    _namespace = "__pg"
+
     def __init__(self, pg: Optional[Any] = None) -> None:
         # The op sequence is SHARED across every wrapper over the same
         # underlying (store, rank) — attached to the store object, keyed by
@@ -60,6 +64,7 @@ class PGWrapper:
             self.rank = pg.rank
             self.world_size = pg.world_size
             self._op_seq_ref = pg._op_seq_ref
+            self._namespace = pg._namespace
         else:
             self.store = pg.store
             self.rank = int(pg.rank)
@@ -72,9 +77,23 @@ class PGWrapper:
     def get_world_size(self) -> int:
         return self.world_size
 
+    def keyed(self, key: str) -> "PGWrapper":
+        """A wrapper over the same store whose operations are keyed
+        under ``key`` and counted from one, outside the shared op
+        sequence. For a thread beside the one that drives the job's
+        collectives (an async take's commit thread): its calls fall
+        between that thread's in an order the ranks do not share, so a
+        place in the shared sequence would pair them with the wrong
+        operation on a peer. ``key`` is the same on every rank and used
+        by one such wrapper (a take's nonce is both)."""
+        out = PGWrapper(self)
+        out._op_seq_ref = [0]
+        out._namespace = f"__pg/{key}"
+        return out
+
     def _next_prefix(self, op: str) -> str:
         self._op_seq_ref[0] += 1
-        return f"__pg/{op}/{self._op_seq_ref[0]}"
+        return f"{self._namespace}/{op}/{self._op_seq_ref[0]}"
 
     def barrier(self) -> None:
         if self.world_size == 1:

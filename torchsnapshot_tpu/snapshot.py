@@ -424,6 +424,11 @@ class Snapshot:
         # done for that operation after it returned (the manager's index,
         # retention, history) is recorded under it (trace.op_scope).
         self.trace_op = 0
+        # The nonce the ranks of the take that made this snapshot agreed
+        # on ("" for a single process, or no take in this process): what
+        # the manager keys its own cross-rank exchange for the step by,
+        # so that it needs no place in the process group's op sequence.
+        self.commit_nonce = ""
         # Merged checksum tables, loaded at most once per Snapshot instance
         # (False = not loaded yet; None = no tables / verification disabled).
         self._checksum_table_cache: Any = False
@@ -471,10 +476,7 @@ class Snapshot:
         finally:
             _tracing.end(take_span)  # no-op if already closed
             op.settle(op_error)
-        snapshot = cls(path=op.path, pg=pg)
-        snapshot._metadata = op.metadata
-        snapshot.trace_op = op.trace_op
-        return snapshot
+        return op.snapshot()
 
     @classmethod
     def async_take(
@@ -486,6 +488,7 @@ class Snapshot:
         incremental_base: Optional[Any] = None,
         record_digests: bool = False,
         _custom_array_prepare_func=None,
+        _after_commit: Optional[Callable[["Snapshot"], None]] = None,
     ) -> "PendingSnapshot":
         """Pipelined checkpoint whose training-visible span is independent
         of checkpoint size (docs/async.md): by default the call returns as
@@ -530,7 +533,7 @@ class Snapshot:
                 pass
             op.settle(e)
             raise
-        return PendingSnapshot(op, op_begin)
+        return PendingSnapshot(op, op_begin, _after_commit)
 
     @classmethod
     def _plan_take(
@@ -1705,6 +1708,18 @@ class _TakeOp:
                 trace_op=self.trace_op,
             )
 
+    def snapshot(self) -> "Snapshot":
+        """The committed snapshot, for the caller and for what the
+        manager does for the step (recorded under ``trace_op``, its
+        cross-rank exchange keyed by ``commit_nonce``). Carries the
+        take's process group: ``restore()`` on it keeps per-rank
+        availability and coordination semantics."""
+        snapshot = Snapshot(path=self.path, pg=self.pg)
+        snapshot._metadata = self.metadata
+        snapshot.trace_op = self.trace_op
+        snapshot.commit_nonce = self.nonce
+        return snapshot
+
     def settle(self, error: Optional[BaseException]) -> None:
         """The end of the take on the thread that ran it, failed or not.
         Success removes the heartbeat file; failure leaves a terminal
@@ -1734,16 +1749,30 @@ class PendingSnapshot:
       tier). ``wait(phase="staged")``.
     - **committed** — every rank's writes are durable and the commit
       marker exists. ``wait()`` / ``wait(phase="committed")``.
+
+    ``after_commit`` (``CheckpointManager.async_save``'s index,
+    retention and history for the step) runs on the commit thread once
+    the commit succeeded, handed the committed snapshot, before
+    ``done()`` turns true; a failed take never reaches it, and what it
+    raises is logged and never becomes the take's error.
     """
 
-    def __init__(self, op: _TakeOp, op_begin: float) -> None:
+    def __init__(
+        self,
+        op: _TakeOp,
+        op_begin: float,
+        after_commit: Optional[Callable[["Snapshot"], None]] = None,
+    ) -> None:
         import threading
 
         self._op = op
+        # Handed in, not set from outside, for the reason on_staged is
+        # wired below: a tiny state commits before the caller could.
+        self._after_commit = after_commit
         self.path = op.path
         # The flight recorder's id of this take (the stage envelope's):
         # the commit envelope joins it, and so does what the manager
-        # does for the step in wait().
+        # does for the step behind it.
         self.trace_op = op.trace_op
         self.commit_nonce = op.nonce
         self.pg = op.pg
@@ -1773,6 +1802,10 @@ class PendingSnapshot:
         self._thread.start()
 
     def _complete_snapshot(self) -> None:
+        # Taken, not kept: the manager's hook refers to the handle that
+        # holds this one, and a cycle would keep the take's requests and
+        # buffers until the collector finds them.
+        after_commit, self._after_commit = self._after_commit, None
         commit_span = _tracing.begin(
             telemetry.names.SPAN_ASYNC_TAKE_COMMIT,
             op=self.trace_op,
@@ -1784,6 +1817,17 @@ class PendingSnapshot:
         except BaseException as e:  # noqa: BLE001 - must propagate via wait()
             self._exc_info = e
             logger.error("Async snapshot failed: %r", e)
+        else:
+            if after_commit is not None:
+                try:
+                    after_commit(self._op.snapshot())
+                except Exception as e:  # noqa: BLE001 - the take succeeded
+                    logger.warning(
+                        "after-commit work for %s failed on the commit "
+                        "thread (the snapshot is committed): %r",
+                        self.path,
+                        e,
+                    )
         finally:
             # Ordering matters on the failure path: the error is recorded
             # and the heartbeat settled TERMINAL ("failed", never a
@@ -1822,11 +1866,7 @@ class PendingSnapshot:
         self._thread.join()
         if self._exc_info is not None:
             raise self._exc_info
-        # Preserve the process group: restore() on the returned snapshot
-        # must keep per-rank availability and coordination semantics.
-        snapshot = Snapshot(path=self.path, pg=self.pg)
-        snapshot._metadata = self._op.metadata
-        return snapshot
+        return self._op.snapshot()
 
     def done(self) -> bool:
         return self._done.is_set()

@@ -323,15 +323,20 @@ SPAN_RESHARD_PLAN = "reshard:plan"
 SPAN_RESHARD_COPY = "reshard:copy"
 SPAN_RESHARD_ASSEMBLE = "reshard:assemble"
 
-# manager.py, after the commit, on the thread that called save()/wait():
-# the index update (retention nested inside it: step deletes + chunk GC)
-# and the autotuner's move.
+# manager.py, after the commit (CheckpointManager._after_commit): the
+# index update (retention nested inside it: step deletes + chunk GC; arg
+# on = "commit" where it ran as the tail of an async_save's commit
+# thread, before done() turned true, "caller" on the thread that called
+# save(), or wait() after the commit thread's pass raised) and the
+# autotuner's move: one span around the decision, where the index ran,
+# and one around the installation of what it decided, always on the
+# thread that called save() / wait().
 SPAN_MANAGER_INDEX = "manager:index"
 SPAN_MANAGER_RETENTION = "manager:retention"
 SPAN_MANAGER_TUNE = "manager:tune"
 # Report emission after the envelope closed (critical path, stage table,
 # gather, sinks, ledger, trace export; arg kind) and the manager's
-# history / ledger / SLO pass (arg kind="step").
+# history / ledger / SLO pass (arg kind="step"; where manager:index ran).
 SPAN_TELEMETRY_REPORT = "telemetry:report"
 
 # storage plugins (fs/s3/gcs); the fs native fast path additionally

@@ -15,10 +15,12 @@ Also exports the equality/rand helpers the reference ships
 
 from __future__ import annotations
 
+import asyncio
 import multiprocessing as mp
 import os
 import pickle
 import socket
+import threading
 import traceback
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -107,6 +109,45 @@ def patch_storage_plugin(cls):
         "torchsnapshot_tpu.snapshot.url_to_storage_plugin",
         side_effect=lambda url: cls(root=url.split("://")[-1]),
     )
+
+
+class MarkerWrites:
+    """The fs plugin's commit-marker writes, held open on an event or made
+    to fail for chosen snapshots, under a ``pytest.MonkeyPatch``: an async
+    take's commit thread stands where a slow or a broken storage would
+    leave it, with every blob durable and the snapshot uncommitted.
+    ``where`` is a piece of the snapshot's path (a manager's step
+    directory name)."""
+
+    TIMEOUT_S = 60.0
+
+    def __init__(self, patch: Any) -> None:
+        from .snapshot import SNAPSHOT_METADATA_FNAME
+        from .storage_plugins.fs import FSStoragePlugin
+
+        self.held: Dict[str, threading.Event] = {}
+        self.broken: List[str] = []
+        write = FSStoragePlugin.write
+
+        async def gated(plugin, write_io):
+            if write_io.path.endswith(SNAPSHOT_METADATA_FNAME):
+                for where, gate in list(self.held.items()):
+                    if where in plugin.root:
+                        assert await asyncio.get_running_loop().run_in_executor(
+                            None, gate.wait, self.TIMEOUT_S
+                        )
+                for where in self.broken:
+                    if where in plugin.root:
+                        raise OSError(f"planted: marker of {where}")
+            return await write(plugin, write_io)
+
+        patch.setattr(FSStoragePlugin, "write", gated)
+
+    def hold(self, where: str) -> threading.Event:
+        """Hold the marker of snapshots under ``where`` until the
+        returned event is set."""
+        self.held[where] = threading.Event()
+        return self.held[where]
 
 
 class ByteCountingStore(Store):

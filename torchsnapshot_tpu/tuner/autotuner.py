@@ -6,7 +6,11 @@ the checkpoint doctor's report-scope rules over it, consults its own
 rolling observation window, and decides ONE bounded move for the *next*
 take (policy.py). Rank 0 decides; the decided vector is broadcast over
 the ``dist_store`` coordinator and applied identically on every rank —
-ranks never run mixed geometries (pinned by test).
+ranks never run mixed geometries (pinned by test). Deciding and
+installing are apart: ``decide_after_step`` runs wherever the manager's
+after-commit work runs (an async save's commit thread), and the manager
+installs the decided vector (``tunables.apply_vector``) on the thread
+that drives the takes, between two of them.
 
 Guard rails:
 
@@ -32,6 +36,7 @@ overrides; byte-identical to a build without the tuner).
 from __future__ import annotations
 
 import logging
+import threading
 import time
 from typing import Any, Dict, Optional
 
@@ -71,12 +76,16 @@ def observation_from_report(
 
 
 class Autotuner:
-    """One per CheckpointManager. ``tune_after_step`` is the only entry
-    point; it is called on every rank after every committed step."""
+    """One per CheckpointManager. ``decide_after_step`` is the only
+    entry point; it is called on every rank after every committed step."""
 
     def __init__(self, root: str) -> None:
         self.root = root
         self._state: Optional[tuner_state.TunerState] = None
+        # One decision at a time: two async saves' commit threads may
+        # reach rank 0's decision together. Not held over the exchanges
+        # around it, which wait for peers.
+        self._decide_lock = threading.Lock()
 
     # -- rank-0 decision --------------------------------------------------
 
@@ -202,15 +211,18 @@ class Autotuner:
 
     # -- every-rank entry point -------------------------------------------
 
-    def tune_after_step(
+    def decide_after_step(
         self, step: int, report: Optional[Any], pg_wrapper: Any
     ) -> Optional[Dict[str, Any]]:
-        """Decide (rank 0), broadcast, apply. ``report`` is rank 0's
-        SnapshotReport for the step (ignored elsewhere). Every rank that
-        committed the step must call this — the broadcast is symmetric
-        whether or not rank 0 produced a decision (a failed decision
-        broadcasts the unchanged vector). Returns the vector as applied
-        on this rank."""
+        """Decide (rank 0) and broadcast; the caller installs. ``report``
+        is rank 0's SnapshotReport for the step (ignored elsewhere).
+        Every rank that committed the step must call this — the
+        broadcast is symmetric whether or not rank 0 produced a decision
+        (a failed decision broadcasts the unchanged vector). Returns the
+        decided, clamped vector for ``tunables.apply_vector``, the same
+        on every rank, or None. ``pg_wrapper``'s operations are store
+        exchanges, never collectives; off the thread that drives the
+        job's collectives it is a ``PGWrapper.keyed`` one."""
         from ..scheduler import get_process_memory_budget_bytes
 
         # Measured on EVERY rank (the local_world_size hostname
@@ -231,17 +243,15 @@ class Autotuner:
                     if report is not None and hasattr(report, "to_dict")
                     else report
                 )
-                decided = self._decide(
-                    step, report_dict, memory_budget_bytes=budget
-                )
+                with self._decide_lock:
+                    decided = self._decide(
+                        step, report_dict, memory_budget_bytes=budget
+                    )
             except Exception as e:  # noqa: BLE001 - tuning never fails a save
                 logger.warning("autotuner: decision failed: %r", e)
                 decided = None
         if pg_wrapper.get_world_size() > 1:
-            # Store-based broadcast (never a collective): safe on the
-            # async-save commit thread, same transport every other
-            # rank-0-decides path in the manager uses.
+            # Store-based broadcast (never a collective), same transport
+            # every other rank-0-decides path in the manager uses.
             decided = pg_wrapper.broadcast_object(decided)
-        if decided is None:
-            return None
-        return tunables.apply_vector(decided)
+        return decided
