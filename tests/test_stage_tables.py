@@ -12,10 +12,13 @@ clocks can be aligned (``trace.xplane_offset_us``).
 
 import asyncio
 import glob
+import mmap
 import os
+import resource
 import statistics
 import threading
 import time
+import types
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
@@ -311,6 +314,201 @@ def test_annotate_adds_args_known_after_the_work():
 
 
 # ---------------------------------------------------------------------------
+# The kernel's account on the spans that move bytes and on the envelopes
+# ---------------------------------------------------------------------------
+
+USER, SYS, FAULTS = names.USAGE_ARGS
+THP = "/sys/kernel/mm/transparent_hugepage/enabled"
+# A process that has never faulted is a kernel that keeps no count (gVisor,
+# which the chip's machine runs): ``fault_bytes`` is then absent everywhere.
+COUNTS_FAULTS = resource.getrusage(resource.RUSAGE_SELF).ru_minflt > 0
+
+
+def _one_span(name, work, envelope=False):
+    """The args of one span of ``name`` around ``work()``, as
+    ``trace_annotation`` records it (an envelope through begin / end)."""
+    rec = trace.get_recorder()
+    mark = rec.mark()
+    if envelope:
+        span = tracing.begin(name, path="/x")
+        try:
+            work()
+        finally:
+            tracing.end(span)
+            tracing.end(span)  # the ``finally`` after an early close
+    else:
+        with tracing.trace_annotation(name, bytes=1):
+            work()
+    (event,) = [e for e in rec.events_since(mark) if e["name"] == name]
+    return event["args"]
+
+
+def _burn(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def test_a_sampled_span_counts_the_pages_it_touches_first():
+    if os.path.exists(THP) and "[always]" in open(THP).read():
+        pytest.skip("transparent huge pages are 'always': a fault is 2 MiB, not a page")
+    if not COUNTS_FAULTS:
+        pytest.skip("this kernel counts no minor faults: ru_minflt is 0 for the whole process")
+    size = 64 << 20
+    fresh = mmap.mmap(-1, size)
+    try:
+        def touch():
+            np.frombuffer(fresh, np.uint8)[:: resource.getpagesize()] = 1
+
+        first = _one_span(names.SPAN_STAGE_D2H, touch)
+        again = _one_span(names.SPAN_STAGE_D2H, touch)
+    finally:
+        fresh.close()
+    assert size // 2 <= first[FAULTS] <= size + (8 << 20), first
+    assert again[FAULTS] < size // 16, again
+
+
+@pytest.mark.parametrize("name,envelope", [
+    (names.SPAN_FS_NATIVE_WRITE, False), (names.SPAN_STAGE_D2H, False),
+    (RESTORE, True), (COMMIT, True)])
+def test_a_sampled_span_tells_computing_from_waiting(name, envelope):
+    spun = _one_span(name, lambda: _burn(0.05), envelope)
+    assert spun[USER] + spun[SYS] >= 40_000, spun
+    # A sleeping thread spends nothing; a process's other threads may (an
+    # envelope reads all of them), so only the thread's account is held low.
+    slept = _one_span(name, lambda: time.sleep(0.05), envelope)
+    assert {USER, SYS} <= set(slept) and (FAULTS in slept) == COUNTS_FAULTS
+    if not envelope:
+        assert slept[USER] + slept[SYS] < 5_000, slept
+
+
+def _counting_resource(monkeypatch, counts_faults=True, **without):
+    """``resource`` as utils/tracing.py sees it, its ``getrusage`` calls
+    counted by target; ``without`` names attributes this platform lacks,
+    and a kernel that does not count faults reports none, ever."""
+    calls = []
+
+    def getrusage(who):
+        calls.append(who)
+        usage = resource.getrusage(who)
+        if counts_faults:
+            return usage
+        return types.SimpleNamespace(
+            ru_utime=usage.ru_utime, ru_stime=usage.ru_stime, ru_minflt=0)
+
+    fake = types.SimpleNamespace(
+        getrusage=getrusage, getpagesize=resource.getpagesize,
+        **{k: getattr(resource, k) for k in ("RUSAGE_THREAD", "RUSAGE_SELF")
+           if k not in without})
+    monkeypatch.setattr(tracing, "resource", fake)
+    return calls
+
+
+@pytest.mark.parametrize("name,envelope,who", [
+    (names.SPAN_STAGE_D2H, False, "RUSAGE_THREAD"),
+    (names.SPAN_FS_NATIVE_PWRITEV, False, "RUSAGE_THREAD"),
+    (names.SPAN_FS_NATIVE_DIRECT_WRITE, False, "RUSAGE_THREAD"),
+    (names.SPAN_FS_NATIVE_WRITE, False, "RUSAGE_THREAD"),
+    (COMMIT, True, "RUSAGE_SELF"), (RESTORE, True, "RUSAGE_SELF"),
+    # Outside the two sets: the stall's envelope, and the spans around
+    # the sampled ones.
+    (STAGE, True, None), (TAKE, True, None), (names.SPAN_LEAF_STAGE, False, None),
+    (names.SPAN_STORAGE_WRITE, False, None), (names.SPAN_CAPTURE_CLONE, False, None),
+    # Sampled once, measured, and out again: a restore's byte-moving spans.
+    (names.SPAN_FS_NATIVE_READ, False, None), (names.SPAN_RESTORE_PLACE, False, None),
+    (names.SPAN_RESHARD_COPY, False, None)])
+def test_only_the_listed_names_pay_for_the_account(monkeypatch, name, envelope, who):
+    calls = _counting_resource(monkeypatch)
+    args = _one_span(name, lambda: None, envelope)
+    if who is None:
+        assert calls == [] and not set(names.USAGE_ARGS) & set(args)
+    else:
+        # One sample each side, the second end of an envelope none.
+        assert calls == [getattr(resource, who)] * 2
+        assert {USER, SYS} <= set(args)
+
+
+def test_a_span_that_may_cross_an_await_is_never_sampled(monkeypatch):
+    calls = _counting_resource(monkeypatch)
+    rec = trace.get_recorder()
+    mark = rec.mark()
+    with rec.span(names.SPAN_STAGE_D2H, bytes=1):
+        pass
+    (event,) = rec.events_since(mark)
+    assert calls == [] and event["args"] == {"bytes": 1}
+
+
+@pytest.mark.parametrize("name,envelope,missing", [
+    (names.SPAN_STAGE_D2H, False, "RUSAGE_THREAD"), (RESTORE, True, "RUSAGE_SELF")])
+def test_a_platform_without_the_account_records_nothing(monkeypatch, name, envelope, missing):
+    calls = _counting_resource(monkeypatch, **{missing: True})
+    args = _one_span(name, lambda: None, envelope)
+    assert calls == [] and not set(names.USAGE_ARGS) & set(args)
+    # Every reader of the table finds no such key, not a zero.
+    op = ev(RESTORE, 0, 1000, 1, op=1, **(args if envelope else {}))
+    span = ev(names.SPAN_STAGE_D2H, 10, 100, 2, op=1, **(args if not envelope else {}))
+    table = critpath.stage_tables([op, span])[1]
+    assert "process" not in table and "cpu_s" not in table["stages"][names.SPAN_STAGE_D2H]
+    assert "cpu_s" not in critpath.format_stage_table(table).splitlines()[2]
+
+
+@pytest.mark.parametrize("name,envelope", [(names.SPAN_STAGE_D2H, False), (COMMIT, True)])
+def test_a_kernel_that_counts_no_faults_leaves_them_out(monkeypatch, name, envelope):
+    """gVisor answers ``getrusage`` with CPU seconds and ``ru_minflt`` 0:
+    the span keeps its CPU and says nothing of faults, not zero."""
+    _counting_resource(monkeypatch, counts_faults=False)
+    args = _one_span(name, lambda: _burn(0.02), envelope)
+    assert args[USER] + args[SYS] >= 10_000 and FAULTS not in args
+    events = [ev(STAGE, 0, 1000, 1, op=1), ev(COMMIT, 1000, 9000, 2, op=1, **(args if envelope else {})),
+              ev(names.SPAN_STAGE_D2H, 2000, 500, 3, op=1, **({} if envelope else args))]
+    table = critpath.stage_tables(events)[1]
+    row = table["process"] if envelope else table["stages"][names.SPAN_STAGE_D2H]
+    assert row["cpu_s"] > 0 and "fault_bytes" not in row
+    printed = critpath.format_stage_table(table).splitlines()[-1 if envelope else 2]
+    assert printed.split()[-1] == "-"
+
+
+def test_the_stage_table_sums_the_account_and_prints_it():
+    def usage(user, system, faults):
+        return {USER: user, SYS: system, FAULTS: faults}
+
+    events = [
+        ev(STAGE, 0, 100_000, 1, op=1, path="/s"),
+        ev(COMMIT, 100_000, 2_000_000, 2, op=1, tid=1, path="/s",
+           **usage(3_000_000, 1_000_000, 96 << 20)),
+        ev(names.SPAN_STAGE_D2H, 200_000, 500_000, 3, op=1, tid=2, bytes=1 << 20,
+           **usage(100_000, 50_000, 0)),
+        ev(names.SPAN_STAGE_D2H, 300_000, 500_000, 4, op=1, tid=3, bytes=1 << 20,
+           **usage(200_000, 150_000, 2 << 20)),
+        # A span of the name from a library without the account beside them.
+        ev(names.SPAN_STAGE_D2H, 900_000, 100_000, 5, op=1, tid=2, bytes=1 << 20),
+        ev(names.SPAN_STORAGE_WRITE, 900_000, 400_000, 6, op=1, tid=4, bytes=3 << 20),
+        # Another op's envelope is another op's account.
+        ev(RESTORE, 5_000_000, 1_000_000, 7, op=7, path="/s", **usage(9, 9, 4096)),
+    ]
+    tables = critpath.stage_tables(events)
+    table = tables[1]
+    d2h = table["stages"][names.SPAN_STAGE_D2H]
+    assert (d2h["count"], d2h["cpu_s"], d2h["sys_s"], d2h["fault_bytes"]) == (
+        3, pytest.approx(0.5), pytest.approx(0.2), 2 << 20)
+    assert set(table["stages"][names.SPAN_STORAGE_WRITE]) == {
+        "count", "busy_s", "thread_s", "bytes", "max_open"}
+    assert table["process"] == {"cpu_s": pytest.approx(4.0), "sys_s": pytest.approx(1.0),
+                                "fault_bytes": 96 << 20, "wall_s": pytest.approx(2.0)}
+    assert tables[7]["process"]["fault_bytes"] == 4096
+    text = critpath.format_stage_table(table).splitlines()
+    assert text[1].split()[-4:] == ["cpu_s", "sys_s", "fault", "MiB"]
+    (row,) = [line.split() for line in text if line.split()[0] == names.SPAN_STAGE_D2H]
+    assert row[-3:] == ["0.500", "0.200", "2.0"]
+    (write,) = [line.split() for line in text if line.split()[0] == names.SPAN_STORAGE_WRITE]
+    assert len(write) == len(row) - 3
+    assert text[-1].split() == ["(process)", "2.000", "4.000", "1.000", "96.0"]
+    # Through a Chrome file, as `telemetry trace` reads it, the same table.
+    doc = trace.chrome_trace(events, {})
+    assert critpath.stage_tables(trace.spans_from_chrome(doc))[1]["process"] == table["process"]
+
+
+# ---------------------------------------------------------------------------
 # Names, segments, device programs
 # ---------------------------------------------------------------------------
 
@@ -482,6 +680,45 @@ def test_the_drain_waits_for_the_clones_on_its_own_thread(saved):
                         if e["op"] == op and e["name"] == names.SPAN_STAGE_D2H)
         assert commit["ts"] <= ready["ts"] and ready["ts"] + ready["dur"] <= first_d2h
     assert critpath.segment_for(names.SPAN_CAPTURE_READY) != critpath.SEG_DEVICE_CAPTURE
+
+
+def test_the_envelopes_carry_the_processs_account_and_the_stall_pays_nothing(saved):
+    """A real save and restore: the commit and restore envelopes hold what
+    the process spent between their ends, the byte-moving spans what their
+    threads did, and none of it was sampled on the thread that called
+    ``async_save``: the stall's envelope has no account and no sampled
+    span of the take is on its track."""
+    events = [e for e in saved["events"] if e["ph"] == "X"]
+    sampled = names.SPANS_WITH_THREAD_USAGE | names.SPANS_WITH_PROCESS_USAGE
+    for op, table in _ops(events, "async_take").items():
+        mine = [e for e in events if e["op"] == op]
+        (stage,) = [e for e in mine if e["name"] == STAGE]
+        (commit,) = [e for e in mine if e["name"] == COMMIT]
+        assert not set(names.USAGE_ARGS) & set(stage["args"])
+        assert commit["args"][USER] + commit["args"][SYS] > 0
+        on_caller = [e["name"] for e in mine if e["tid"] == stage["tid"]]
+        assert not sampled & set(on_caller), on_caller
+        moved = [e for e in mine if e["name"] in names.SPANS_WITH_THREAD_USAGE]
+        assert {names.SPAN_STAGE_D2H, names.SPAN_FS_NATIVE_WRITE} <= {e["name"] for e in moved}
+        assert all({USER, SYS} <= set(e["args"]) for e in moved)
+        process = table["process"]
+        assert process["wall_s"] == pytest.approx(commit["dur"] / 1e6, abs=1e-5)
+        assert process["cpu_s"] == pytest.approx(
+            (commit["args"][USER] + commit["args"][SYS]) / 1e6, abs=1e-5)
+        # The write's threads computed inside the envelope, so their CPU
+        # is part of the process's (a tick of the kernel's clock apart).
+        write = table["stages"][names.SPAN_FS_NATIVE_WRITE]
+        assert 0 < write["cpu_s"] <= process["cpu_s"] + 0.02
+        assert ("fault_bytes" in write) == ("fault_bytes" in process) == COUNTS_FAULTS
+        assert write.get("fault_bytes", 0) <= process.get("fault_bytes", 0) + (1 << 20)
+    for op, table in _ops(events, "restore").items():
+        (envelope,) = [e for e in events if e["op"] == op and e["name"] == RESTORE]
+        assert {USER, SYS} <= set(envelope["args"])
+        assert table["process"]["wall_s"] == pytest.approx(table["wall_s"], abs=1e-5)
+        # The envelope alone: no span of a restore pays for a sample.
+        assert not [name for name, row in table["stages"].items() if "cpu_s" in row]
+    assert saved["reports"]["async_take"].critical_path["process"]["cpu_s"] > 0
+    assert "(process)" in critpath.format_stage_table(table)
 
 
 @pytest.mark.parametrize("entry", ["async_save", "save"])

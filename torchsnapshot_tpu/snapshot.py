@@ -317,6 +317,8 @@ def _build_and_emit_report(
                 if cp is not None and table is not None:
                     cp["stages"] = table["stages"]
                     cp["unattributed_s"] = table["unattributed_s"]
+                    if "process" in table:
+                        cp["process"] = table["process"]
                 report.critical_path = cp
             except Exception as e:  # noqa: BLE001 - attribution is best-effort
                 logger.warning(

@@ -497,6 +497,31 @@ def _max_open(intervals: List[Tuple[int, int]]) -> int:
     return peak
 
 
+_PROCESS = "process"
+_USER_US, _SYS_US, _FAULT_BYTES = names.USAGE_ARGS
+
+
+def _add_usage(
+    usage: Dict[str, Dict[str, Any]],
+    key: str,
+    args: Dict[str, Any],
+    wall_us: Optional[int] = None,
+) -> None:
+    """Add one span's kernel account (``names.USAGE_ARGS``, where the
+    span carries it) to ``usage[key]``; with ``wall_us`` its wall too."""
+    if _USER_US not in args:
+        return
+    row = usage.setdefault(key, {"cpu_s": 0.0, "sys_s": 0.0})
+    row["cpu_s"] = round(
+        row["cpu_s"] + (args[_USER_US] + args[_SYS_US]) / 1e6, 6
+    )
+    row["sys_s"] = round(row["sys_s"] + args[_SYS_US] / 1e6, 6)
+    if _FAULT_BYTES in args:  # absent on a kernel that counts no faults
+        row["fault_bytes"] = row.get("fault_bytes", 0) + args[_FAULT_BYTES]
+    if wall_us is not None:
+        row["wall_s"] = round(row.get("wall_s", 0.0) + wall_us / 1e6, 6)
+
+
 def stage_tables(
     events: Sequence[Dict[str, Any]]
 ) -> Dict[int, Dict[str, Any]]:
@@ -508,7 +533,13 @@ def stage_tables(
     ``busy_s`` is the union of the name's intervals (wall seconds at
     least one was open), ``thread_s`` their sum, so ``thread_s /
     busy_s`` is the stage's mean parallelism and ``max_open`` its peak;
-    ``bytes`` sums the spans' ``bytes`` arg. ``unattributed_s`` is the
+    ``bytes`` sums the spans' ``bytes`` arg. A stage whose spans carry
+    the kernel's account (``names.USAGE_ARGS``) also has ``cpu_s`` (user
+    and system seconds its threads spent inside the spans), ``sys_s``
+    (the system part) and, where the kernel counts faults,
+    ``fault_bytes``, and the table a ``process`` entry of the same with
+    ``wall_s``, summed over the envelopes that carry the process's
+    account. ``unattributed_s`` is the
     envelope wall during which no span of the op was open on any
     thread: the op's self time. Spans are not clipped to the envelope —
     report emission and the manager's post-commit work run after it
@@ -542,16 +573,19 @@ def stage_tables(
                 if name == names.SPAN_ASYNC_TAKE_STAGE:
                     unstamped[path] = op
         entry = ops.setdefault(
-            op, {"kind": _KIND_BY_ENVELOPE[name], "windows": []}
+            op, {"kind": _KIND_BY_ENVELOPE[name], "windows": [], "usage": {}}
         )
         entry["windows"].append((lo, hi))
+        _add_usage(entry["usage"], _PROCESS, e.get("args") or {}, hi - lo)
     out: Dict[int, Dict[str, Any]] = {}
     for op, entry in ops.items():
         windows = _merge_intervals(entry["windows"])
         by_name: Dict[str, List[Tuple[int, int]]] = {}
         nbytes: Dict[str, int] = {}
+        usage: Dict[str, Dict[str, Any]] = entry["usage"]
         for e, lo, hi in spans:
             name = e["name"]
+            args = e.get("args") or {}
             if name in _ALL_ENVELOPE_NAMES:
                 continue
             span_op = e.get("op", 0)
@@ -560,9 +594,10 @@ def stage_tables(
             ):
                 continue
             by_name.setdefault(name, []).append((lo, hi))
-            b = (e.get("args") or {}).get("bytes")
+            b = args.get("bytes")
             if isinstance(b, int):
                 nbytes[name] = nbytes.get(name, 0) + b
+            _add_usage(usage, name, args)
         stages: Dict[str, Dict[str, Any]] = {}
         covered: List[Tuple[int, int]] = []
         for name, intervals in by_name.items():
@@ -576,6 +611,7 @@ def stage_tables(
                 ),
                 "bytes": nbytes.get(name, 0),
                 "max_open": _max_open(intervals),
+                **usage.get(name, {}),
             }
         wall_us = sum(hi - lo for lo, hi in windows)
         covered_us = sum(
@@ -589,6 +625,8 @@ def stage_tables(
             ),
             "unattributed_s": round((wall_us - covered_us) / 1e6, 6),
         }
+        if _PROCESS in usage:
+            out[op][_PROCESS] = usage[_PROCESS]
     return out
 
 
@@ -599,14 +637,32 @@ def format_stage_table(table: Dict[str, Any]) -> str:
         f"{table['kind']}: wall {table['wall_s']:.3f} s, "
         f"unattributed {table['unattributed_s']:.3f} s",
         f"  {'stage':<32} {'count':>6} {'busy_s':>9} {'thread_s':>9} "
-        f"{'par':>5} {'max':>4} {'MiB':>9}",
+        f"{'par':>5} {'max':>4} {'MiB':>9} {'cpu_s':>9} {'sys_s':>9} "
+        f"{'fault MiB':>10}",
     ]
+
+    def usage(row: Dict[str, Any]) -> str:
+        if "cpu_s" not in row:
+            return ""
+        faults = row.get("fault_bytes")
+        return f" {row['cpu_s']:>9.3f} {row['sys_s']:>9.3f} " + (
+            f"{'-':>10}" if faults is None else f"{faults / 2**20:>10.1f}"
+        )
+
     for name, row in table["stages"].items():
         par = row["thread_s"] / row["busy_s"] if row["busy_s"] else 0.0
         lines.append(
             f"  {name:<32} {row['count']:>6} {row['busy_s']:>9.3f} "
             f"{row['thread_s']:>9.3f} {par:>5.2f} {row['max_open']:>4} "
-            f"{row['bytes'] / 2**20:>9.1f}"
+            f"{row['bytes'] / 2**20:>9.1f}" + usage(row)
+        )
+    process = table.get(_PROCESS)
+    if process is not None:
+        # The whole process between the ends of the envelopes that
+        # carry its account: every thread, the library's or not.
+        lines.append(
+            f"  {'(process)':<32} {'':>6} {process['wall_s']:>9.3f} "
+            f"{'':>9} {'':>5} {'':>4} {'':>9}" + usage(process)
         )
     return "\n".join(lines)
 

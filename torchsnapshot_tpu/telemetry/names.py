@@ -398,6 +398,39 @@ INSTANT_RSS_PEAK = "rss:peak"
 # telemetry/watchdog.py: an open span outlived the stall deadline
 INSTANT_WATCHDOG_STALL = "watchdog:stall"
 
+# The kernel's account on a span (utils/tracing.py samples getrusage
+# where the span opens and where it closes; the differences join its
+# args): CPU microseconds in user and in system mode, and bytes faulted
+# in (minor faults x the page size; absent where the kernel keeps no
+# count of them, as gVisor does not). Annotated, so that the names lint
+# reads the three as what they are and not as metric names.
+USAGE_ARGS: tuple = ("cpu_user_us", "cpu_sys_us", "fault_bytes")
+# Sampled with RUSAGE_THREAD: the save side's spans that move the bytes
+# off the device and into storage, each open and closed on one executor
+# thread. A thread's account holds what that thread spent and touched
+# first; pages another thread (PJRT's) touched for it are not in it.
+# Exactly the names a reader reads (chipbench's `d2h_cpu_over_wall`,
+# `write_cpu_over_wall`). A sample is a system call with the GIL held
+# (6 us under gVisor), which every thread of the operation waits
+# behind: sampling a restore's read, copy and place spans cost a
+# restore of 299 leaves 1.7-3.7 % (PERF.md section 6), so a restore is
+# sampled at its envelope alone.
+SPANS_WITH_THREAD_USAGE: frozenset = frozenset({
+    SPAN_STAGE_D2H,
+    SPAN_FS_NATIVE_WRITE,
+    SPAN_FS_NATIVE_PWRITEV,
+    SPAN_FS_NATIVE_DIRECT_WRITE,
+})
+# Sampled with RUSAGE_SELF: the envelopes a metric reads, each open and
+# closed on one thread. The whole process between the two ends, the
+# train loop and the runtime's own threads included. The stall's
+# envelope (snapshot:async_take:stage) is not one: RUSAGE_SELF walks
+# every thread of the process, on the caller's thread.
+SPANS_WITH_PROCESS_USAGE: frozenset = frozenset({
+    SPAN_ASYNC_TAKE_COMMIT,
+    SPAN_RESTORE,
+})
+
 # ---------------------------------------------------------------------------
 # Checkpoint-doctor verdict ids (telemetry/doctor.py).
 #

@@ -67,7 +67,7 @@ from typing import (
 )
 
 from .. import knobs
-from . import names
+from . import names, watchdog
 
 logger: logging.Logger = logging.getLogger(__name__)
 
@@ -243,8 +243,6 @@ class SpanRecorder:
             )
         _CONTEXT.set((op, bseq))
         # Outside the lock: may start the watchdog thread.
-        from . import watchdog
-
         watchdog.ensure_started(self)
         return token
 
