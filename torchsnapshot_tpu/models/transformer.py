@@ -61,7 +61,9 @@ class TransformerConfig:
     n_heads: int = 8
     n_layers: int = 4
     d_ff: int = 2048
-    n_experts: int = 0  # 0 = dense MLP in every block
+    # The dense family: the soft-routed experts of every MoE block (0 =
+    # dense MLP in every block). kimi_linear: the experts this device holds.
+    n_experts: int = 0
     moe_every: int = 2  # every k-th block is MoE (when n_experts > 0)
     dtype: Any = jnp.bfloat16
     learning_rate: float = 1e-3
@@ -81,6 +83,31 @@ class TransformerConfig:
     # other leaf leaves a step as the bits it entered with, and the
     # optimizer state holds moments for the adapters alone.
     lora_rank: int = 0
+    # "dense": the block below. "kimi_linear": Kimi Linear's layers
+    # (models/kimi_linear.py), sized by the fields that follow; the dense
+    # family reads none of them.
+    family: str = "dense"
+    # One letter a layer, the first n_layers taken: "K" KDA linear
+    # attention, "M" latent attention (MLA).
+    layer_kinds: str = ""
+    # The router's width; n_experts above then counts the experts this
+    # device holds, from first_expert on (expert parallelism's share).
+    n_routed_experts: int = 0
+    first_expert: int = 0
+    experts_per_token: int = 0
+    n_shared_experts: int = 0
+    expert_d_ff: int = 0
+    n_dense_layers: int = 0  # leading layers with a dense SwiGLU of width d_ff
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    kda_head_dim: int = 0
+    conv_kernel: int = 0
+    routed_scaling_factor: float = 1.0
+    # What each step moves a router's correction bias by, per expert,
+    # against the sign of its load's excess.
+    router_bias_rate: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -109,12 +136,29 @@ def make_mesh(
     return Mesh(arr, axis_names=("dp", "sp", "tp"))
 
 
+FAMILIES = ("dense", "kimi_linear")
+
+
+def _other_family(cfg: TransformerConfig):
+    """The module of a family other than the dense one, or None."""
+    if cfg.family not in FAMILIES:
+        raise ValueError(f"unknown family {cfg.family!r}; expected one of {FAMILIES}")
+    if cfg.family == "dense":
+        return None
+    from . import kimi_linear
+
+    return kimi_linear
+
+
 def _is_moe_layer(cfg: TransformerConfig, i: int) -> bool:
     return cfg.n_experts > 0 and (i % cfg.moe_every == cfg.moe_every - 1)
 
 
 def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Params:
     """NamedSharding pytree matching :func:`init_params` structure."""
+    other = _other_family(cfg)
+    if other is not None:
+        return other.param_shardings(cfg, mesh)
 
     def ns(*spec: Any) -> NamedSharding:
         return NamedSharding(mesh, P(*spec))
@@ -161,6 +205,9 @@ def init_params(
     materializes only its own shard (no full-model host copy — matters for
     the 20 GB-class benchmark configs).
     """
+    other = _other_family(cfg)
+    if other is not None:
+        return other.init_params(cfg, rng, mesh=mesh)
 
     def _init(rng: jax.Array) -> Params:
         n_keys = 3 + 5 * cfg.n_layers
@@ -228,12 +275,16 @@ def _constrain(x: jax.Array, mesh: Optional[Mesh], *spec: Any) -> jax.Array:
 
 
 def _moe_mlp(block: Params, x: jax.Array) -> jax.Array:
-    """Soft-routed MoE: every expert computed, outputs gate-combined.
+    """Soft-routed MoE: every expert computes every token, outputs
+    softmax-gate-combined.
 
-    Shape-static (no dynamic dispatch), so it jits cleanly and the expert
-    einsums shard over the ``sp`` (=ep) axis via the stacked-weight
-    shardings. Token-dropping top-k dispatch with all_to_all is a later
-    optimization; for checkpointing purposes the state layout is identical.
+    This is what the dense family's ``n_experts`` gives. Shape-static, so
+    it jits cleanly and the expert einsums shard over the ``sp`` (=ep) axis
+    via the stacked-weight shardings. Top-k dispatch, where each expert
+    computes only the tokens routed to it, is the ``kimi_linear`` family's
+    expert layer (``kimi_linear._held_experts``: sort by expert, grouped
+    matmuls); its state holds a router bias and gate/up/down stacks, so the
+    two layouts differ.
     """
     gates = jax.nn.softmax(
         jnp.einsum("bsd,de->bse", x, block["router"].astype(jnp.float32)), axis=-1
@@ -419,6 +470,9 @@ def init_train_state(
     seed: int = 0,
     mesh: Optional[Mesh] = None,
 ) -> TrainState:
+    other = _other_family(cfg)
+    if other is not None:
+        return other.init_train_state(cfg, seed=seed, mesh=mesh)
     rng = jax.random.PRNGKey(seed)
     params = init_params(cfg, rng, mesh=mesh)
     opt = _optimizer(cfg)
@@ -461,6 +515,9 @@ def make_train_step(
     """Build the jitted full training step (fwd + loss + bwd + adamw).
     With ``cfg.lora_rank`` the loss is differentiated, and adamw run,
     over the adapters alone."""
+    other = _other_family(cfg)
+    if other is not None:
+        return other.make_train_step(cfg, mesh=mesh)
     opt = _optimizer(cfg)
 
     def loss_fn(params: Params, tokens: jax.Array) -> jax.Array:
