@@ -1,6 +1,8 @@
-"""ctypes bindings for the native I/O runtime (native/ts_io.cpp).
+"""ctypes bindings for the native I/O runtime (native/ts_io.cpp) and the
+numpy allocation handler of the save side's D2H copies
+(native/ts_slab_alloc.cpp, :func:`slab_alloc`).
 
-The shared library is compiled on first use with the host toolchain and
+Each shared library is compiled on first use with the host toolchain and
 cached next to the source (falling back to a temp dir when the package is
 installed read-only). Everything degrades gracefully: if no C++ compiler
 is available or the build fails, ``lib()`` returns ``None`` and callers
@@ -9,7 +11,9 @@ use their pure-Python paths — behavior is identical, only slower.
 Why ctypes and not a CPython extension: ctypes releases the GIL around
 every foreign call, which is exactly what the scheduler's executor threads
 need (N threads → N concurrent pwrite/pread streams), and it keeps the
-package importable on machines with no toolchain.
+package importable on machines with no toolchain. The allocation handler
+calls into Python and numpy's C API, so it is loaded with ``ctypes.PyDLL``,
+which keeps the GIL held.
 """
 
 from __future__ import annotations
@@ -20,6 +24,7 @@ import logging
 import os
 import shutil
 import subprocess
+import sys
 import tempfile
 import threading
 from typing import List, Optional, Sequence, Tuple
@@ -43,28 +48,39 @@ def _compiler() -> Optional[str]:
     return None
 
 
-def _build_and_load() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_SRC_PATH):
-        logger.warning("native source missing at %s", _SRC_PATH)
+def _build_and_load(
+    src_path: str = _SRC_PATH,
+    flags: Sequence[str] = (),
+    loader: type = ctypes.CDLL,
+    abi: str = "",
+) -> Optional[ctypes.CDLL]:
+    """Compile ``src_path`` (with ``flags`` besides the usual) into a
+    shared library cached beside it, or reuse the one cached there, and
+    load it with ``loader``; None where that fails. ``abi`` names what the
+    library was built against besides its source and compiler (part of
+    the cache key)."""
+    if not os.path.exists(src_path):
+        logger.warning("native source missing at %s", src_path)
         return None
     cc = _compiler()
     if cc is None:
         logger.info("no C++ compiler found; using pure-Python I/O paths")
         return None
-    with open(_SRC_PATH, "rb") as f:
+    with open(src_path, "rb") as f:
         src = f.read()
-    tag = hashlib.sha256(src + cc.encode()).hexdigest()[:16]
+    tag = hashlib.sha256(src + cc.encode() + abi.encode()).hexdigest()[:16]
+    stem = os.path.splitext(os.path.basename(src_path))[0]
     candidates = [
-        os.path.join(os.path.dirname(_SRC_PATH), f"_ts_io_{tag}.so"),
+        os.path.join(os.path.dirname(src_path), f"_{stem}_{tag}.so"),
         os.path.join(
             tempfile.gettempdir(), f"torchsnapshot_tpu_{os.getuid()}",
-            f"_ts_io_{tag}.so",
+            f"_{stem}_{tag}.so",
         ),
     ]
     for so_path in candidates:
         if os.path.exists(so_path):
             try:
-                return ctypes.CDLL(so_path)
+                return loader(so_path)
             except OSError:
                 pass  # stale/corrupt cache: rebuild below
         out_dir = os.path.dirname(so_path)
@@ -77,7 +93,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
             os.close(fd)
             cmd = [
                 cc, "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-                _SRC_PATH, "-o", tmp_out,
+                *flags, src_path, "-o", tmp_out,
             ]
             proc = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=120
@@ -89,7 +105,7 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
                 return None
             os.replace(tmp_out, so_path)
             tmp_out = None
-            return ctypes.CDLL(so_path)
+            return loader(so_path)
         except (OSError, subprocess.TimeoutExpired) as e:
             logger.debug("native build in %s failed: %s", out_dir, e)
             continue
@@ -118,6 +134,49 @@ def lib() -> Optional[ctypes.CDLL]:
             _lib = l
             _load_attempted = True
     return _lib
+
+
+_SLAB_SRC_PATH = os.path.join(
+    os.path.dirname(__file__), "native", "ts_slab_alloc.cpp"
+)
+_slab_lib: Optional[ctypes.PyDLL] = None
+_slab_load_attempted = False
+
+
+def slab_alloc() -> Optional[ctypes.PyDLL]:
+    """The numpy allocation handler of ``native/ts_slab_alloc.cpp``
+    (``d2h_slabs``), loaded with the GIL held around its calls, or None
+    (native disabled, no toolchain or headers). It embeds the CPython and
+    numpy ABIs, so their versions are part of its cache key."""
+    global _slab_lib, _slab_load_attempted
+    if knobs.is_native_disabled():
+        return None
+    if _slab_load_attempted:
+        return _slab_lib
+    with _lock:
+        if not _slab_load_attempted:
+            import sysconfig
+
+            import numpy
+
+            flags = [
+                "-I" + sysconfig.get_paths()["include"],
+                "-I" + numpy.get_include(),
+            ]
+            abi = f"python {sys.hexversion} numpy {numpy.__version__}"
+            l = _build_and_load(_SLAB_SRC_PATH, flags, ctypes.PyDLL, abi)
+            if l is not None:
+                l.ts_slabs_init.argtypes = [
+                    ctypes.py_object, ctypes.py_object, ctypes.c_ssize_t,
+                ]
+                l.ts_slabs_init.restype = ctypes.c_int
+                l.ts_slabs_enter.argtypes = []
+                l.ts_slabs_enter.restype = ctypes.c_int
+                l.ts_slabs_exit.argtypes = []
+                l.ts_slabs_exit.restype = None
+            _slab_lib = l
+            _slab_load_attempted = True
+    return _slab_lib
 
 
 def _declare(l: ctypes.CDLL) -> None:

@@ -1,4 +1,5 @@
-"""Recycled host destinations for restore reads.
+"""Recycled host destinations for restore reads and for the save side's
+device-to-host copies.
 
 A restore reads every leaf into host memory and places it on a device
 from there. Memory the process has never touched costs a page fault per
@@ -17,16 +18,24 @@ of its reads that has to be copied into them takes one more slab as its
 read buffer, which comes back when the copy returns, with no device to ask.
 
 :class:`DestinationPool` is the process's slabs; :func:`process_pool` is the
-one instance restores share. It keeps its free slabs between restores, up to
-the largest cap a restore gave it (``retained_bytes``), until the process ends
-or ``clear()`` is called. :class:`DestinationLeases` is one read pipeline's
-side of it: the slabs that pipeline has out, its cap, and its reads waiting
-for a slab.
+one instance restores and saves share. It keeps its free slabs between
+operations, up to the largest cap one gave it (``retained_bytes``), until the
+process ends or ``clear()`` is called. :class:`DestinationLeases` is one
+read pipeline's side of it: the slabs that pipeline has out, its cap, and
+its reads waiting for a slab.
 
 Only a placement that copies may take a slab: the CPU backend's
 ``device_put`` can alias an aligned numpy buffer, and a recycled slab would
 then rewrite an array the application holds (``snapshot._restore_destination``
 decides; nothing here knows about devices).
+
+The save side leases from the same pool (``d2h_slabs``): inside a
+device-snapshot take's staging window the host array a D2H copy lands in
+is a slab of exactly its size (``try_take`` under a cap of the window plus
+its largest request), given back when numpy frees the array. A state's
+leaves have the same shapes when saved and restored, so each side recycles
+the other's slabs. The pool retains the largest cap either side has given
+it: after saves alone, the window plus the largest request.
 """
 
 import asyncio
@@ -62,8 +71,8 @@ _STARVED_RECHECK_S = 0.1
 
 
 class Slab:
-    """``nbytes`` of page-aligned host memory. ``recycled`` says a read
-    landed in it before: its pages are faulted in."""
+    """``nbytes`` of page-aligned host memory. ``recycled`` says a read or
+    a device-to-host copy landed in it before: its pages are faulted in."""
 
     __slots__ = ("array", "recycled")
 
@@ -286,7 +295,7 @@ _PROCESS_POOL = DestinationPool()
 
 
 def process_pool() -> DestinationPool:
-    """The pool every restore of this process shares."""
+    """The pool every restore and save of this process shares."""
     return _PROCESS_POOL
 
 

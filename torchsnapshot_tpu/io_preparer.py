@@ -36,7 +36,7 @@ from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from . import knobs
+from . import d2h_slabs, knobs
 from .io_types import BufferConsumer, BufferStager, BufferType, ReadReq, WriteReq
 from .manifest import (
     ArrayEntry,
@@ -321,12 +321,28 @@ class ArrayBufferStager(BufferStager):
             arr = arr[self.slc]
         if is_jax_array(arr):
             # jax.Array is immutable: the host copy is consistent even for
-            # async snapshots, with no defensive copy.
+            # async snapshots, with no defensive copy. Inside a staging
+            # window the copy lands in a recycled slab (d2h_slabs), which
+            # goes back when the write drops ``host``.
+            nbytes = int(arr.nbytes)
             with trace_annotation(
-                metric_names.SPAN_STAGE_D2H, bytes=int(arr.nbytes)
-            ):
-                host = np.asarray(arr)
+                metric_names.SPAN_STAGE_D2H, bytes=nbytes
+            ) as span:
+                with d2h_slabs.lease(nbytes) as lease:
+                    host = np.asarray(arr)
+                if lease is not None:
+                    span.annotate(
+                        **{
+                            metric_names.ARG_LEASED_BYTES: lease.leased_bytes,
+                            metric_names.ARG_RECYCLED_BYTES: lease.recycled_bytes,
+                        }
+                    )
             host = np.ascontiguousarray(host)
+            if lease is not None and lease.leased_bytes:
+                # The device array caches ``host``: it goes now, and
+                # the write's reference is the slab's last.
+                self.arr = arr = None
+                d2h_slabs.drop_runtime_refs()
         else:
             host = np.asarray(arr)
             if self.is_async_snapshot and not self._captured:

@@ -42,7 +42,7 @@ if TYPE_CHECKING:  # pragma: no cover - typing only
     from .dest_pool import DestinationLeases
     from .telemetry.progress import ProgressTracker
 
-from . import knobs, telemetry
+from . import d2h_slabs, knobs, telemetry
 from .dest_pool import Lease
 from .telemetry.trace import get_recorder as _trace_recorder
 from .utils.tracing import run_in_executor, trace_annotation
@@ -692,7 +692,11 @@ async def execute_write_reqs(
                 stats.write_variant_bytes.get(variant, 0) + buf_len
             )
         finally:
+            # The bytes go before their reservation does: a slab under
+            # them (d2h_slabs) is back in the pool before the next
+            # request is admitted.
             del buf
+            write_io = None
             await budget.release(buf_len)
         stats.done += 1
         stats.bytes_moved += buf_len
@@ -843,23 +847,23 @@ class DeferredIOWork:
 
     def sync_complete(self, event_loop: asyncio.AbstractEventLoop) -> None:
         self._await_device_clones()
-        pool = StagingPool(
-            self._memory_budget_bytes,
-            request_bytes=[
-                r.buffer_stager.get_staging_cost_bytes()
-                for r in self.write_reqs
-            ],
-        )
-        inner = event_loop.run_until_complete(
-            execute_write_reqs(
-                write_reqs=self.write_reqs,
-                storage=self._storage,
-                memory_budget_bytes=self._memory_budget_bytes,
-                rank=self._rank,
-                progress=self._progress,
-                staging_pool=pool,
+        request_bytes = [
+            r.buffer_stager.get_staging_cost_bytes() for r in self.write_reqs
+        ]
+        pool = StagingPool(self._memory_budget_bytes, request_bytes=request_bytes)
+        # The pipeline's tasks copy this context: their D2H copies land
+        # in recycled slabs, as many as the window holds.
+        with d2h_slabs.staging_window(request_bytes, pool.total_bytes):
+            inner = event_loop.run_until_complete(
+                execute_write_reqs(
+                    write_reqs=self.write_reqs,
+                    storage=self._storage,
+                    memory_budget_bytes=self._memory_budget_bytes,
+                    rank=self._rank,
+                    progress=self._progress,
+                    staging_pool=pool,
+                )
             )
-        )
         self._inner = inner
         # The inner pipeline's table is the live one; expose it so the
         # caller's checksum-table write (and an incremental take's
