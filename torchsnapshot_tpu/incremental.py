@@ -287,17 +287,27 @@ def clone_bytes_by_device(flattened: Dict[str, Any]) -> Dict[Any, int]:
     return need
 
 
+def room_margin(nbytes: int) -> int:
+    """What a device must keep free beside a clone of ``nbytes``."""
+    return max(nbytes // _ROOM_MARGIN_SHARE, _ROOM_MARGIN_MIN_BYTES)
+
+
+def memory_stats(device: Any) -> Optional[Dict[str, int]]:
+    """``device.memory_stats()``: the one place the capture's account of
+    room reads it."""
+    return device.memory_stats()
+
+
 def room_for_clone(flattened: Dict[str, Any]) -> bool:
     """Whether every device's ``memory_stats()`` shows room for the clone
     of the take's jax sources: ``bytes_limit - bytes_in_use`` at least its
     bytes there and the margin. A device that keeps no such account (the
     CPU backend) sets no limit this can see."""
     for device, nbytes in clone_bytes_by_device(flattened).items():
-        stats = device.memory_stats()
+        stats = memory_stats(device)
         if not stats or "bytes_limit" not in stats:
             continue
-        margin = max(nbytes // _ROOM_MARGIN_SHARE, _ROOM_MARGIN_MIN_BYTES)
-        if stats["bytes_limit"] - stats.get("bytes_in_use", 0) < nbytes + margin:
+        if stats["bytes_limit"] - stats.get("bytes_in_use", 0) < nbytes + room_margin(nbytes):
             return False
     return True
 

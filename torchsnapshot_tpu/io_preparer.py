@@ -32,7 +32,7 @@ import functools
 import logging
 import sys
 from concurrent.futures import Executor
-from typing import Any, Callable, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -878,21 +878,116 @@ def prepare_write(
 class CapturedSources(NamedTuple):
     """What :func:`capture_write_reqs` pinned: the number of distinct
     sources, the on-device clones among them (not ready yet: the drain
-    waits for them, ``scheduler.DeferredIOWork``), and how the jax
-    sources were cloned: by how many group programs, how many leaves in
-    those, and how many leaves one by one."""
+    waits for them, ``scheduler.DeferredIOWork``), how the jax sources
+    were cloned (by how many group programs, how many leaves in those,
+    how many leaves one by one), and the bytes cloned and copied to the
+    host for want of room, beside the room the take saw (None where no
+    device keeps an account)."""
 
     sources: int
     device_clones: List[Any]
     clone_programs: int
     clone_leaves: int
     fallback_leaves: int
+    clone_bytes: int = 0
+    host_copy_bytes: int = 0
+    room_bytes: Optional[int] = None
 
     @property
     def device_programs(self) -> int:
         """Programs the device clones come from: a clone that no group
         program made is one of its own."""
         return self.clone_programs + len(self.device_clones) - self.clone_leaves
+
+
+def _bytes_by_device(arr: Any) -> Dict[Any, int]:
+    devices = arr.sharding.device_set
+    if len(devices) == 1:
+        return {next(iter(devices)): int(arr.nbytes)}
+    need: Dict[Any, int] = {}
+    for shard in arr.addressable_shards:
+        need[shard.device] = need.get(shard.device, 0) + int(shard.data.nbytes)
+    return need
+
+
+def _plan_clones(sources: List[Any]) -> Tuple[int, Optional[int]]:
+    """How many of the take's jax sources, in the take's order, to clone
+    on the device, and the room the take saw (None where no device keeps
+    an account). The room on a device is ``bytes_limit - bytes_in_use -
+    peak_bytes_reserved``, one ``memory_stats()`` a take: the TPU runtime
+    reserves a program's temporaries (a train step's gradients and
+    activations) at the bottom of memory, outside ``bytes_in_use``, and
+    its high-water mark holds them once the step has run, so a loop's
+    saves see the same room. Every source is cloned where all of them fit
+    beside ``incremental.room_margin``; else the longest prefix that
+    does, so the split is a function of the layout and the room. Where
+    the clone of every source does not fit and no program has reserved
+    anything yet (a save before the first step), the step's need is
+    unknown: nothing is cloned, and a WARNING says so."""
+    from .incremental import memory_stats, room_margin
+
+    needs = [_bytes_by_device(arr) for arr in sources]
+    rooms: Dict[Any, int] = {}
+    unread: List[Any] = []
+    for device in {d for need in needs for d in need}:
+        stats = memory_stats(device)
+        if not stats or "bytes_limit" not in stats:
+            continue
+        reserve = stats.get("peak_bytes_reserved", 0)
+        if not reserve:
+            unread.append(device)
+        rooms[device] = stats["bytes_limit"] - stats.get("bytes_in_use", 0) - reserve
+    if not rooms:
+        return len(sources), None
+    room = sum(rooms.values())
+    used: Dict[Any, int] = {}
+    for count, need in enumerate(needs):
+        after = {d: used.get(d, 0) + n for d, n in need.items() if d in rooms}
+        if any(n + room_margin(n) > rooms[d] for d, n in after.items()):
+            break
+        used.update(after)
+    else:
+        return len(sources), room
+    if unread:
+        logger.warning(
+            "async take: no device room for a whole clone and no program "
+            "has reserved memory on %s yet (a save before the first step): "
+            "copying every source to the host, so the step keeps its room",
+            sorted(map(str, unread)),
+        )
+        return 0, room
+    return count, room
+
+
+def _copy_to_host(sources: List[Any], cache: dict) -> None:
+    """Copy jax sources to the host now, into ``cache``: the bytes of each
+    land in a slab of the staging pool (``d2h_slabs``), several transfers
+    in flight. Each waits for the device to reach its source."""
+    import contextvars
+    from concurrent.futures import ThreadPoolExecutor
+
+    from .scheduler import LINK_TRANSFERS_IN_FLIGHT
+
+    def copy(arr: Any) -> np.ndarray:
+        nbytes = int(arr.nbytes)
+        with trace_annotation(
+            metric_names.SPAN_CAPTURE_HOST_COPY, kind="no_room", bytes=nbytes
+        ):
+            with d2h_slabs.lease(nbytes):
+                return np.ascontiguousarray(np.asarray(arr))
+
+    sizes = [int(arr.nbytes) for arr in sources]
+    with d2h_slabs.staging_window(sizes, sum(sizes)):
+        with ThreadPoolExecutor(
+            min(len(sources), LINK_TRANSFERS_IN_FLIGHT),
+            thread_name_prefix="ts_capture_host_copy",
+        ) as pool:
+            futures = [
+                pool.submit(contextvars.copy_context().run, copy, arr)
+                for arr in sources
+            ]
+            for arr, fut in zip(sources, futures):
+                cache[id(arr)] = fut.result()
 
 
 def _clone_device_groups(
@@ -964,21 +1059,38 @@ def capture_write_reqs(
     work alone, while the set of leaves such a take writes is what its
     skip decisions leave, another in every save: a program over all of
     them would be compiled anew nearly every time. Each source is then a
-    program of its own, compiled once a shape."""
+    program of its own, compiled once a shape.
+
+    Where a device has no room for the clone of every source beside the
+    process's reserve (:func:`_plan_clones`), the sources that fit are
+    cloned and the others are copied to the host before this returns
+    (``capture:host_copy`` spans of kind ``no_room``): a planned path,
+    the same split from save to save, not a fallback."""
     cache: dict = {}
     # A leaf sliced into chunk or shard stagers, or held by a slab's
     # member, is one source. The dict keeps every source alive, and so
     # its ``id`` its own, until the pass is over.
-    jax_sources = {
+    jax_sources = list({
         id(arr): arr
         for req in write_reqs
         for arr in req.buffer_stager.jax_sources()
-    }
+    }.values())
+    cloned, room = _plan_clones(jax_sources)
+    to_clone, to_host = jax_sources[:cloned], jax_sources[cloned:]
     programs, leaves = _clone_device_groups(
-        list(jax_sources.values()),
-        cache,
-        group_max=1 if queue_drained else _CLONE_GROUP_MAX,
+        to_clone, cache, group_max=1 if queue_drained else _CLONE_GROUP_MAX
     )
+    if to_host:
+        logger.info(
+            "async take: no device room for a whole clone (%d bytes free "
+            "beyond the process's reserve): cloning %d of %d jax sources, "
+            "copying %d bytes to the host before returning",
+            room,
+            len(to_clone),
+            len(jax_sources),
+            sum(int(arr.nbytes) for arr in to_host),
+        )
+        _copy_to_host(to_host, cache)
     for req in write_reqs:
         req.buffer_stager.capture(cache, leaf=req.path)
     return CapturedSources(
@@ -986,7 +1098,10 @@ def capture_write_reqs(
         device_clones=[v for v in cache.values() if is_jax_array(v)],
         clone_programs=programs,
         clone_leaves=leaves,
-        fallback_leaves=len(jax_sources) - leaves,
+        fallback_leaves=len(to_clone) - leaves,
+        clone_bytes=sum(int(arr.nbytes) for arr in to_clone),
+        host_copy_bytes=sum(int(arr.nbytes) for arr in to_host),
+        room_bytes=room,
     )
 
 

@@ -14,7 +14,8 @@ attention), the first ``n_layers`` of the string taken:
   (``q_lora_rank`` null): keys and values from a ``kv_lora_rank`` latent, a
   ``qk_rope_head_dim`` key part shared by the heads, causal softmax.
 - The first ``n_dense_layers`` layers have a dense SwiGLU of width ``d_ff``;
-  every other layer an **expert layer**: sigmoid scores over
+  every other layer an **expert layer** (``models/experts.py``, shared with
+  the ``glm_dsa`` family): sigmoid scores over
   ``n_routed_experts``, the top ``experts_per_token`` by score plus a
   correction bias, the chosen scores renormalised and scaled by
   ``routed_scaling_factor``; this device holds experts ``first_expert ..
@@ -36,28 +37,29 @@ with the expert dimension over ``sp`` (ep = sp), small leaves replicated.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from ..ops.attention import causal_attention
 from ..ops.kda import chunk_kda
-from .transformer import Params, TrainState, TransformerConfig, _optimizer
+from . import experts
+from .experts import BIAS
+from .experts import Leaf as _Leaf
+from .experts import biases as _biases  # noqa: F401 - tests route through these
+from .experts import dispatch as _dispatch  # noqa: F401
+from .experts import route as _route  # noqa: F401
+from .experts import swiglu as _swiglu
+from .experts import trainable as _trainable  # noqa: F401
+from .experts import with_biases as _with_biases
+from .transformer import Params, TrainState, TransformerConfig
 
 EPS = 1e-5
-BIAS = "router_bias"
-
-
-@dataclasses.dataclass(frozen=True)
-class _Leaf:
-    shape: Tuple[int, ...]
-    spec: Tuple[Any, ...]
-    init: str = "normal"  # normal (1/sqrt(fan_in)), ones, zeros, a_log, dt_bias
-    dtype: Any = None  # None: cfg.dtype
 
 
 _DENSE_ONLY = ("attn_impl", "lora_rank", "moe_every")
@@ -148,45 +150,24 @@ def _layout(cfg: TransformerConfig) -> Params:
     }
 
 
-def _is_leaf(x: Any) -> bool:
-    return isinstance(x, _Leaf)
-
-
 def param_shardings(cfg: TransformerConfig, mesh: Mesh) -> Params:
-    return jax.tree_util.tree_map(
-        lambda leaf: NamedSharding(mesh, P(*leaf.spec)), _layout(cfg), is_leaf=_is_leaf
-    )
+    return experts.shardings(_layout(cfg), mesh)
 
 
 def _init_leaf(cfg: TransformerConfig, leaf: _Leaf, key: jax.Array) -> jax.Array:
     dtype = leaf.dtype or cfg.dtype
-    if leaf.init == "ones":
-        return jnp.ones(leaf.shape, dtype)
-    if leaf.init == "zeros":
-        return jnp.zeros(leaf.shape, dtype)
     if leaf.init == "a_log":  # A ~ U(1, 16), as FLA's KDA initialises it
         return jnp.log(jax.random.uniform(key, leaf.shape, jnp.float32, 1.0, 16.0)).astype(dtype)
     if leaf.init == "dt_bias":  # softplus^-1 of dt ~ logU(1e-3, 1e-1), as FLA's
         dt = jnp.exp(jax.random.uniform(key, leaf.shape, jnp.float32,
                                         math.log(1e-3), math.log(1e-1)))
         return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-    std = 1.0 / math.sqrt(leaf.shape[-2])
-    return (jax.random.normal(key, leaf.shape, jnp.float32) * std).astype(dtype)
+    return experts.init_leaf(cfg, leaf, key)
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array, mesh: Optional[Mesh] = None) -> Params:
     """Initialise on the device, each device materialising its own shard."""
-    layout = _layout(cfg)
-
-    def _init(rng: jax.Array) -> Params:
-        leaves, treedef = jax.tree_util.tree_flatten(layout, is_leaf=_is_leaf)
-        keys = jax.random.split(rng, len(leaves))
-        return jax.tree_util.tree_unflatten(
-            treedef, [_init_leaf(cfg, leaf, key) for leaf, key in zip(leaves, keys)])
-
-    if mesh is None:
-        return jax.jit(_init)(rng)
-    return jax.jit(_init, out_shardings=param_shardings(cfg, mesh))(rng)
+    return experts.init_params(_layout(cfg), functools.partial(_init_leaf, cfg), rng, mesh)
 
 
 # ----------------------------------------------------------------------
@@ -204,10 +185,6 @@ def _l2norm(x: jax.Array) -> jax.Array:
     x32 = x.astype(jnp.float32)
     return (x32 * jax.lax.rsqrt(jnp.sum(jnp.square(x32), axis=-1, keepdims=True) + 1e-6)).astype(
         x.dtype)
-
-
-def _swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
 def _causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
@@ -252,72 +229,8 @@ def _mla(cfg: TransformerConfig, p: Params, x: jax.Array) -> jax.Array:
     return attn.reshape(b, s, h * dv) @ p["wo"]
 
 
-def _route(cfg: TransformerConfig, p: Params, x: jax.Array):
-    """(chosen experts (t, k), their weights (t, k) float32, load over every
-    expert (n_routed_experts,) float32)."""
-    scores = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(p[BIAS]), cfg.experts_per_token)
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    return chosen, weights * cfg.routed_scaling_factor, _counts(chosen, cfg.n_routed_experts)
-
-
-def _counts(ids: jax.Array, n: int) -> jax.Array:
-    """How many of ``ids`` are each of ``0 .. n - 1``, float32: a compare and
-    a sum, whose cost no value of ``ids`` changes (a scatter-add's can)."""
-    return jnp.sum(ids.reshape(-1, 1) == jnp.arange(n), axis=0, dtype=jnp.float32)
-
-
-def _dispatch(cfg: TransformerConfig, chosen: jax.Array):
-    """The (token, slot) assignments sorted by held expert, the others last:
-    (the order, whether each sorted row is held, the group sizes). The others
-    are counted into the last held expert's group, so the groups cover every
-    row whatever the routing."""
-    t, k = chosen.shape
-    local = (chosen - cfg.first_expert).reshape(-1)
-    held = (local >= 0) & (local < cfg.n_experts)
-    order = jnp.argsort(jnp.where(held, local, cfg.n_experts), stable=True)
-    sizes = _counts(jnp.where(held, local, -1), cfg.n_experts).astype(jnp.int32)
-    sizes = sizes.at[-1].add(t * k - jnp.sum(sizes))
-    return order, jnp.take(held, order), sizes
-
-
-def _held_experts(cfg: TransformerConfig, p: Params, x: jax.Array, chosen: jax.Array,
-                  weights: jax.Array) -> jax.Array:
-    """What the held experts give, computed for the tokens routed to them
-    alone: the (token, slot) assignments sorted by held expert, run through
-    grouped matmuls, put back in (token, slot) order and summed by their
-    weights.
-
-    The assignments to other devices' experts go through the last held
-    expert's matmuls as rows of zeros, which give zeros and add nothing to a
-    gradient. So the grouped matmuls do the same work whatever the routing
-    lands on: with those rows left out of every group, the step's time on a
-    v5e followed how many assignments the seed's routing held (PERF.md)."""
-    t, k = chosen.shape
-    order, mine, sizes = _dispatch(cfg, chosen)
-    rows = jnp.where(mine[:, None], jnp.take(x, order // k, axis=0), 0)
-
-    def grouped(lhs: jax.Array, w: jax.Array) -> jax.Array:
-        return jax.lax.ragged_dot(lhs, w, sizes)
-
-    out = grouped(jax.nn.silu(grouped(rows, p["w_gate"])) * grouped(rows, p["w_up"]),
-                  p["w_down"])
-    unsort = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
-    out = jnp.take(out, unsort, axis=0).reshape(t, k, -1)
-    return jnp.einsum("tkd,tk->td", out, weights.astype(out.dtype))
-
-
 def _moe(cfg: TransformerConfig, p: Params, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    b, s, d = x.shape
-    flat = x.reshape(b * s, d)
-    with jax.named_scope("kimi:router"):
-        chosen, weights, load = _route(cfg, p, flat)
-    with jax.named_scope("kimi:experts"):
-        y = _held_experts(cfg, p, flat, chosen, weights)
-    with jax.named_scope("kimi:shared"):
-        y = y + _swiglu(flat, p["shared_gate"], p["shared_up"], p["shared_down"])
-    return y.reshape(b, s, d), load
+    return experts.moe(cfg, p, x, "kimi")
 
 
 def forward(cfg: TransformerConfig, params: Params,
@@ -351,41 +264,17 @@ def forward(cfg: TransformerConfig, params: Params,
 # ----------------------------------------------------------------------
 
 
-def _trainable(params: Params) -> Params:
-    """``params`` (or a tree laid out like it) without the routers' biases:
-    the tree the optimizer sees."""
-    return {**params, "layers": [
-        {**block, "moe": {k: v for k, v in block["moe"].items() if k != BIAS}}
-        if "moe" in block else block for block in params["layers"]]}
-
-
-def _with_biases(trainable: Params, biases: List[Optional[jax.Array]]) -> Params:
-    return {**trainable, "layers": [
-        {**block, "moe": {**block["moe"], BIAS: bias}} if bias is not None else block
-        for block, bias in zip(trainable["layers"], biases)]}
-
-
-def _biases(params: Params) -> List[Optional[jax.Array]]:
-    return [block["moe"][BIAS] if "moe" in block else None for block in params["layers"]]
-
-
 def init_train_state(cfg: TransformerConfig, seed: int = 0,
                      mesh: Optional[Mesh] = None) -> TrainState:
-    rng = jax.random.PRNGKey(seed)
-    params = init_params(cfg, rng, mesh=mesh)
-    opt = _optimizer(cfg)
-    step = jnp.zeros((), dtype=jnp.int32)
-    trainable = _trainable(params)
-    if mesh is None:
-        opt_state = jax.jit(opt.init)(trainable)
-    else:
-        replicated = NamedSharding(mesh, P())
-        opt_shardings = optax.tree_map_params(
-            opt, lambda _, sharding: sharding, jax.eval_shape(opt.init, trainable),
-            _trainable(param_shardings(cfg, mesh)), transform_non_params=lambda _: replicated)
-        opt_state = jax.jit(opt.init, out_shardings=opt_shardings)(trainable)
-        step, rng = jax.device_put((step, rng), replicated)
-    return TrainState(params=params, opt_state=opt_state, step=step, rng=rng)
+    return experts.init_train_state(cfg, _layout(cfg), functools.partial(_init_leaf, cfg),
+                                    seed, mesh)
+
+
+def _loss(cfg: TransformerConfig, trainable: Params, biases: List[Optional[jax.Array]],
+          tokens: jax.Array):
+    logits, loads = forward(cfg, _with_biases(trainable, biases), tokens)
+    losses = optax.softmax_cross_entropy_with_integer_labels(logits[:, :-1], tokens[:, 1:])
+    return jnp.mean(losses), loads
 
 
 def make_train_step(
@@ -393,32 +282,4 @@ def make_train_step(
 ) -> Callable[[TrainState, jax.Array], Tuple[TrainState, jax.Array]]:
     """The jitted step: forward, loss, backward and adamw over every leaf but
     the routers' biases, which move by the step's loads."""
-    opt = _optimizer(cfg)
-
-    def loss_fn(trainable: Params, biases: List[Optional[jax.Array]], tokens: jax.Array):
-        logits, loads = forward(cfg, _with_biases(trainable, biases), tokens)
-        losses = optax.softmax_cross_entropy_with_integer_labels(logits[:, :-1], tokens[:, 1:])
-        return jnp.mean(losses), loads
-
-    def train_step(state: TrainState, tokens: jax.Array) -> Tuple[TrainState, jax.Array]:
-        if mesh is not None:
-            tokens = jax.lax.with_sharding_constraint(tokens, NamedSharding(mesh, P("dp", None)))
-        trainable, biases = _trainable(state.params), _biases(state.params)
-        (loss, loads), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            trainable, biases, tokens)
-        updates, new_opt_state = opt.update(grads, state.opt_state, trainable)
-        loads = iter(loads)
-        new_biases = []
-        for bias in biases:
-            if bias is not None:
-                load = next(loads)
-                bias = bias + cfg.router_bias_rate * jnp.sign(jnp.mean(load) - load)
-            new_biases.append(bias)
-        new_params = _with_biases(optax.apply_updates(trainable, updates), new_biases)
-        return (
-            TrainState(params=new_params, opt_state=new_opt_state, step=state.step + 1,
-                       rng=jax.random.fold_in(state.rng, state.step)),
-            loss,
-        )
-
-    return jax.jit(train_step, donate_argnums=(0,))
+    return experts.make_train_step(cfg, functools.partial(_loss, cfg), mesh)

@@ -84,8 +84,9 @@ class TransformerConfig:
     # optimizer state holds moments for the adapters alone.
     lora_rank: int = 0
     # "dense": the block below. "kimi_linear": Kimi Linear's layers
-    # (models/kimi_linear.py), sized by the fields that follow; the dense
-    # family reads none of them.
+    # (models/kimi_linear.py); "glm_dsa": GLM-5's (models/glm_dsa.py). Both
+    # are sized by the fields that follow; the dense family reads none of
+    # them.
     family: str = "dense"
     # One letter a layer, the first n_layers taken: "K" KDA linear
     # attention, "M" latent attention (MLA).
@@ -108,6 +109,16 @@ class TransformerConfig:
     # What each step moves a router's correction bias by, per expert,
     # against the sign of its load's excess.
     router_bias_rate: float = 0.0
+    # glm_dsa: the query's latent, the DSA indexer's heads, their width and
+    # the keys each query keeps.
+    q_lora_rank: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # glm_dsa: the tensor-parallel degree this device's FFN columns are a
+    # share of. d_ff and expert_d_ff are the published widths and it holds
+    # 1/tp_size of their columns; n_heads counts the heads it holds.
+    tp_size: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -136,7 +147,7 @@ def make_mesh(
     return Mesh(arr, axis_names=("dp", "sp", "tp"))
 
 
-FAMILIES = ("dense", "kimi_linear")
+FAMILIES = ("dense", "kimi_linear", "glm_dsa")
 
 
 def _other_family(cfg: TransformerConfig):
@@ -145,9 +156,9 @@ def _other_family(cfg: TransformerConfig):
         raise ValueError(f"unknown family {cfg.family!r}; expected one of {FAMILIES}")
     if cfg.family == "dense":
         return None
-    from . import kimi_linear
+    from . import glm_dsa, kimi_linear
 
-    return kimi_linear
+    return {"kimi_linear": kimi_linear, "glm_dsa": glm_dsa}[cfg.family]
 
 
 def _is_moe_layer(cfg: TransformerConfig, i: int) -> bool:

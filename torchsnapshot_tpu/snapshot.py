@@ -36,7 +36,7 @@ import fnmatch
 import functools
 import logging
 import time
-from typing import Any, Callable, Dict, List, Optional, Set, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -849,7 +849,11 @@ class Snapshot:
                     clone_programs=captured.clone_programs,
                     clone_leaves=captured.clone_leaves,
                     fallback_leaves=captured.fallback_leaves,
+                    clone_bytes=captured.clone_bytes,
+                    host_copy_bytes=captured.host_copy_bytes,
                 )
+                if captured.room_bytes is not None:
+                    capture_span.annotate(clone_room_bytes=captured.room_bytes)
             logger.debug(
                 "async take captured %d device/host sources for %d "
                 "deferred write requests",
@@ -1038,7 +1042,11 @@ class Snapshot:
                     round_locs = op.exchange(plans, i)
                     try:
                         if plans:
-                            op.read_plans(plans, apply=True)
+                            op.read_plans(
+                                plans,
+                                apply=True,
+                                replaced=_replaced_without_room(plans),
+                            )
                     finally:
                         if op.fanout_ctx is not None:
                             op.fanout_ctx.drop(round_locs)
@@ -1211,6 +1219,8 @@ class Snapshot:
         read_reqs = []
         restored: Dict[str, Any] = {}
         container_entries: Manifest = {}
+        # The application's device leaves that the restored ones replace.
+        replaced: List[Any] = []
         # Per-leaf groups of (reads, deferred conversion): the conversion
         # (np buffer -> the leaf flavor the application currently holds,
         # e.g. a jax device array) may run as soon as the group's reads
@@ -1256,6 +1266,8 @@ class Snapshot:
             if convert is None:
                 restored[path] = dst
             else:
+                if is_jax_array(current_leaf):
+                    replaced.append(current_leaf)
 
                 def _pp(
                     batch: Optional["_PlacementBatch"],
@@ -1288,6 +1300,7 @@ class Snapshot:
             restored=restored,
             groups=groups,
             read_reqs=read_reqs,
+            replaced=replaced,
         )
 
     # ------------------------------------------------------------------
@@ -1619,6 +1632,7 @@ class _StatefulLoadPlan:
         restored: Dict[str, Any],
         groups: List[_LeafGroup],
         read_reqs: List[Any],
+        replaced: Optional[List[Any]] = None,
     ) -> None:
         self.key = key
         self.stateful = stateful
@@ -1626,6 +1640,7 @@ class _StatefulLoadPlan:
         self.restored = restored
         self.groups = groups
         self.read_reqs = read_reqs
+        self.replaced = replaced or []
 
     def finish_reads(self, batch: _PlacementBatch) -> None:
         """Register into ``batch`` the deferred conversions (np buffers
@@ -1653,6 +1668,29 @@ class _StatefulLoadPlan:
                 {**self.container_entries}, self.restored, prefix=self.key
             )
             self.stateful.load_state_dict(state_dict)
+
+
+def _replaced_without_room(plans: List[_StatefulLoadPlan]) -> List[Any]:
+    """The application's device arrays that ``plans``' restored arrays
+    replace, where a device has no room for both (``bytes_limit -
+    bytes_in_use`` below their bytes and ``incremental.room_margin``);
+    else none. A device that keeps no account (the CPU backend) has room."""
+    from .incremental import memory_stats, room_margin
+
+    replaced = {
+        id(a): a for plan in plans for a in plan.replaced if not a.is_deleted()
+    }
+    need: Dict[Any, int] = {}
+    for arr in replaced.values():
+        for shard in arr.addressable_shards:
+            need[shard.device] = need.get(shard.device, 0) + int(shard.data.nbytes)
+    for device, nbytes in need.items():
+        stats = memory_stats(device)
+        if not stats or "bytes_limit" not in stats:
+            continue
+        if stats["bytes_limit"] - stats.get("bytes_in_use", 0) < nbytes + room_margin(nbytes):
+            return list(replaced.values())
+    return []
 
 
 def _finish_plans(plans: List[_StatefulLoadPlan]) -> None:
@@ -2142,7 +2180,10 @@ class _RestoreOp:
         )
 
     def read_plans(
-        self, plans: List["_StatefulLoadPlan"], apply: bool
+        self,
+        plans: List["_StatefulLoadPlan"],
+        apply: bool,
+        replaced: Sequence[Any] = (),
     ) -> None:
         """The read pipeline, spelled here alone: one pipeline over the
         reads of ``plans``, with streaming placement and destinations
@@ -2151,7 +2192,15 @@ class _RestoreOp:
         thread that calls it. ``apply``: each plan is handed to the
         application as soon as the pipeline is through (the sync driver,
         a key at a time); otherwise its last placements are dispatched
-        and ``wait()`` applies it."""
+        and ``wait()`` applies it.
+
+        ``replaced``: the application's device arrays that the restored
+        ones replace, where the device has no room for both
+        (:func:`_replaced_without_room`; the sync driver's, which holds
+        the caller's thread). Nothing is then placed while reads run:
+        every read lands on the host and is verified, and only then are
+        these arrays freed and the new ones placed, so a restore that
+        fails in its reads leaves the application's state whole."""
         read_reqs = [r for plan in plans for r in plan.read_reqs]
         # The rank's pre-batching destination bytes — the denominator of
         # the read-amplification metric restore reports carry.
@@ -2162,7 +2211,7 @@ class _RestoreOp:
             read_reqs = batch_read_requests(read_reqs)
         # Streaming placement: completed leaves device_put in rolling
         # batches while the remaining reads are still in flight.
-        placer = _StreamingPlacer()
+        placer = _StreamingPlacer(0 if replaced else None)
         for plan in plans:
             placer.register_plan(plan)
         placer.lease_destinations(read_reqs, self.memory_budget_bytes)
@@ -2192,6 +2241,16 @@ class _RestoreOp:
             placer.report_destinations(pipeline)
             self.pipelines.append(pipeline)
             placer.flush()
+            if replaced:
+                logger.info(
+                    "restore of %s: no device room for the restored arrays "
+                    "beside the %d they replace; every read verified, "
+                    "freeing those before placing",
+                    [plan.key for plan in plans],
+                    len(replaced),
+                )
+                for arr in replaced:
+                    arr.delete()
             if apply:
                 for plan in plans:
                     plan.apply(finish=True)

@@ -240,15 +240,20 @@ SPAN_LEAF_CONSUME = "consume:leaf"
 # Device-snapshot async takes: the pre-return capture pass (on-device
 # clone dispatch + mutable-host-leaf copies) — the only staging-flavored
 # work left inside async_take's training-visible span. It ends with
-# clone_programs, clone_leaves (the members of those programs) and
-# fallback_leaves (jax sources cloned one by one).
+# clone_programs, clone_leaves (the members of those programs),
+# fallback_leaves (jax sources cloned one by one), clone_bytes and
+# host_copy_bytes (the jax sources' bytes cloned, and copied to the host
+# for want of device room), and clone_room_bytes (the room the take saw
+# beyond the process's reserve; absent where no device keeps an account).
 SPAN_DEVICE_CAPTURE = "stage:device_capture"
 # Inside that pass. capture:clone is the dispatch of one clone program:
 # one a device group over all its jax sources (args: kind, bytes,
 # leaves), or one jax leaf's where its group's program failed (kind,
 # bytes, leaf). One per distinct source (kind, bytes, leaf): the host
 # copy of a mutable numpy leaf (or of a jax leaf whose clone failed),
-# the eager pickle of an object.
+# the eager pickle of an object. A jax source the device had no room to
+# clone is copied to the host inside the pass too (kind no_room, bytes),
+# on one of several threads.
 SPAN_CAPTURE_CLONE = "capture:clone"
 SPAN_CAPTURE_HOST_COPY = "capture:host_copy"
 SPAN_CAPTURE_OBJECT = "capture:object"
