@@ -61,14 +61,14 @@ def stats(monkeypatch):
     return fake
 
 
-def _take_delete_restore(path, tree):
+def _take_delete_restore(path, tree, **take_kwargs):
     """An async take of `tree` whose leaves are deleted right after the call
     returns; the restore is compared with the bits the leaves held. Returns
     the take's spans by name."""
     want = {k: np.array(np.asarray(v)) for k, v in tree.items()}
     rec = trace.get_recorder()
     mark = rec.mark()
-    pending = ts.Snapshot.async_take(str(path), {"m": ts.PyTreeState(tree)})
+    pending = ts.Snapshot.async_take(str(path), {"m": ts.PyTreeState(tree)}, **take_kwargs)
     for v in tree.values():
         v.delete()
     snapshot = pending.wait()
@@ -110,6 +110,26 @@ def test_a_state_over_the_room_clones_what_fits_and_copies_the_rest(tmp_path, st
     # Inside the call: the copies end before async_take returns.
     (stage,) = spans[names.SPAN_ASYNC_TAKE_STAGE]
     assert max(e["ts"] + e["dur"] for e in copies) <= stage["ts"] + stage["dur"]
+
+
+def test_chunked_sources_leave_whole_where_cloned_and_are_cut_on_the_host_where_not(
+        tmp_path, stats):
+    """A state of chunked leaves over the room: a cloned leaf leaves the
+    device in one transfer that writes its four chunks; a leaf copied to
+    the host inside the call is written a chunk at a time from that copy.
+    No transfer slices a device array."""
+    from torchsnapshot_tpu import knobs
+
+    stats.room = 2 * LEAF + MARGIN
+    with knobs.override_incremental_chunk_size_bytes(LEAF // 4):
+        spans = _take_delete_restore(tmp_path / "s", _tree(), record_digests=True)
+    args = _capture_args(spans)
+    assert (args["clone_bytes"], args["host_copy_bytes"]) == (2 * LEAF, (N - 2) * LEAF)
+    assert (args["clone_programs"], args["clone_leaves"]) == (1, 2)
+    copies = spans[names.SPAN_CAPTURE_HOST_COPY]
+    assert [e["args"] for e in copies] == [{"kind": "no_room", "bytes": LEAF}] * (N - 2)
+    d2h = [e["args"] for e in spans[names.SPAN_STAGE_D2H]]
+    assert [(a["bytes"], a[names.ARG_SLICED_BYTES]) for a in d2h] == [(LEAF, 0)] * 2
 
 
 def test_the_reserve_is_the_programs_reservation_read_at_every_take(tmp_path, stats):

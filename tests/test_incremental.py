@@ -957,3 +957,135 @@ def test_a_source_captured_on_the_host_is_written_without_its_digest(tmp_path, m
     fresh = {"m": ts.PyTreeState({"w": jnp.zeros_like(w), "b": jnp.zeros_like(b)})}
     ts.Snapshot(str(tmp_path / "s1")).restore(fresh)
     assert_tree_eq(fresh["m"].tree, {"w": w, "b": b})
+
+
+# ---------------------------------------------------------------------------
+# chunked leaves at staging: a written subset slices, a whole set tiles
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_the_digests_of_row_arrays_are_the_digests_over_the_leafs_rows(dtype):
+    """The digest of `x[a:b]` as an array of its own is the digest of those
+    rows of `x`: a chunk digests the same however its bytes are held."""
+    x = (jnp.arange(40 * 24) % 251).astype(dtype).reshape(40, 24)
+    ranges = ((0, 16), (16, 32), (32, 40))
+    over_rows = device_digest.materialize_many(device_digest.digest_many_async([(x, ranges)]))
+    over_arrays = device_digest.materialize_many(
+        device_digest.digest_many_async([(x[a:b], None) for a, b in ranges]))
+    assert over_arrays.tolist() == over_rows.tolist()
+    host = np.asarray(x)
+    assert [tuple(r) for r in over_rows.tolist()] == [
+        device_digest.digest_host(host[a:b]) for a, b in ranges]
+
+
+def _partly_changed_pair(root, behind, monkeypatch, rows=(19,)):
+    """A full take and an incremental one of a dense state whose chunked
+    leaf changed in `rows` between them; returns the second take's spans
+    by name, its manifest as YAML objects, and the restore of it."""
+    monkeypatch.setattr(incremental, "room_for_clone", lambda flattened: behind)
+    w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
+    b = jnp.arange(5, dtype=jnp.int32)
+    changed = w
+    for row in rows:
+        changed = changed.at[row].add(1.0)
+    want = {"w": np.asarray(changed).tobytes(), "b": np.arange(5, dtype=np.int32).tobytes()}
+    rec = trace.get_recorder()
+    with knobs.override_incremental_chunk_size_bytes(1024):  # 8 chunks of 8 rows
+        base = os.path.join(root, "s0")
+        ts.Snapshot.async_take(base, {"m": ts.PyTreeState({"w": w, "b": b})},
+                               record_digests=True).wait()
+        mark = rec.mark()
+        live = {"m": ts.PyTreeState({"w": changed, "b": b + 0})}
+        pending = ts.Snapshot.async_take(os.path.join(root, "s1"), live, incremental_base=base)
+        for leaf in live["m"].tree.values():
+            leaf.delete()
+        snapshot = pending.wait()
+    spans = {}
+    for e in rec.events_since(mark):
+        if e.get("ph") == "X":
+            spans.setdefault(e["name"], []).append(e["args"])
+    manifest = {k: entry_to_yaml_obj(v) for k, v in snapshot.get_manifest().items()}
+    fresh = {"m": ts.PyTreeState({"w": jnp.zeros_like(w), "b": jnp.zeros_like(b)})}
+    snapshot.restore(fresh)
+    restored = {k: np.asarray(v).tobytes() for k, v in fresh["m"].tree.items()}
+    return spans, manifest, restored, want
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_partly_changed_chunked_leaf_writes_its_one_chunk_on_both_paths(
+        tmp_path, monkeypatch, path):
+    """One chunk of eight changed: on both paths it is the one written, its
+    requests do not tile the leaf, and its transfer slices the clone first.
+    Behind the return the skipped chunks' requests go before the drain
+    waits, so only their clone's bytes are waited for."""
+    spans, manifest, restored, want = _partly_changed_pair(
+        str(tmp_path), PATHS[path], monkeypatch)
+    (plan,) = [a for a in spans[names.SPAN_TAKE_PLAN] if "bytes_written" in a]
+    chunk = 8 * 32 * 4
+    assert (plan["chunks_written"], plan["bytes_written"]) == (1, chunk)
+    assert (plan["chunks_referenced"], plan["bytes_referenced"]) == (8, 7 * chunk + 20)
+    w = manifest["0/m/w"]
+    locations = [c["array"]["location"] for c in w["chunks"]]
+    assert locations[2] == "0/m/w_16"
+    assert all(loc.startswith("../s0/") for i, loc in enumerate(locations) if i != 2)
+    assert manifest["0/m/b"]["location"] == "../s0/0/m/b"
+    (d2h,) = spans[names.SPAN_STAGE_D2H]
+    assert (d2h["bytes"], d2h[names.ARG_SLICED_BYTES]) == (chunk, chunk)
+    assert restored == want
+
+
+@pytest.mark.parametrize("path", PATHS)
+def test_a_wholly_changed_chunked_leaf_leaves_the_device_in_one_transfer(
+        tmp_path, monkeypatch, path):
+    """Every chunk changed: on both paths the written requests tile the
+    leaf, and one transfer of its clone, slicing nothing, writes them all."""
+    spans, manifest, restored, want = _partly_changed_pair(
+        str(tmp_path), PATHS[path], monkeypatch, rows=range(0, 64, 8))
+    (plan,) = [a for a in spans[names.SPAN_TAKE_PLAN] if "bytes_written" in a]
+    assert (plan["chunks_written"], plan["chunks_referenced"]) == (8, 1)
+    assert [c["array"]["location"] for c in manifest["0/m/w"]["chunks"]] == [
+        f"0/m/w_{8 * i}" for i in range(8)]
+    (d2h,) = spans[names.SPAN_STAGE_D2H]
+    assert (d2h["bytes"], d2h[names.ARG_SLICED_BYTES]) == (64 * 32 * 4, 0)
+    assert restored == want
+
+
+@pytest.mark.parametrize("rows", [(19,), tuple(range(0, 64, 8))], ids=["one", "every"])
+def test_both_paths_give_a_changed_chunked_leaf_the_same_manifest(tmp_path, monkeypatch, rows):
+    behind = _partly_changed_pair(str(tmp_path / "b"), True, monkeypatch, rows)
+    waiting = _partly_changed_pair(str(tmp_path / "w"), False, monkeypatch, rows)
+    assert behind[1] == waiting[1]
+    assert behind[2] == waiting[2] == behind[3]
+
+
+def test_the_commit_thread_compiles_no_digest_or_clone_program(tmp_path, monkeypatch):
+    """The caller of a job's first take compiles the digest program before
+    the clone exists; the commit thread dispatches it, stages a tiling
+    leaf whole, and compiles nothing; a second take compiles nothing
+    anywhere."""
+    import threading
+
+    monkeypatch.setattr(incremental, "room_for_clone", lambda flattened: True)
+    compiled = []
+
+    def listener(name, *args, **kwargs):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiled.append(threading.current_thread().name)
+
+    state = {"m": ts.PyTreeState({"odd": jnp.arange(3 * 41 * 7.0).reshape(41, 21),
+                                  "b": jnp.ones(13)})}
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        with knobs.override_incremental_chunk_size_bytes(21 * 4 * 8):  # 6 chunks
+            base = str(tmp_path / "s1")
+            ts.Snapshot.async_take(base, state, record_digests=True).wait()
+            first = list(compiled)
+            changed = {"m": ts.PyTreeState({k: v + 1 for k, v in state["m"].tree.items()})}
+            jax.block_until_ready(changed["m"].tree)
+            compiled.clear()
+            ts.Snapshot.async_take(str(tmp_path / "s2"), changed, incremental_base=base).wait()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    assert "MainThread" in first and "snapshot-commit" not in first
+    assert compiled == []

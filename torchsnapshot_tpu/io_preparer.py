@@ -317,16 +317,21 @@ class ArrayBufferStager(BufferStager):
         arr = self.arr
         if self.array_prepare_func is not None:
             arr = self.array_prepare_func(arr, self.is_async_snapshot)
+        sliced = self.slc is not None and is_jax_array(arr)
         if self.slc is not None:
             arr = arr[self.slc]
         if is_jax_array(arr):
             # jax.Array is immutable: the host copy is consistent even for
             # async snapshots, with no defensive copy. Inside a staging
             # window the copy lands in a recycled slab (d2h_slabs), which
-            # goes back when the write drops ``host``.
+            # goes back when the write drops ``host``. A device slice is a
+            # program of its own, queued behind whatever the runtime holds:
+            # the transfer waits for it.
             nbytes = int(arr.nbytes)
             with trace_annotation(
-                metric_names.SPAN_STAGE_D2H, bytes=nbytes
+                metric_names.SPAN_STAGE_D2H,
+                bytes=nbytes,
+                **{metric_names.ARG_SLICED_BYTES: nbytes if sliced else 0},
             ) as span:
                 with d2h_slabs.lease(nbytes) as lease:
                     host = np.asarray(arr)
@@ -1038,6 +1043,63 @@ def _clone_device_groups(
             programs += 1
             leaves += len(members)
     return programs, leaves
+
+
+def stage_sources_whole(write_reqs: List[WriteReq]) -> List[WriteReq]:
+    """A device-snapshot take's requests, as its staging runs them: the
+    chunk requests over one device array whose dim-0 rows tile it exactly
+    (every chunk of a leaf written) become one request that transfers
+    the array whole and writes each chunk's bytes from the host copy
+    (``WriteReq.parts``), in the place of the first of them. Each chunk
+    would otherwise slice the array on the device first, a program that
+    waits behind the steps the training loop keeps queued, before its
+    transfer can start; the whole array leaves by DMA from a buffer that
+    exists. Only an array that a plain take stages in one request (no
+    larger than the chunk and shard sizes) is staged whole, so the host
+    holds no more at once than a plain take of it would. A subset of the
+    chunks (an incremental take's skips), a prepare function and a host
+    source keep their requests."""
+    groups: Dict[int, List[Tuple[int, int, WriteReq]]] = {}
+    for req in write_reqs:
+        stager = req.buffer_stager
+        if (
+            type(stager) is ArrayBufferStager
+            and stager.array_prepare_func is None
+            and isinstance(stager.slc, slice)
+            and is_jax_array(stager.arr)
+            and stager.arr.shape
+        ):
+            start, stop, step = stager.slc.indices(stager.arr.shape[0])
+            if step == 1:
+                groups.setdefault(id(stager.arr), []).append((start, stop, req))
+    limit = min(knobs.get_max_chunk_size_bytes(), knobs.get_max_shard_size_bytes())
+    # A group's first request in the take's order, by id, to the request
+    # that takes its place; the ids of the others, which go (and with
+    # them their stagers' references to the array).
+    whole: Dict[int, WriteReq] = {}
+    merged: set = set()
+    for rows in groups.values():
+        first = rows[0][2]
+        rows.sort(key=lambda r: r[:2])
+        arr = first.buffer_stager.arr
+        nbytes = int(arr.nbytes)
+        tiles = len(rows) > 1 and all(
+            start == (rows[i - 1][1] if i else 0) and stop > start
+            for i, (start, stop, _) in enumerate(rows)
+        )
+        if not tiles or rows[-1][1] != arr.shape[0] or nbytes > limit:
+            continue
+        row_bytes = nbytes // arr.shape[0]
+        whole[id(first)] = WriteReq(
+            path=first.path,
+            buffer_stager=ArrayBufferStager(arr, first.buffer_stager.is_async_snapshot),
+            parts=[
+                (req.path, start * row_bytes, stop * row_bytes)
+                for start, stop, req in rows
+            ],
+        )
+        merged.update(id(req) for _, _, req in rows if req is not first)
+    return [whole.get(id(req), req) for req in write_reqs if id(req) not in merged]
 
 
 def capture_write_reqs(

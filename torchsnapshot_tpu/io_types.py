@@ -216,6 +216,11 @@ class BufferConsumer(abc.ABC):
 class WriteReq:
     path: str
     buffer_stager: BufferStager
+    # One staging for several blobs: each blob's path and the ``[start,
+    # end)`` bytes of the staged buffer it is (a source staged whole for
+    # its chunk writes, ``io_preparer.stage_sources_whole``). None: the
+    # staged buffer is ``path``'s blob.
+    parts: Optional[List[Tuple[str, int, int]]] = None
 
 
 @dataclass

@@ -54,6 +54,7 @@ from .integrity import (
     verify_page_crcs,
     verify_range_checksum,
 )
+from .io_preparer import stage_sources_whole
 from .io_types import (
     BufferList,
     ReadIO,
@@ -558,6 +559,20 @@ class PendingIOWork:
         event_loop.run_until_complete(self.complete())
 
 
+def _release_together(budget: MemoryBudget, total: int) -> Callable:
+    """A write's release for the parts of one staged buffer: the whole
+    reservation goes back with the last of them, when no part's bytes
+    (and so no byte of a slab under them, d2h_slabs) are held."""
+    left = [total]
+
+    async def release(nbytes: int) -> None:
+        left[0] -= nbytes
+        if left[0] == 0:
+            await budget.release(total)
+
+    return release
+
+
 async def execute_write_reqs(
     write_reqs: List[WriteReq],
     storage: StoragePlugin,
@@ -579,13 +594,16 @@ async def execute_write_reqs(
         if staging_pool is not None
         else MemoryBudget(memory_budget_bytes)
     )
+    # A request staged once for several blobs (``WriteReq.parts``) counts
+    # one a blob, as the writes do.
+    blobs = sum(len(r.parts) if r.parts else 1 for r in write_reqs)
     stats = _PipelineStats()
-    stats.pending = len(write_reqs)
-    reporter = _ProgressReporter(stats, budget, rank, len(write_reqs), progress)
+    stats.pending = blobs
+    reporter = _ProgressReporter(stats, budget, rank, blobs, progress)
     reporter.print_header()
     if progress is not None:
         progress.begin_pipeline(
-            len(write_reqs),
+            blobs,
             sum(r.buffer_stager.get_staging_cost_bytes() for r in write_reqs),
             phase="staging",
         )
@@ -614,7 +632,10 @@ async def execute_write_reqs(
             return compute_checksum_entry(buf)
         return await run_in_executor(executor, compute_checksum_entry, buf)
 
-    async def write_one(req: WriteReq, buf) -> None:
+    async def write_one(path: str, buf, release=None) -> None:
+        """Write ``buf`` as ``path``'s blob and give back its reservation;
+        ``release``, where given, gives it back in its place (the parts
+        of one staged buffer, whose bytes go together)."""
         nonlocal fused_declined
         buf_len = len(buf)
         try:
@@ -646,12 +667,12 @@ async def execute_write_reqs(
                 is not StoragePlugin.write_with_checksum
             )
             if record_checksums and not fused:
-                checksums[req.path] = await checksum_off_slot(buf)
+                checksums[path] = await checksum_off_slot(buf)
             declined = False
             # One WriteIO for the whole request: the plugin stamps the
             # write-path variant that actually served it (vectorized /
             # direct / fused / buffered) onto this object.
-            write_io = WriteIO(path=req.path, buf=buf)
+            write_io = WriteIO(path=path, buf=buf)
             async with io_slots:
                 stats.waiting_io -= 1
                 stats.io += 1
@@ -662,7 +683,7 @@ async def execute_write_reqs(
                     if fused:
                         entry = await storage.write_with_checksum(write_io)
                         if entry is not None:
-                            checksums[req.path] = entry
+                            checksums[path] = entry
                         else:
                             # Plugin declined at runtime (native lib
                             # unavailable; nothing written): fall back
@@ -678,7 +699,7 @@ async def execute_write_reqs(
                 # writes: checksum off the I/O slots, then re-acquire a
                 # slot for the plain write.
                 fused_declined = True
-                checksums[req.path] = await checksum_off_slot(buf)
+                checksums[path] = await checksum_off_slot(buf)
                 stats.waiting_io += 1
                 async with io_slots:
                     stats.waiting_io -= 1
@@ -697,7 +718,7 @@ async def execute_write_reqs(
             # request is admitted.
             del buf
             write_io = None
-            await budget.release(buf_len)
+            await (release or budget.release)(buf_len)
         stats.done += 1
         stats.bytes_moved += buf_len
         reporter.maybe_report()
@@ -717,7 +738,8 @@ async def execute_write_reqs(
             bytes=cost,
         ):
             await budget.acquire(cost)
-        stats.pending -= 1
+        writes = len(req.parts) if req.parts else 1
+        stats.pending -= writes
         stats.staging += 1
         stage_span = recorder.begin(
             telemetry.names.SPAN_PIPELINE_STAGE, blob=req.path, bytes=cost
@@ -731,13 +753,20 @@ async def execute_write_reqs(
             raise
         recorder.end(stage_span, staged_bytes=len(buf))
         stats.staging -= 1
-        stats.waiting_io += 1
+        stats.waiting_io += writes
         stats.bytes_staged += len(buf)
         reporter.publish_progress()
         # Re-price the reservation: actual buffer size can differ from the
         # staging cost (e.g. pickled objects).
         await budget.adjust(len(buf) - cost)
-        io_tasks.append(asyncio.create_task(write_one(req, buf)))
+        if req.parts is None:
+            io_tasks.append(asyncio.create_task(write_one(req.path, buf)))
+        else:
+            release = _release_together(budget, len(buf))
+            io_tasks.extend(
+                asyncio.create_task(write_one(path, buf[start:end], release))
+                for path, start, end in req.parts
+            )
         del buf
 
     staging_tasks = [asyncio.create_task(stage_one(r)) for r in write_reqs]
@@ -847,6 +876,9 @@ class DeferredIOWork:
 
     def sync_complete(self, event_loop: asyncio.AbstractEventLoop) -> None:
         self._await_device_clones()
+        # A chunk's slice would wait behind the steps the caller keeps
+        # queued: a clone whose chunks are all written leaves whole.
+        self.write_reqs = stage_sources_whole(self.write_reqs)
         request_bytes = [
             r.buffer_stager.get_staging_cost_bytes() for r in self.write_reqs
         ]

@@ -263,12 +263,15 @@ SPAN_CAPTURE_OBJECT = "capture:object"
 SPAN_CAPTURE_READY = "capture:ready"
 # Inside stage:leaf, the ``np.asarray`` of a jax array alone: the PJRT
 # transfer plus the host-side untiling, without the slicing and
-# ascontiguousarray around it (args: bytes; inside a device-snapshot
-# take's staging window, ARG_LEASED_BYTES, the bytes that landed in a
-# slab of dest_pool, and ARG_RECYCLED_BYTES, those of them in a slab a
-# copy had written before: d2h_slabs.py).
+# ascontiguousarray around it (args: bytes; ARG_SLICED_BYTES, the bytes
+# whose transfer first had to slice a device array, a program queued
+# behind the runtime's; inside a device-snapshot take's staging window,
+# ARG_LEASED_BYTES, the bytes that landed in a slab of dest_pool, and
+# ARG_RECYCLED_BYTES, those of them in a slab a copy had written before:
+# d2h_slabs.py).
 SPAN_STAGE_D2H = "stage:d2h"
 # Annotated, so that the names lint reads them as the span args they are.
+ARG_SLICED_BYTES: str = "sliced_bytes"
 ARG_LEASED_BYTES: str = "leased_bytes"
 ARG_RECYCLED_BYTES: str = "recycled_bytes"
 
